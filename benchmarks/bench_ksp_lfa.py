@@ -16,6 +16,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 import sys
 import time
 from pathlib import Path
@@ -77,16 +78,7 @@ def main() -> None:
     ap.add_argument("--backend", choices=("auto", "cpu"), default="auto")
     args = ap.parse_args()
     if args.backend == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-    else:
-        # real-chip run: serialize against the driver's bench slot;
-        # always yieldable — an auxiliary harness must never kill a
-        # live measurement (bench.py lock protocol)
-        import bench
-
-        bench.acquire_bench_lock(yieldable=True)
+        os.environ["JAX_PLATFORMS"] = "cpu"  # before jax is imported
 
     from openr_tpu.decision.linkstate import LinkState, PrefixState
     from openr_tpu.decision.oracle import compute_routes as oracle_routes

@@ -54,10 +54,6 @@ from openr_tpu.utils import topogen  # noqa: E402
 def main() -> None:
     n_nodes = int(sys.argv[1]) if len(sys.argv) > 1 else 100_000
     b = int(sys.argv[2]) if len(sys.argv) > 2 else 32
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # noqa: BLE001
-        pass
     devs = jax.devices("cpu")
     assert len(devs) >= N_DEV, devs
 

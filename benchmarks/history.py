@@ -148,9 +148,9 @@ def check_history(
     records: list[dict], tolerance: float = DEFAULT_TOLERANCE
 ) -> list[str]:
     """Compare the NEWEST record's headline metrics vs the median of all
-    prior records sharing its fingerprint AND metric name (degraded
-    runs rename the metric, so cpu_fallback rows never gate real-TPU
-    ones). Returns human-readable warnings; empty = clean. Pure over
+    prior records sharing its fingerprint AND metric name (a row under
+    another metric name never gates this one). Returns human-readable
+    warnings; empty = clean. Pure over
     the loaded records — testable without files."""
     if len(records) < 2:
         return []

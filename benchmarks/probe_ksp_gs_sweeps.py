@@ -16,7 +16,6 @@ REPO = str(Path(__file__).resolve().parent.parent)
 sys.path.insert(0, REPO)
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 import importlib.util

@@ -1,6 +1,6 @@
 """Per-stage timing of TpuSpfSolver.solve's fused split path at 100k.
 
-The live-chip decomposition (benchmarks/logs/decomp_tpu_0345.out) shows
+The pre-PR-1 chip decomposition (logs removed in PR 21) shows
 pure kernel p50 206 ms but the headline solve p50 335 ms; this probe
 splits the remaining ~130 ms between: host prep (to_csr, neighbor
 metric scan), the fused dispatch + scalar drain, the packed-buffer

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 from pathlib import Path
@@ -39,9 +40,7 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # host profile; before jax import
 
     import dataclasses
 

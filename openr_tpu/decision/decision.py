@@ -377,6 +377,9 @@ class Decision(OpenrModule):
         self.rib_policy = None  # set via apply_rib_policy (openr_tpu.policy)
         self._spf_runs = 0
         self._last_spf_ms = 0.0
+        # text of the most recent failed rebuild's exception (None until
+        # one fails); counted as decision.rebuild.failed
+        self.last_rebuild_error: str | None = None
         self.last_breakdown_ms: dict[str, float] = {}
         # perf_counter() of the snapshot behind the most recently
         # EMITTED RouteUpdate, and behind the most recently COMPLETED
@@ -1283,8 +1286,19 @@ class Decision(OpenrModule):
             }
         except asyncio.CancelledError:
             raise  # node shutdown mid-rebuild must propagate (OR005)
-        except Exception:  # noqa: BLE001 — keep serving the old RIB
+        except Exception as exc:  # noqa: BLE001 — keep serving the old RIB
             log.exception("%s: route rebuild failed", self.name)
+            # a solver that cannot compile or run (first contact with a
+            # new chip, a bad kernel) would otherwise show up only as a
+            # RIB that never arrives: count it and keep the text where
+            # ctrl, the emulator and chip_smoke.py can read it
+            self.last_rebuild_error = f"{type(exc).__name__}: {exc}"
+            if self.counters:
+                self.counters.increment("decision.rebuild.failed")
+                self.counters.flight_record(
+                    "decision.rebuild_failed",
+                    error=self.last_rebuild_error[:200],
+                )
             # the dirt describing this batch was consumed but its routes
             # never landed: drop the per-area caches so the next rebuild
             # is a from-scratch one instead of trusting a stale artifact.

@@ -275,10 +275,13 @@ class ProcCluster:
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (pkg_root, env.get("PYTHONPATH")) if p
         )
-        # the node process must never touch a TPU plugin — and with
+        # a chip belongs to one process: a child that reached for it
+        # would fail or hang behind whoever holds it, so every node
+        # process is pinned to the CPU backend — and with
         # use_tpu_solver=False it never imports jax at all (the import
-        # is lazy); the env pin is belt-and-braces for the odd path
-        # (compile ledger) that does
+        # is lazy); the pin covers the odd path (compile ledger) that
+        # does. Multi-process harnesses therefore measure the host path
+        # only, never the device.
         env["JAX_PLATFORMS"] = "cpu"
         pn.proc = subprocess.Popen(
             [

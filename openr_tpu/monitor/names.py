@@ -49,6 +49,7 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.rebuild.topo_delta",
         "decision.rebuild.cached_areas",
         "decision.rebuild.area_solves",
+        "decision.rebuild.failed",
         # merge-book fallback matrix (docs/Decision.md): scoped = the
         # delta fold patched the persistent merged RIB in place; full =
         # a first-build/policy/mismatch round re-armed it from scratch

@@ -29,28 +29,6 @@ from openr_tpu.ops.spf import INF_DIST
 from openr_tpu.parallel.mesh import GRAPH_AXIS, SOURCES_AXIS
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version shim: ``jax.shard_map(check_vma=)`` is the jax>=0.6
-    spelling; on the 0.4.x line the API lives at
-    ``jax.experimental.shard_map.shard_map`` whose ``check_rep`` checker
-    has no replication rule for ``while_loop`` (NotImplementedError on
-    both kernel bodies) and must be off — the varying/replication
-    typing the comments below justify is enforced wherever check_vma
-    exists, and the cross-version parity tests (tests/test_parallel.py)
-    pin the numerics either way."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=True,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def _local_sssp(edge_src, edge_dst, edge_metric, edge_blocked, roots, num_nodes):
     """Per-device body: local edge shard, local root slice, pmin across the
     graph axis after every segmented relax."""
@@ -109,7 +87,7 @@ def sharded_sssp(
     num_nodes: int,
 ) -> jax.Array:
     """Returns dist [Vp, B] (B sharded over `sources`, rows replicated)."""
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_local_sssp, num_nodes=num_nodes),
         mesh=mesh,
         in_specs=(
@@ -120,6 +98,7 @@ def sharded_sssp(
             P(SOURCES_AXIS),
         ),
         out_specs=P(None, SOURCES_AXIS),
+        check_vma=True,
     )
     return fn(edge_src, edge_dst, edge_metric, edge_blocked, roots)
 
@@ -142,11 +121,8 @@ def _local_split_sssp(
     # carry must carry the same manual-axes type. (Values stay
     # replicated in fact — every shard computes identical full dist —
     # so per-shard while_loop trip counts coincide and the in-loop
-    # collectives stay aligned.) pcast only exists on the check_vma
-    # (jax>=0.6) line; 0.4.x's check_rep infers the carry's rep set
-    # from the loop body instead, so no cast is needed there.
-    if hasattr(jax.lax, "pcast"):
-        dist = jax.lax.pcast(dist, GRAPH_AXIS, to="varying")
+    # collectives stay aligned.)
+    dist = jax.lax.pcast(dist, GRAPH_AXIS, to="varying")
 
     if has_overloads:
         over_rows = node_overloaded[base_nbr]  # [vp/G, W] src-overloaded
@@ -208,7 +184,7 @@ def sharded_sssp_split(
     g = mesh.shape[GRAPH_AXIS]
     if vp % g:
         raise ValueError(f"vp={vp} must divide by graph axis size {g}")
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _local_split_sssp, vp=vp, has_overloads=has_overloads
         ),
@@ -223,6 +199,7 @@ def sharded_sssp_split(
             P(SOURCES_AXIS),
         ),
         out_specs=P(None, SOURCES_AXIS),
+        check_vma=True,
     )
     return fn(
         base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, node_overloaded, roots

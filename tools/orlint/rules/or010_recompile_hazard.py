@@ -4,8 +4,8 @@ A jitted kernel recompiles whenever a *static* argument takes a value
 it has never seen or a *traced* argument arrives with a new shape. Both
 are invisible locally — the call site looks identical, the first call
 works, and the cost only shows up as a compile storm under churn
-(~100 ms+ per variant through the production tunnel, multiplied by chip
-count once the solve is sharded). The codebase's defense is
+(a rebuild stalled for a whole compile, once per variant). The
+codebase's defense is
 quantization: every jit-facing capacity goes through a bucket helper
 (``pad_batch``/``pad_bucket`` power-of-two buckets, ``tight_nodes``
 node grid, the ``pick_*`` selectors with small fixed codomains —
