@@ -18,7 +18,6 @@ import asyncio
 import json
 import logging
 import threading
-import time
 from dataclasses import replace
 
 import numpy as np
@@ -34,7 +33,13 @@ from openr_tpu.decision.oracle import (
 from openr_tpu.decision.oracle import compute_routes as oracle_compute_routes
 from openr_tpu.decision.oracle import metric_key
 from openr_tpu.messaging import QueueClosedError, ReplicateQueue, RQueue
-from openr_tpu.monitor import compile_ledger, perf, work_ledger
+from openr_tpu.monitor import (
+    compile_ledger,
+    names,
+    perf,
+    profiling,
+    work_ledger,
+)
 from openr_tpu.monitor import device as device_telemetry
 from openr_tpu.types.kvstore import Publication, Value
 from openr_tpu.types.routes import (
@@ -74,6 +79,28 @@ _NO_DIRT: frozenset = frozenset()
 # dropped — the soak's memory watermark relies on this staying bounded
 # under long structural-churn horizons (docs/Decision.md)
 _WARM_IDLE_TRIM = 64
+
+#: Decision.last_breakdown_ms keys older than the span record, and the
+#: span each is the wall time of; every other span of a rebuild's record
+#: is published under its own name (names.REBUILD_SPANS)
+_BREAKDOWN_KEYS = {
+    "decode": "decision:decode",
+    "apply_snapshot": "decision:apply_snapshot",
+    "compute_diff": "decision:compute_diff",
+    "compute_rib": "decision:compute_rib",
+    "diff": "decision:diff",
+}
+
+
+def _breakdown_view(rec: profiling.SpanRecord) -> dict[str, float]:
+    """A rebuild's span record as Decision.last_breakdown_ms: wall ms by
+    name, every span of the rebuild path present — 0.0 where the branch
+    did not run — so that per-event series of any two names line up."""
+    out = dict.fromkeys(names.REBUILD_SPANS, 0.0)
+    out.update(rec.ms)
+    for key, span in _BREAKDOWN_KEYS.items():
+        out[key] = out.pop(span)
+    return out
 
 
 class _TopoDelta:
@@ -380,7 +407,14 @@ class Decision(OpenrModule):
         # text of the most recent failed rebuild's exception (None until
         # one fails); counted as decision.rebuild.failed
         self.last_rebuild_error: str | None = None
+        # where the last successful rebuild spent its time: a view of
+        # its span record (_breakdown_view; docs/Monitor.md "Spans")
         self.last_breakdown_ms: dict[str, float] = {}
+        # decision:debounce_wait — opened by the first publication
+        # buffered since the last rebuild began, closed into the record
+        # of the rebuild that picks the batch up (both on the loop
+        # thread, in different tasks: not a `with` block)
+        self._debounce_span: profiling.Span | None = None
         # perf_counter() of the snapshot behind the most recently
         # EMITTED RouteUpdate, and behind the most recently COMPLETED
         # rebuild (emitted or not) — benchmarks use the pair to attribute
@@ -458,6 +492,9 @@ class Decision(OpenrModule):
 
     async def cleanup(self) -> None:
         self.debounce.cancel()
+        if self._debounce_span is not None:
+            self._debounce_span.stop()
+            self._debounce_span = None
 
     # ----------------------------------------------------------- publication
 
@@ -513,6 +550,8 @@ class Decision(OpenrModule):
             ):
                 self._pending_kvs[(area, key)] = None  # tombstone
                 buffered = True
+        if buffered and self._debounce_span is None:
+            self._debounce_span = profiling.start("decision:debounce_wait")
         if (
             buffered
             and pub.perf_events is not None
@@ -1073,149 +1112,151 @@ class Decision(OpenrModule):
         mismatch (out-of-band LSDB mutation), artifact absent (node not
         in topology at solve time).
         """
-        ts = time.perf_counter()
-        if dirt is None:
-            dirt = {a: None for a in states}
-        scope: set | None = None
-        lscope: tuple | None = None
-        cached_areas = 0
-        warm_areas = 0
-        warm_region = 0
-        if self.rib_policy is not None or self.force_full_rebuild:
-            # RibPolicy.apply mutates the MERGED rdb in place — which
-            # aliases the single-area rdb — so per-area caching is
-            # unsound while a policy is installed: recompute from
-            # scratch until it is removed/expired (empty cache then
-            # forces the next round full, picking up the policy drop)
-            self._area_cache.clear()
-            new_rib = self.compute_rib(states)
-            path = "full"
-        else:
-            per_area: dict[str, RouteDatabase] = {}
-            solved_any = False
-            prefix_scope: set = set()
-            label_scope_set: set = set()
-            bumps = ps_bumps or {}
-            lbumps = ls_bumps or {}
-            for a, (ls, ps) in states.items():
-                d = dirt.get(a, _NO_DIRT)
-                cache = self._area_cache.get(a)
-                # revision guard: both revs must equal cached rev + the
-                # EXACT bump count the tracked drains produced (the
-                # topology side legitimately advances under tracked
-                # metric-only dirt) — so an out-of-band mutation is
-                # caught even on a round that also carries legitimate
-                # dirt of the same kind
-                if cache is not None and (
-                    cache["ls_rev"] + lbumps.get(a, 0) != ls.rev
-                    or ps.rev != cache["ps_rev"] + bumps.get(a, 0)
-                ):
-                    cache = None  # out-of-band mutation: doubt → full
-                if (
-                    isinstance(d, _TopoDelta)
-                    and cache is not None
-                    and cache["art"] is not None
-                ):
-                    res = self._warm_area(ls, ps, cache, d)
-                    if res is not None:
-                        rdb, art, t_pfx, t_lbl, region = res
-                        # warm solve: delta = dirty edges + prefixes,
-                        # touched = warm region + reassembled routes
-                        work_ledger.commit(
-                            "spf_warm",
-                            region + len(t_pfx) + len(t_lbl),
-                            len(d.edges) + len(d.prefixes),
+        with profiling.annotate("decision:compute_rib"):
+            if dirt is None:
+                dirt = {a: None for a in states}
+            scope: set | None = None
+            lscope: tuple | None = None
+            cached_areas = 0
+            warm_areas = 0
+            warm_region = 0
+            if self.rib_policy is not None or self.force_full_rebuild:
+                # RibPolicy.apply mutates the MERGED rdb in place — which
+                # aliases the single-area rdb — so per-area caching is
+                # unsound while a policy is installed: recompute from
+                # scratch until it is removed/expired (empty cache then
+                # forces the next round full, picking up the policy drop)
+                self._area_cache.clear()
+                new_rib = self.compute_rib(states)
+                path = "full"
+            else:
+                per_area: dict[str, RouteDatabase] = {}
+                solved_any = False
+                prefix_scope: set = set()
+                label_scope_set: set = set()
+                bumps = ps_bumps or {}
+                lbumps = ls_bumps or {}
+                for a, (ls, ps) in states.items():
+                    d = dirt.get(a, _NO_DIRT)
+                    cache = self._area_cache.get(a)
+                    # revision guard: both revs must equal cached rev + the
+                    # EXACT bump count the tracked drains produced (the
+                    # topology side legitimately advances under tracked
+                    # metric-only dirt) — so an out-of-band mutation is
+                    # caught even on a round that also carries legitimate
+                    # dirt of the same kind
+                    if cache is not None and (
+                        cache["ls_rev"] + lbumps.get(a, 0) != ls.rev
+                        or ps.rev != cache["ps_rev"] + bumps.get(a, 0)
+                    ):
+                        cache = None  # out-of-band mutation: doubt → full
+                    if (
+                        isinstance(d, _TopoDelta)
+                        and cache is not None
+                        and cache["art"] is not None
+                    ):
+                        res = self._warm_area(ls, ps, cache, d)
+                        if res is not None:
+                            rdb, art, t_pfx, t_lbl, region = res
+                            # warm solve: delta = dirty edges + prefixes,
+                            # touched = warm region + reassembled routes
+                            work_ledger.commit(
+                                "spf_warm",
+                                region + len(t_pfx) + len(t_lbl),
+                                len(d.edges) + len(d.prefixes),
+                            )
+                            self._area_cache[a] = {
+                                "rdb": rdb, "art": art,
+                                "ls_rev": ls.rev, "ps_rev": ps.rev,
+                            }
+                            prefix_scope |= t_pfx
+                            label_scope_set |= t_lbl
+                            warm_areas += 1
+                            warm_region += region
+                            per_area[a] = rdb
+                            continue
+                        self._warm_fallbacks += 1
+                        d = None  # warm refused: full solve for this area
+                    elif isinstance(d, _TopoDelta):
+                        d = None  # no warmable cache: full solve
+                    # the artifact is only needed for prefix-dirt
+                    # reassembly: a no-dirt area reuses its cached rdb even
+                    # when the artifact is None (node outside the topology
+                    # at solve time — the cached rdb is correctly empty)
+                    if d is None or cache is None or (
+                        d and cache["art"] is None
+                    ):
+                        rdb, art = self._compute_area(
+                            ls, ps, want_artifact=True
                         )
                         self._area_cache[a] = {
                             "rdb": rdb, "art": art,
                             "ls_rev": ls.rev, "ps_rev": ps.rev,
                         }
-                        prefix_scope |= t_pfx
-                        label_scope_set |= t_lbl
-                        warm_areas += 1
-                        warm_region += region
-                        per_area[a] = rdb
-                        continue
-                    self._warm_fallbacks += 1
-                    d = None  # warm refused: full solve for this area
-                elif isinstance(d, _TopoDelta):
-                    d = None  # no warmable cache: full solve
-                # the artifact is only needed for prefix-dirt
-                # reassembly: a no-dirt area reuses its cached rdb even
-                # when the artifact is None (node outside the topology
-                # at solve time — the cached rdb is correctly empty)
-                if d is None or cache is None or (d and cache["art"] is None):
-                    rdb, art = self._compute_area(ls, ps, want_artifact=True)
-                    self._area_cache[a] = {
-                        "rdb": rdb, "art": art,
-                        "ls_rev": ls.rev, "ps_rev": ps.rev,
-                    }
-                    solved_any = True
-                elif not d:
-                    rdb = cache["rdb"]
-                    cached_areas += 1
+                        solved_any = True
+                    elif not d:
+                        rdb = cache["rdb"]
+                        cached_areas += 1
+                    else:
+                        rdb = self._reassemble_area(cache, ps, d)
+                        cache["rdb"] = rdb
+                        cache["ps_rev"] = ps.rev
+                        prefix_scope |= d
+                    per_area[a] = rdb
+                if solved_any:
+                    path = "full"
+                    new_rib = merge_area_ribs(per_area, self.node_name)
+                    if len(per_area) == 1:
+                        # detach the merge book from the per-area cache:
+                        # the single-area fast path returns the cached rdb
+                        # itself, and the book must never alias it (scoped
+                        # rounds patch cache rdbs in place off-loop, while
+                        # ctrl readers hold self.rib on the event loop).
+                        # Bulk C dict copy, full-rebuild rounds only.
+                        detached = RouteDatabase(this_node_name=self.node_name)
+                        detached.unicast_routes = dict(new_rib.unicast_routes)
+                        detached.mpls_routes = dict(new_rib.mpls_routes)
+                        new_rib = detached
                 else:
-                    rdb = self._reassemble_area(cache, ps, d)
-                    cache["rdb"] = rdb
-                    cache["ps_rev"] = ps.rev
-                    prefix_scope |= d
-                per_area[a] = rdb
-            if solved_any:
-                path = "full"
-                new_rib = merge_area_ribs(per_area, self.node_name)
-                if len(per_area) == 1:
-                    # detach the merge book from the per-area cache:
-                    # the single-area fast path returns the cached rdb
-                    # itself, and the book must never alias it (scoped
-                    # rounds patch cache rdbs in place off-loop, while
-                    # ctrl readers hold self.rib on the event loop).
-                    # Bulk C dict copy, full-rebuild rounds only.
-                    detached = RouteDatabase(this_node_name=self.node_name)
-                    detached.unicast_routes = dict(new_rib.unicast_routes)
-                    detached.mpls_routes = dict(new_rib.mpls_routes)
-                    new_rib = detached
+                    path = "topo_delta" if warm_areas else "prefix_only"
+                    scope = prefix_scope
+                    lscope = tuple(sorted(label_scope_set))
+                    # delta merge book: fold ONLY the scoped keys across
+                    # the per-area RIBs and express the result as the
+                    # RouteUpdate that patches the live book. self.rib is
+                    # read-only in this worker thread; _rebuild_routes
+                    # applies the update in place on the event loop. No
+                    # base-table copy — the round is O(delta × areas).
+                    update = merge_scope_delta(
+                        per_area, self.rib, scope, lscope
+                    )
+                    new_rib = self.rib
+        with profiling.annotate("decision:diff"):
+            self._merge_mode = "scoped" if scope is not None else "full"
+            if scope is not None:
+                # the book fold above already produced the exact delta with
+                # diff semantics (identity-first compare); the diff stage
+                # records the scoped comparisons it performed — ratio 1
+                work_ledger.commit(
+                    "diff",
+                    len(scope) + len(lscope),
+                    len(scope) + len(lscope),
+                )
             else:
-                path = "topo_delta" if warm_areas else "prefix_only"
-                scope = prefix_scope
-                lscope = tuple(sorted(label_scope_set))
-                # delta merge book: fold ONLY the scoped keys across
-                # the per-area RIBs and express the result as the
-                # RouteUpdate that patches the live book. self.rib is
-                # read-only in this worker thread; _rebuild_routes
-                # applies the update in place on the event loop. No
-                # base-table copy — the round is O(delta × areas).
-                update = merge_scope_delta(per_area, self.rib, scope, lscope)
-                new_rib = self.rib
-        tr = time.perf_counter()
-        self._merge_mode = "scoped" if scope is not None else "full"
-        if scope is not None:
-            # the book fold above already produced the exact delta with
-            # diff semantics (identity-first compare); the diff stage
-            # records the scoped comparisons it performed — ratio 1
-            work_ledger.commit(
-                "diff",
-                len(scope) + len(lscope),
-                len(scope) + len(lscope),
-            )
-        else:
-            # full sweep walks both tables; no delta to credit
-            work_ledger.commit(
-                "diff",
-                len(self.rib.unicast_routes)
-                + len(self.rib.mpls_routes)
-                + len(new_rib.unicast_routes)
-                + len(new_rib.mpls_routes),
-                0,
-            )
-            update = diff_route_dbs(self.rib, new_rib)
-        self._rebuild_path = path
-        self._rebuild_cached_areas = cached_areas
-        self._rebuild_warm_areas = warm_areas
-        self._rebuild_warm_region = warm_region
-        self._compute_split_ms = {
-            "compute_rib": (tr - ts) * 1e3,
-            "diff": (time.perf_counter() - tr) * 1e3,
-        }
+                # full sweep walks both tables; no delta to credit
+                work_ledger.commit(
+                    "diff",
+                    len(self.rib.unicast_routes)
+                    + len(self.rib.mpls_routes)
+                    + len(new_rib.unicast_routes)
+                    + len(new_rib.mpls_routes),
+                    0,
+                )
+                update = diff_route_dbs(self.rib, new_rib)
+            self._rebuild_path = path
+            self._rebuild_cached_areas = cached_areas
+            self._rebuild_warm_areas = warm_areas
+            self._rebuild_warm_region = warm_region
         return new_rib, update
 
     async def _rebuild_routes(self) -> None:
@@ -1233,7 +1274,24 @@ class Decision(OpenrModule):
                     name=f"{self.name}.syncgate",
                 )
             return
-        t0 = time.perf_counter()
+        # one span record per rebuild (docs/Monitor.md "Spans"): every
+        # host phase from the batch's first publication to the push
+        # closes into it, the solver thread's too (the record follows
+        # asyncio.to_thread); last_breakdown_ms is its published view
+        with profiling.collect() as rec:
+            held, self._debounce_span = self._debounce_span, None
+            if held is not None:
+                held.stop(rec)
+            with profiling.annotate("decision:rebuild"):
+                done = await self._rebuild(rec)
+        if done:
+            # published breakdown (round-2 verdict item 3): where a
+            # steady-state churn rebuild actually spends its time
+            self.last_breakdown_ms = _breakdown_view(rec)
+
+    async def _rebuild(self, rec: profiling.SpanRecord) -> bool:
+        """One rebuild under the open record `rec`; False when it failed
+        (the old RIB keeps serving)."""
         traces: list = []
         try:
             # serde decode of the coalesced flap backlog runs in the
@@ -1241,49 +1299,40 @@ class Decision(OpenrModule):
             # superseded mid-flight falls back to inline decode); the
             # event loop only pays the cheap LSDB apply + snapshot, so
             # publication processing never stalls behind a rebuild
-            t1 = t0
+            decoded = None
             if self._pending_kvs:
                 batch_view = dict(self._pending_kvs)
-                decoded = await asyncio.to_thread(
-                    self._decode_batch, batch_view
+                with profiling.annotate("decision:decode"):
+                    decoded = await asyncio.to_thread(
+                        self._decode_batch, batch_view
+                    )
+            with profiling.annotate("decision:apply_snapshot"):
+                if decoded is not None:
+                    self._drain_pending(decoded)
+                # take the traces AFTER the decode await:
+                # _snapshot_states' drain folds in publications that
+                # arrived during it, so their route changes ship in
+                # THIS update — their traces must ride along, not wait
+                # for a (typically empty) next rebuild. Anything
+                # arriving after the snapshot stays pending for the
+                # rebuild that will actually contain it.
+                traces, self._pending_perf = self._pending_perf, []
+                for pe in traces:
+                    pe.add_perf_event(
+                        perf.DECISION_DEBOUNCED, node=self.node_name
+                    )
+                states = self._snapshot_states()
+                # consume the dirt AFTER the snapshot: everything the
+                # snapshot folded in has its dirt recorded by now, and
+                # anything arriving later stays pending for the rebuild
+                # that will actually contain it
+                dirt, self._dirty = self._dirty, {}
+                ps_bumps, self._dirty_ps_bumps = self._dirty_ps_bumps, {}
+                ls_bumps, self._dirty_ls_bumps = self._dirty_ls_bumps, {}
+            with profiling.annotate("decision:compute_diff"):
+                new_rib, update = await asyncio.to_thread(
+                    self._compute_and_diff, states, dirt, ps_bumps, ls_bumps
                 )
-                t1 = time.perf_counter()
-                self._drain_pending(decoded)
-            # take the traces AFTER the decode await: _snapshot_states'
-            # drain folds in publications that arrived during it, so
-            # their route changes ship in THIS update — their traces
-            # must ride along, not wait for a (typically empty) next
-            # rebuild. Anything arriving after the snapshot stays
-            # pending for the rebuild that will actually contain it.
-            traces, self._pending_perf = self._pending_perf, []
-            for pe in traces:
-                pe.add_perf_event(
-                    perf.DECISION_DEBOUNCED, node=self.node_name
-                )
-            states = self._snapshot_states()
-            # consume the dirt AFTER the snapshot: everything the
-            # snapshot folded in has its dirt recorded by now, and
-            # anything arriving later stays pending for the rebuild
-            # that will actually contain it
-            dirt, self._dirty = self._dirty, {}
-            ps_bumps, self._dirty_ps_bumps = self._dirty_ps_bumps, {}
-            ls_bumps, self._dirty_ls_bumps = self._dirty_ls_bumps, {}
-            t2 = time.perf_counter()
-            new_rib, update = await asyncio.to_thread(
-                self._compute_and_diff, states, dirt, ps_bumps, ls_bumps
-            )
-            t3 = time.perf_counter()
-            # published breakdown (round-2 verdict item 3): where a
-            # steady-state churn rebuild actually spends its time
-            self.last_breakdown_ms = {
-                "decode": (t1 - t0) * 1e3,
-                "apply_snapshot": (t2 - t1) * 1e3,
-                "compute_diff": (t3 - t2) * 1e3,
-                # thread-side split of compute_diff (solve+assembly vs
-                # RIB delta) — the two terms verdict item 3 asked to
-                # see separately
-                **getattr(self, "_compute_split_ms", {}),
-            }
         except asyncio.CancelledError:
             raise  # node shutdown mid-rebuild must propagate (OR005)
         except Exception as exc:  # noqa: BLE001 — keep serving the old RIB
@@ -1318,8 +1367,17 @@ class Decision(OpenrModule):
             self._pending_perf = (  # orlint: disable=OR003
                 traces + self._pending_perf
             )[:_PERF_PENDING_CAP]
-            return
-        self._last_spf_ms = (time.perf_counter() - t0) * 1e3
+            return False
+        with profiling.annotate("decision:export_counters"):
+            self._export_rebuild(rec, traces)
+        with profiling.annotate("decision:publish"):
+            self._publish(rec.t0, new_rib, update, traces)
+        return True
+
+    def _export_rebuild(self, rec: profiling.SpanRecord, traces) -> None:
+        """Everything a finished rebuild stamps and exports before its
+        routes go out: markers, the trim policy, the counter surface."""
+        self._last_spf_ms = rec.elapsed_ms()
         self._spf_runs += 1
         path = self._rebuild_path
         marker = {
@@ -1383,10 +1441,7 @@ class Decision(OpenrModule):
             # windowed latency stats (exported as .p50/.p99 per window):
             # the solve+assembly+diff core, and the full rebuild
             self.counters.add_value(
-                "decision.spf_solve_ms",
-                getattr(self, "_compute_split_ms", {}).get(
-                    "compute_rib", (t3 - t2) * 1e3
-                ),
+                "decision.spf_solve_ms", rec.ms["decision:compute_rib"]
             )
             self.counters.add_value("decision.rebuild_ms", self._last_spf_ms)
             # steady-state work ledger (monitor/work_ledger.py): per-
@@ -1404,9 +1459,13 @@ class Decision(OpenrModule):
                     self.counters.set(f"decision.spf.{k}", n)
                 for k, n in self._tpu.elect_stats.items():
                     self.counters.set(f"decision.elect.{k}", n)
-                for k, v in self._tpu.last_phase_ms.items():
-                    stat = f"{k}_ms"
-                    self.counters.add_value(f"decision.elect.{stat}", v)
+                phase_ms = self._tpu.last_phase_ms
+                for k in ("election", "assembly", "mpls"):
+                    if k in phase_ms:
+                        stat = f"{k}_ms"
+                        self.counters.add_value(
+                            f"decision.elect.{stat}", phase_ms[k]
+                        )
                 self.counters.set(
                     "decision.nexthop_groups", len(self._tpu._nh_intern)
                 )
@@ -1439,6 +1498,11 @@ class Decision(OpenrModule):
                         and c["art"].nh_intern is not None
                     ),
                 )
+
+    def _publish(self, t0: float, new_rib, update, traces) -> None:
+        """Land the rebuild's routes in the merge book and push them. On
+        the event loop with no await: ctrl readers never see a torn
+        table. `t0`: perf_counter() when the rebuild began."""
         first = not self.rib_computed.is_set()
         if new_rib is self.rib:
             # delta merge book: apply the scoped update to the live

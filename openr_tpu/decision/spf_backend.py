@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import logging
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +54,7 @@ from openr_tpu.ops.spf_split import (
     batched_sssp_split_warm_rib,
     build_split_tables,
     pick_gs_chunks,
+    rib_buffer_trailer,
     tight_nodes,
     unpack_rib_buffer,
 )
@@ -82,6 +82,18 @@ log = logging.getLogger(__name__)
 # slots repeat the last (row, col) and a duplicate .set of the same
 # INF_DIST is a no-op. Cones beyond the top tier chunk by it.
 _WARM_SCATTER_TIERS = (8192, 131_072, 1_048_576)
+
+#: TpuSpfSolver.last_phase_ms as a view of a compute_routes call's span
+#: record: six phases that follow one another and cover the call (only
+#: one of the three solve spans runs in a call)
+_PHASE_SPANS = {
+    "prepare": ("spf:prepare",),
+    "solve": ("spf:batched_solve", "spf:batched_dist", "spf:native_solve"),
+    "unpack": ("spf:unpack",),
+    "election": ("spf:rib_election",),
+    "assembly": ("spf:rib_unicast",),
+    "mpls": ("spf:rib_mpls",),
+}
 
 
 def _warm_scatter_pad(n: int) -> int:
@@ -283,7 +295,12 @@ class TpuSpfSolver:
         # observability: full table (re)builds+uploads vs in-place patch
         # scatters vs pure hits — under metric-only churn, `uploads`
         # must stay flat after warmup (tested)
-        self.dev_cache_stats = {"uploads": 0, "patches": 0, "hits": 0}
+        # scatter_calls: eager scatter programs dispatched (table
+        # patches + the warm start's INF scatters), each a host→device
+        # round of its own
+        self.dev_cache_stats = {
+            "uploads": 0, "patches": 0, "hits": 0, "scatter_calls": 0,
+        }
         # observability for the split kernel's regime picks (round-3
         # verdict weak 5: GS chunking must never disable SILENTLY):
         # gs_active / gs_disabled count batched solves by whether dense
@@ -294,9 +311,20 @@ class TpuSpfSolver:
         # `self.platform`, or the C++ host solver (_use_native) — so a
         # "tpu" backend that never touched the device is visible.
         # Surfaced as decision.spf.* counters.
+        # The kernel's own loop counters, read from the packed
+        # buffer's trailer (ops.spf_split.rib_buffer_trailer), add up
+        # here: dense_sweeps / tail_rounds / net_sweeps / tail_spills of
+        # cold solves; the last three warm_-prefixed of warm starts,
+        # which run no dense sweep. A spill or a net sweep means the
+        # tail's frontier cap was too small (docs/Monitor.md "Spans").
+        # warm_cone_cells sizes the warm start's host-side cone walk.
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
+            "dense_sweeps": 0, "tail_rounds": 0, "net_sweeps": 0,
+            "tail_spills": 0,
+            "warm_tail_rounds": 0, "warm_net_sweeps": 0,
+            "warm_tail_spills": 0, "warm_cone_cells": 0,
         }
         # SPF engine invocations (kernel launch OR native solve): the
         # dirty-scoped rebuild's acceptance signal — prefix-only churn
@@ -335,8 +363,10 @@ class TpuSpfSolver:
         # device-resident advertiser matrix per election-view gen
         # (small LRU — one live gen per PrefixState lineage)
         self._elect_dev: dict = {}
-        # observability: last assembly's phase split (the bench's
-        # rib_election_ms / rib_assembly_ms) and election shape counts
+        # observability: the last compute_routes call as six phases
+        # that follow one another and cover it (prepare, solve, unpack,
+        # election, assembly, mpls) — a view of the call's span record
+        # (_PHASE_SPANS) — and election shape counts
         self.last_phase_ms: dict[str, float] = {}
         self.elect_stats = {
             "plain": 0, "multi": 0, "complex": 0, "device_elections": 0,
@@ -431,66 +461,68 @@ class TpuSpfSolver:
         done = cache.get("journal_len", 0)
         if len(csr.patches) > done:
             self.dev_cache_stats["patches"] += 1
-            new_patches = list(csr.patches[done:])
-            # pad the patch arrays to a bucket (repeating the last patch
-            # — duplicate .set of the same value is a no-op): without
-            # this, every distinct patch COUNT is a new traced shape and
-            # the scatter re-compiles on every churn rebuild
-            # (~130 ms/cycle measured in round 1)
-            n = len(new_patches)
-            nb = pad_batch(n)
-            patches = new_patches + [new_patches[-1]] * (nb - n)
-            rows = np.array([p.dense_row for p in patches], np.int32)
-            cols = np.array([p.dense_col for p in patches], np.int32)
-            idxs = np.array([p.edge_idx for p in patches], np.int32)
-            vals = np.array([p.metric for p in patches], np.int32)
-            for name, dset in cache["sets"].items():
-                if name == "dense":
-                    dset["wgt"] = (
-                        dset["wgt"]
-                        .at[jnp.asarray(rows), jnp.asarray(cols)]
-                        .set(jnp.asarray(vals))
-                    )
-                elif name == "edge":
-                    dset["metric"] = (
-                        dset["metric"]
-                        .at[jnp.asarray(idxs)]
-                        .set(jnp.asarray(vals))
-                    )
-                elif name == "split":
-                    h = cache["host"]["split"]
-                    w, ov_pos = h["base_w"], h["ov_pos"]
-                    if dset.get("uniform_metric") and bool(
-                        (vals != dset["uniform_metric"]).any()
-                    ):
-                        dset["uniform_metric"] = 0
-                    in_base = cols < w
-                    if in_base.any():
-                        # no-op pad target: repeat the first base patch
-                        br = np.where(in_base, rows, rows[in_base][0])
-                        bc = np.where(in_base, cols, cols[in_base][0])
-                        bv = np.where(in_base, vals, vals[in_base][0])
-                        dset["base_wgt"] = (
-                            dset["base_wgt"]
-                            .at[jnp.asarray(br), jnp.asarray(bc)]
-                            .set(jnp.asarray(bv))
+            with profiling.annotate("spf:patch_scatter"):
+                new_patches = list(csr.patches[done:])
+                # pad the patch arrays to a bucket (repeating the last patch
+                # — duplicate .set of the same value is a no-op): without
+                # this, every distinct patch COUNT is a new traced shape and
+                # the scatter re-compiles on every churn rebuild
+                # (~130 ms/cycle measured in round 1)
+                n = len(new_patches)
+                nb = pad_batch(n)
+                patches = new_patches + [new_patches[-1]] * (nb - n)
+                rows = np.array([p.dense_row for p in patches], np.int32)
+                cols = np.array([p.dense_col for p in patches], np.int32)
+                idxs = np.array([p.edge_idx for p in patches], np.int32)
+                vals = np.array([p.metric for p in patches], np.int32)
+                for name, dset in cache["sets"].items():
+                    if name == "dense":
+                        dset["wgt"] = self._eager_set(
+                            dset["wgt"], (rows, cols), vals
                         )
-                    if (~in_base).any():
-                        sel = ~in_base
-                        orow = np.where(
-                            sel, ov_pos[rows], ov_pos[rows[sel][0]]
+                    elif name == "edge":
+                        dset["metric"] = self._eager_set(
+                            dset["metric"], (idxs,), vals
                         )
-                        ocol = np.where(
-                            sel, cols - w, cols[sel][0] - w
-                        )
-                        ov = np.where(sel, vals, vals[sel][0])
-                        dset["ov_wgt"] = (
-                            dset["ov_wgt"]
-                            .at[jnp.asarray(orow), jnp.asarray(ocol)]
-                            .set(jnp.asarray(ov))
-                        )
+                    elif name == "split":
+                        h = cache["host"]["split"]
+                        w, ov_pos = h["base_w"], h["ov_pos"]
+                        if dset.get("uniform_metric") and bool(
+                            (vals != dset["uniform_metric"]).any()
+                        ):
+                            dset["uniform_metric"] = 0
+                        in_base = cols < w
+                        if in_base.any():
+                            # no-op pad target: repeat the first base patch
+                            br = np.where(in_base, rows, rows[in_base][0])
+                            bc = np.where(in_base, cols, cols[in_base][0])
+                            bv = np.where(in_base, vals, vals[in_base][0])
+                            dset["base_wgt"] = self._eager_set(
+                                dset["base_wgt"], (br, bc), bv
+                            )
+                        if (~in_base).any():
+                            sel = ~in_base
+                            orow = np.where(
+                                sel, ov_pos[rows], ov_pos[rows[sel][0]]
+                            )
+                            ocol = np.where(
+                                sel, cols - w, cols[sel][0] - w
+                            )
+                            ov = np.where(sel, vals, vals[sel][0])
+                            dset["ov_wgt"] = self._eager_set(
+                                dset["ov_wgt"], (orow, ocol), ov
+                            )
             cache["journal_len"] = len(csr.patches)
         cache["version"] = csr.version
+
+    def _eager_set(self, arr, index: tuple, values):
+        """`arr.at[index].set(values)` from host index arrays, dispatched
+        eagerly: one scatter program and a dozen tiny index-shaping ones
+        a call, counted as dev_cache_stats["scatter_calls"]."""
+        self.dev_cache_stats["scatter_calls"] += 1
+        if isinstance(values, np.ndarray):
+            values = jnp.asarray(values)
+        return arr.at[tuple(jnp.asarray(i) for i in index)].set(values)
 
     def trim_caches(self, fingerprint_cap: int = 8) -> None:
         """Reclaim assembly-cache memory (e.g. after a fleet pass on a
@@ -546,10 +578,22 @@ class TpuSpfSolver:
         solve — cold, warm, fleet — passes here exactly once, so this
         is where the device engine is counted."""
         self.spf_kernel_stats["engine_device"] += 1
-        table = self._pick_table(csr)
-        dev = self._device_arrays(csr, table)
-        has_over = bool(csr.node_overloaded.any())
+        with profiling.annotate("spf:dispatch"):
+            table = self._pick_table(csr)
+            dev = self._device_arrays(csr, table)
+            has_over = bool(csr.node_overloaded.any())
         return table, dev, has_over
+
+    def _count_kernel_loops(self, buf: np.ndarray, prefix: str = "") -> None:
+        """Add the packed buffer's trailer to spf_kernel_stats (`prefix`
+        "warm_": the warm kernel, which runs no dense sweep)."""
+        got = rib_buffer_trailer(buf)
+        st = self.spf_kernel_stats
+        if not prefix:
+            st["dense_sweeps"] += got["dense_sweeps"]
+        st[prefix + "tail_rounds"] += got["tail_rounds"]
+        st[prefix + "net_sweeps"] += got["net_sweeps"]
+        st[prefix + "tail_spills"] += got["spilled"]
 
     def _solve_dist(
         self, csr, roots: np.ndarray, _dispatched: tuple | None = None
@@ -767,45 +811,58 @@ class TpuSpfSolver:
           * the batched TPU kernel ({self} ∪ neighbors roots) with the
             elementwise first-hop identity — the batched/LFA path.
         """
-        csr = ls.to_csr()
-        my_id = csr.name_to_id.get(my_node)
-        if my_id is None:
-            return None
-        self.solve_count += 1
-        nbr_key = (csr.base_version, my_id)
-        nbr_ids = self._nbr_cache.get(nbr_key)
-        if nbr_ids is None:
-            nbr_ids = sorted(d for (s, d) in csr.adj_details if s == my_id)
-            self._nbr_cache[nbr_key] = nbr_ids
-            while len(self._nbr_cache) > 4 * self._dev_lru_cap:
-                self._nbr_cache.pop(next(iter(self._nbr_cache)))
-        n = len(nbr_ids)
-        b = pad_batch(1 + n)
-        nbr_metric_real = np.empty(n, dtype=np.int32)
-        for i, d in enumerate(nbr_ids):
-            # same METRIC_MAX clamp as the CSR builder / oracle, or the
-            # first-hop identity breaks for metrics above the clamp
-            nbr_metric_real[i] = min(
-                min(det[1] for det in csr.details(my_id, d)),
-                METRIC_MAX,
-            )
+        with profiling.annotate("spf:prepare"):
+            with profiling.annotate("spf:to_csr"):
+                csr = ls.to_csr()
+            my_id = csr.name_to_id.get(my_node)
+            if my_id is None:
+                return None
+            self.solve_count += 1
+            nbr_key = (csr.base_version, my_id)
+            nbr_ids = self._nbr_cache.get(nbr_key)
+            if nbr_ids is None:
+                nbr_ids = sorted(
+                    d for (s, d) in csr.adj_details if s == my_id
+                )
+                self._nbr_cache[nbr_key] = nbr_ids
+                while len(self._nbr_cache) > 4 * self._dev_lru_cap:
+                    self._nbr_cache.pop(next(iter(self._nbr_cache)))
+            n = len(nbr_ids)
+            b = pad_batch(1 + n)
+            nbr_metric_real = np.empty(n, dtype=np.int32)
+            for i, d in enumerate(nbr_ids):
+                # same METRIC_MAX clamp as the CSR builder / oracle, or
+                # the first-hop identity breaks for metrics above the
+                # clamp
+                nbr_metric_real[i] = min(
+                    min(det[1] for det in csr.details(my_id, d)),
+                    METRIC_MAX,
+                )
+            native = self._use_native()
+            if native:
+                self.spf_kernel_stats["engine_native"] += 1
+                oc = self._native_out_csr(csr)
+            else:
+                roots, nbr_ids_p, nbr_metric, nbr_over = (
+                    self._rib_pad_arrays(
+                        csr, my_id, nbr_ids, nbr_metric_real, b
+                    )
+                )
+                table, dev, has_over = self._dispatch(csr)
 
-        if self._use_native():
-            self.spf_kernel_stats["engine_native"] += 1
-            oc = self._native_out_csr(csr)
-            d1, fh_n = oc.rib_solve(
-                my_id, np.array(nbr_ids, dtype=np.int32), nbr_metric_real
-            )
-            dist = d1[:, None]  # [Vp, 1]: column 0 = root, like the batch
-            fh = np.zeros((b - 1, d1.shape[0]), dtype=bool)
-            fh[:n] = fh_n
+        if native:
+            with profiling.annotate("spf:native_solve"):
+                d1, fh_n = oc.rib_solve(
+                    my_id, np.array(nbr_ids, dtype=np.int32),
+                    nbr_metric_real,
+                )
+            with profiling.annotate("spf:unpack"):
+                # [Vp, 1]: column 0 = root, like the batch
+                dist = d1[:, None]
+                fh = np.zeros((b - 1, d1.shape[0]), dtype=bool)
+                fh[:n] = fh_n
             return csr, dist, fh, nbr_ids, None
 
-        roots, nbr_ids_p, nbr_metric, nbr_over = self._rib_pad_arrays(
-            csr, my_id, nbr_ids, nbr_metric_real, b
-        )
-
-        table, dev, has_over = self._dispatch(csr)
         if table == "split":
             # fused single-dispatch path with packed outputs: ~0.8 MB
             # instead of ~16 MB of device→host traffic per rebuild at
@@ -842,7 +899,11 @@ class TpuSpfSolver:
                 ),
                 span="spf:batched_solve",
             )
-            d_root, fh, lfa = unpack_rib_buffer(buf, vp, b, self.enable_lfa)
+            with profiling.annotate("spf:unpack"):
+                d_root, fh, lfa = unpack_rib_buffer(
+                    buf, vp, b, self.enable_lfa
+                )
+                self._count_kernel_loops(buf)
             return csr, _LazyDist(dist_dev, d_root), fh, nbr_ids, lfa
 
         # distinct span from the fused split-RIB path's
@@ -855,14 +916,15 @@ class TpuSpfSolver:
             dist = self._solve_dist(
                 csr, roots, _dispatched=(table, dev, has_over)
             )
-        fh = np.asarray(
-            first_hop_matrix(
-                dist,
-                jnp.asarray(nbr_metric),
-                jnp.asarray(nbr_ids_p),
-                jnp.asarray(nbr_over),
+        with profiling.annotate("spf:unpack"):
+            fh = np.asarray(
+                first_hop_matrix(
+                    dist,
+                    jnp.asarray(nbr_metric),
+                    jnp.asarray(nbr_ids_p),
+                    jnp.asarray(nbr_over),
+                )
             )
-        )
         device_telemetry.observe(
             "first_hop_matrix",
             lambda: first_hop_matrix.lower(
@@ -927,11 +989,24 @@ class TpuSpfSolver:
         `assemble_prefix_routes` can re-assemble touched prefixes under
         prefix-only churn with zero new kernel launches."""
         rdb = RouteDatabase(this_node_name=my_node)
-        solved = self.solve(ls, my_node)
+        # the call's own record (handed on to the caller's, if one is
+        # open): last_phase_ms is read from it, not timed a second time
+        with profiling.collect() as rec:
+            solved = self.solve(ls, my_node)
+            if solved is not None:
+                with profiling.annotate(
+                    "spf:rib_assembly", counters=self.counters
+                ):
+                    rdb = self._assemble_routes(
+                        rdb, ls, ps, my_node, solved
+                    )
+        ms = rec.ms
+        self.last_phase_ms = {
+            phase: sum(ms.get(name, 0.0) for name in spans)
+            for phase, spans in _PHASE_SPANS.items()
+        }
         if solved is None:
             return (rdb, None) if return_artifact else rdb
-        with profiling.annotate("spf:rib_assembly", counters=self.counters):
-            rdb = self._assemble_routes(rdb, ls, ps, my_node, solved)
         if return_artifact:
             return rdb, SolveArtifact(
                 my_node=my_node, ls=ls, ksp_k=self.ksp_k, solved=solved
@@ -1103,7 +1178,8 @@ class TpuSpfSolver:
         old_csr, old_dist, old_fh, nbr_ids, lfa = art.solved
         if lfa is not None or not isinstance(old_dist, _LazyDist):
             return None  # native/dense-path artifact: no warm columns
-        csr = ls.to_csr()
+        with profiling.annotate("spf:to_csr"):
+            csr = ls.to_csr()
         if csr.base_version != old_csr.base_version:
             return None  # structural change: interning/base moved
         if self._pick_table(csr) != "split":
@@ -1153,14 +1229,19 @@ class TpuSpfSolver:
             changed_ids = np.zeros(0, np.int64)
             region = 0
         else:
-            old_mat = np.asarray(old_dist)  # cached host mirror
+            with profiling.annotate("spf:dist_mirror"):
+                # host mirror of the [vp, B] matrix, fetched once per
+                # artifact: every warm start makes a new artifact
+                old_mat = np.asarray(old_dist)
             roots_real = [my_id, *nbr_ids]
-            cone = self._warm_cone(
-                old_csr, old_mat, changes, roots_real, cells_budget
-            )
+            with profiling.annotate("spf:warm_cone"):
+                cone = self._warm_cone(
+                    old_csr, old_mat, changes, roots_real, cells_budget
+                )
             if cone is None:
                 return None
             rows_all, cols_all, seed, cone_union = cone
+            self.spf_kernel_stats["warm_cone_cells"] += len(rows_all)
             _table, dev, has_over = self._dispatch(csr)
             vp = dev["vp"]
             nbr_metric_real = np.empty(len(nbr_ids), dtype=np.int32)
@@ -1173,19 +1254,21 @@ class TpuSpfSolver:
                 csr, my_id, nbr_ids, nbr_metric_real, bb
             )
             dist_dev = old_dist._dev
-            if rows_all:
-                n_sc = len(rows_all)
-                nb = _warm_scatter_pad(n_sc)
-                rows = np.full(nb, rows_all[-1], np.int32)
-                rows[:n_sc] = rows_all
-                cols = np.full(nb, cols_all[-1], np.int32)
-                cols[:n_sc] = cols_all
-                top = _WARM_SCATTER_TIERS[-1]
-                for off in range(0, nb, top):
-                    dist_dev = dist_dev.at[
-                        jnp.asarray(rows[off : off + top]),
-                        jnp.asarray(cols[off : off + top]),
-                    ].set(INF_DIST)
+            with profiling.annotate("spf:warm_scatter"):
+                if rows_all:
+                    n_sc = len(rows_all)
+                    nb = _warm_scatter_pad(n_sc)
+                    rows = np.full(nb, rows_all[-1], np.int32)
+                    rows[:n_sc] = rows_all
+                    cols = np.full(nb, cols_all[-1], np.int32)
+                    cols[:n_sc] = cols_all
+                    top = _WARM_SCATTER_TIERS[-1]
+                    for off in range(0, nb, top):
+                        dist_dev = self._eager_set(
+                            dist_dev,
+                            (rows[off : off + top], cols[off : off + top]),
+                            INF_DIST,
+                        )
             gs = pick_gs_chunks(vp)
             with profiling.annotate("spf:warm_solve", counters=self.counters):
                 dist_dev2, packed = batched_sssp_split_warm_rib(
@@ -1212,420 +1295,428 @@ class TpuSpfSolver:
                 ),
                 span="spf:warm_solve",
             )
-            d_root, fh, _ = unpack_rib_buffer(buf, vp, bb, False)
-            self.solve_count += 1
-            self.warm_solves += 1
-            n_live = len(csr.node_names)
-            old_d_root = old_dist._d_root
-            changed = (
-                d_root[:n_live] != old_d_root[:n_live]
-            ) | (fh[:, :n_live] != old_fh[:, :n_live]).any(axis=0)
-            changed_ids = np.nonzero(changed)[0]
-            region = len(cone_union | set(changed_ids.tolist()))
+            with profiling.annotate("spf:warm_unpack"):
+                d_root, fh, _ = unpack_rib_buffer(buf, vp, bb, False)
+                self._count_kernel_loops(buf, "warm_")
+                self.solve_count += 1
+                self.warm_solves += 1
+                n_live = len(csr.node_names)
+                old_d_root = old_dist._d_root
+                changed = (
+                    d_root[:n_live] != old_d_root[:n_live]
+                ) | (fh[:, :n_live] != old_fh[:, :n_live]).any(axis=0)
+                changed_ids = np.nonzero(changed)[0]
+                region = len(cone_union | set(changed_ids.tolist()))
             solved2 = (csr, _LazyDist(dist_dev2, d_root), fh, nbr_ids, None)
             art2 = SolveArtifact(
                 my_node=my_node, ls=ls, ksp_k=self.ksp_k, solved=solved2
             )
 
         # ---- scoped reassembly ---------------------------------------
-        _c2, dist2, fh2, _n2, _l2 = art2.solved
-        d_root2 = dist2[:, 0]
-        n_live = len(csr.node_names)
-        changed_mask = np.zeros(csr.padded_nodes, bool)
-        changed_mask[changed_ids] = True
-        view = ps.election_view(csr.name_to_id, csr.base_version)
-        touched = set(prefix_dirt)
-        if len(view.plain_p):
-            for i in np.nonzero(changed_mask[view.orig])[0]:
-                touched.add(view.plain_p[int(i)])
-        if view.multi is not None:
-            # anycast ECMP: the election outcome depends only on its
-            # advertisers' (dist, first-hop) classes — scope by the
-            # advertiser matrix instead of re-assembling all of them
-            t = view.multi
-            hit = t.known & changed_mask[t.adv]
-            for i in np.unique(t.seg[hit]).tolist():
-                touched.add(t.prefixes[i])
-        for p, _per in view.complex_items:
-            # UCMP/KSP/constrained prefixes: KSP depends on the whole
-            # graph and the rest are cheap — always re-assemble (exact)
-            touched.add(p)
-        entries = self.assemble_prefix_routes(art2, ps, touched)
-        rdb = RouteDatabase(this_node_name=my_node)
-        rdb.unicast_routes = dict(cached_rdb.unicast_routes)
-        rdb.mpls_routes = dict(cached_rdb.mpls_routes)
-        for p in touched:
-            e = entries.get(p)
-            if e is None:
-                rdb.unicast_routes.pop(p, None)
-            else:
-                rdb.unicast_routes[p] = e
-        if len(changed_ids):
-            labels_v = self._node_labels(ls, csr, n_live)
-            slot_cache = self._nbr_slot_cache(csr, my_id, nbr_ids)
-            mk = self._mk_nexthops_cached_factory(fh2, slot_cache, ls.area)
-            for i in changed_ids.tolist():
-                if i == my_id:
-                    continue
-                label = int(labels_v[i])
-                if label < MPLS_LABEL_MIN:
-                    continue
-                touched_labels.add(label)
-                node = csr.node_names[i]
-                if d_root2[i] >= INF_DIST or not fh2[:, i].any():
-                    rdb.mpls_routes.pop(label, None)
-                    continue
-                igp = int(d_root2[i])
-                nhs = self._mpls_wrap(mk(np.array([i]), igp), node, label)
-                if nhs:
-                    rdb.mpls_routes[label] = RibMplsEntry(
-                        label=label, nexthops=nhs
-                    )
+        with profiling.annotate("spf:warm_reassemble"):
+            _c2, dist2, fh2, _n2, _l2 = art2.solved
+            d_root2 = dist2[:, 0]
+            n_live = len(csr.node_names)
+            changed_mask = np.zeros(csr.padded_nodes, bool)
+            changed_mask[changed_ids] = True
+            view = ps.election_view(csr.name_to_id, csr.base_version)
+            touched = set(prefix_dirt)
+            if len(view.plain_p):
+                for i in np.nonzero(changed_mask[view.orig])[0]:
+                    touched.add(view.plain_p[int(i)])
+            if view.multi is not None:
+                # anycast ECMP: the election outcome depends only on its
+                # advertisers' (dist, first-hop) classes — scope by the
+                # advertiser matrix instead of re-assembling all of them
+                t = view.multi
+                hit = t.known & changed_mask[t.adv]
+                for i in np.unique(t.seg[hit]).tolist():
+                    touched.add(t.prefixes[i])
+            for p, _per in view.complex_items:
+                # UCMP/KSP/constrained prefixes: KSP depends on the whole
+                # graph and the rest are cheap — always re-assemble (exact)
+                touched.add(p)
+            entries = self.assemble_prefix_routes(art2, ps, touched)
+            rdb = RouteDatabase(this_node_name=my_node)
+            rdb.unicast_routes = dict(cached_rdb.unicast_routes)
+            rdb.mpls_routes = dict(cached_rdb.mpls_routes)
+            for p in touched:
+                e = entries.get(p)
+                if e is None:
+                    rdb.unicast_routes.pop(p, None)
                 else:
-                    rdb.mpls_routes.pop(label, None)
+                    rdb.unicast_routes[p] = e
+            if len(changed_ids):
+                labels_v = self._node_labels(ls, csr, n_live)
+                slot_cache = self._nbr_slot_cache(csr, my_id, nbr_ids)
+                mk = self._mk_nexthops_cached_factory(fh2, slot_cache, ls.area)
+                for i in changed_ids.tolist():
+                    if i == my_id:
+                        continue
+                    label = int(labels_v[i])
+                    if label < MPLS_LABEL_MIN:
+                        continue
+                    touched_labels.add(label)
+                    node = csr.node_names[i]
+                    if d_root2[i] >= INF_DIST or not fh2[:, i].any():
+                        rdb.mpls_routes.pop(label, None)
+                        continue
+                    igp = int(d_root2[i])
+                    nhs = self._mpls_wrap(mk(np.array([i]), igp), node, label)
+                    if nhs:
+                        rdb.mpls_routes[label] = RibMplsEntry(
+                            label=label, nexthops=nhs
+                        )
+                    else:
+                        rdb.mpls_routes.pop(label, None)
         return rdb, art2, touched, touched_labels, region
 
     def _assemble_routes(self, rdb, ls, ps, my_node, solved):
-        t_elect0 = time.perf_counter()
-        csr, dist, fh, nbr_ids, lfa = solved
-        my_id = csr.name_to_id[my_node]
-        d_root = dist[:, 0]  # [Vp]
-        # hoisted out of the per-prefix loop: "does ANY neighbor serve as
-        # a first hop toward node X" is O(B) per node — scanning it per
-        # prefix made RIB assembly O(P·B·V) and dominated churn rebuilds
-        fh_any = fh.any(axis=0)  # [Vp]
-        slot_cache = self._nbr_slot_cache(csr, my_id, nbr_ids)
-        mk_nexthops_cached = self._mk_nexthops_cached_factory(
-            fh, slot_cache, ls.area
-        )
+        with profiling.annotate("spf:rib_election"):
+            csr, dist, fh, nbr_ids, lfa = solved
+            my_id = csr.name_to_id[my_node]
+            d_root = dist[:, 0]  # [Vp]
+            # hoisted out of the per-prefix loop: "does ANY neighbor serve as
+            # a first hop toward node X" is O(B) per node — scanning it per
+            # prefix made RIB assembly O(P·B·V) and dominated churn rebuilds
+            fh_any = fh.any(axis=0)  # [Vp]
+            slot_cache = self._nbr_slot_cache(csr, my_id, nbr_ids)
+            mk_nexthops_cached = self._mk_nexthops_cached_factory(
+                fh, slot_cache, ls.area
+            )
 
-        # per-destination-node (first-hop column, igp) equivalence
-        # classes, computed ONCE and shared by the plain-prefix and MPLS
-        # sections: dest_cls[i] is node i's class, dest_tokens[c] a
-        # content-stable hashable token (survives rebuilds — it encodes
-        # the column bits + igp, so cross-rebuild caches can key on it)
-        n_live = len(csr.node_names)
-        dest_cls, dest_tokens = _dest_classes(fh, d_root, n_live)
+            # per-destination-node (first-hop column, igp) equivalence
+            # classes, computed ONCE and shared by the plain-prefix and MPLS
+            # sections: dest_cls[i] is node i's class, dest_tokens[c] a
+            # content-stable hashable token (survives rebuilds — it encodes
+            # the column bits + igp, so cross-rebuild caches can key on it)
+            n_live = len(csr.node_names)
+            dest_cls, dest_tokens = _dest_classes(fh, d_root, n_live)
 
-        # ---- unicast: plain prefixes, vectorized --------------------------
-        # The dominant RIB shape is "one advertiser, SP_ECMP, no
-        # constraints" (every loopback in the fabric). PrefixState
-        # pre-classifies those (cached across churn), and their routes
-        # assemble here in bulk: reachability/IGP as numpy vectors, and
-        # NextHop construction deduplicated by unique (first-hop-column,
-        # igp) classes — in a fat-tree thousands of prefixes collapse to
-        # a handful of classes. The general per-prefix loop below keeps
-        # every other case (anycast, UCMP, KSP, min_nexthop, LFA).
-        view = ps.election_view(csr.name_to_id, csr.base_version)
-        plain_p, plain_n, plain_e = view.plain_p, view.plain_n, view.plain_e
-        orig, complex_items, view_gen = view.orig, view.complex_items, view.gen
-        multi = view.multi
-        if lfa is not None:
-            # LFA backups are per-target, not per-class — every prefix
-            # takes the general scalar loop when LFA is enabled (the
-            # fallback matrix in docs/Decision.md)
-            merged = list(complex_items)
+            # ---- unicast: plain prefixes, vectorized ----------------------
+            # The dominant RIB shape is "one advertiser, SP_ECMP, no
+            # constraints" (every loopback in the fabric). PrefixState
+            # pre-classifies those (cached across churn), and their routes
+            # assemble here in bulk: reachability/IGP as numpy vectors, and
+            # NextHop construction deduplicated by unique (first-hop-column,
+            # igp) classes — in a fat-tree thousands of prefixes collapse to
+            # a handful of classes. The general per-prefix loop below keeps
+            # every other case (anycast, UCMP, KSP, min_nexthop, LFA).
+            view = ps.election_view(csr.name_to_id, csr.base_version)
+            plain_p, plain_n = view.plain_p, view.plain_n
+            plain_e, orig = view.plain_e, view.orig
+            complex_items, view_gen = view.complex_items, view.gen
+            multi = view.multi
+            if lfa is not None:
+                # LFA backups are per-target, not per-class — every prefix
+                # takes the general scalar loop when LFA is enabled (the
+                # fallback matrix in docs/Decision.md)
+                merged = list(complex_items)
+                if len(plain_p):
+                    merged += [
+                        (p, {plain_n[i]: plain_e[i]})
+                        for i, p in enumerate(plain_p)
+                    ]
+                if multi is not None:
+                    merged += multi_items(multi)
+                complex_items = sorted(merged)
+                multi = None
+                plain_p = []
+            self.elect_stats["plain"] = len(plain_p)
+            self.elect_stats["multi"] = (
+                len(multi.prefixes) if multi is not None else 0
+            )
+            self.elect_stats["complex"] = len(complex_items)
+            # work ledger election stage (full solve): delta = electable
+            # prefixes, touched = candidate advertiser slots — the ratio is
+            # the mean advertisers-per-prefix, bounded by topology fanout
+            n_elect = (
+                len(plain_p)
+                + self.elect_stats["multi"]
+                + len(complex_items)
+            )
+            work_ledger.commit(
+                "election",
+                len(plain_p)
+                + (len(multi.adv) if multi is not None else 0)
+                + sum(len(pn) for _p, pn in complex_items),
+                n_elect,
+            )
+            # multi-advertiser election: the masked argmax/argmin over the
+            # prefix→advertiser matrix (device-side segmented reductions
+            # past elect_device_min slots, NumPy below — byte-equal)
+            mel = None
+            if multi is not None and len(multi.prefixes):
+                mel = self._elect_multi(multi, d_root, fh_any, my_id, view_gen)
+            # fingerprint for every cross-rebuild assembly cache: my own
+            # adjacency slot details (interface names, min-metric parallel
+            # links), which the fh column alone can't see
+            slot_gen = (ls.area, tuple(tuple(s) for s in slot_cache))
             if len(plain_p):
-                merged += [
-                    (p, {plain_n[i]: plain_e[i]})
-                    for i, p in enumerate(plain_p)
-                ]
-            if multi is not None:
-                merged += multi_items(multi)
-            complex_items = sorted(merged)
-            multi = None
-            plain_p = []
-        self.elect_stats["plain"] = len(plain_p)
-        self.elect_stats["multi"] = (
-            len(multi.prefixes) if multi is not None else 0
-        )
-        self.elect_stats["complex"] = len(complex_items)
-        # work ledger election stage (full solve): delta = electable
-        # prefixes, touched = candidate advertiser slots — the ratio is
-        # the mean advertisers-per-prefix, bounded by topology fanout
-        n_elect = (
-            len(plain_p)
-            + self.elect_stats["multi"]
-            + len(complex_items)
-        )
-        work_ledger.commit(
-            "election",
-            len(plain_p)
-            + (len(multi.adv) if multi is not None else 0)
-            + sum(len(pn) for _p, pn in complex_items),
-            n_elect,
-        )
-        # multi-advertiser election: the masked argmax/argmin over the
-        # prefix→advertiser matrix (device-side segmented reductions
-        # past elect_device_min slots, NumPy below — byte-equal)
-        mel = None
-        if multi is not None and len(multi.prefixes):
-            mel = self._elect_multi(multi, d_root, fh_any, my_id, view_gen)
-        # fingerprint for every cross-rebuild assembly cache: my own
-        # adjacency slot details (interface names, min-metric parallel
-        # links), which the fh column alone can't see
-        slot_gen = (ls.area, tuple(tuple(s) for s in slot_cache))
-        if len(plain_p):
-            reach = (
-                (d_root[orig] < INF_DIST) & fh_any[orig] & (orig != my_id)
-            )
-            igp = d_root[orig].astype(np.int64)
-            idxs = np.nonzero(reach)[0]
-            cls = dest_cls[orig[idxs]]  # shared per-node classification
-            ucls, uidx = np.unique(cls, return_index=True)
-            class_nhs = {}
-            for c, u in zip(ucls.tolist(), uidx.tolist()):
-                i = idxs[u]
-                class_nhs[c] = self._mk_nexthops_union(
-                    slot_cache, fh[:, orig[i]], int(igp[i]), ls.area
+                reach = (
+                    (d_root[orig] < INF_DIST) & fh_any[orig] & (orig != my_id)
                 )
-        t_asm0 = time.perf_counter()
-        self.last_phase_ms = {"election": (t_asm0 - t_elect0) * 1e3}
-        cell = None
-        if len(plain_p) or mel is not None:
-            # cross-rebuild RibEntry caches (same shape as the MPLS
-            # entry cache below): under churn most plain prefixes keep
-            # the same (first-hop set, igp) class, and the frozen
-            # RibEntry can be reused as-is — which also lets the
-            # Decision/Fib diffs skip field-by-field equality via
-            # identity. Three levels, all scoped to the slot fingerprint
-            # and the solver_view generation:
-            #   entries:    (view row, class token) → RibEntry
-            #   classdicts: (token, membership fp) → {prefix: RibEntry}
-            #   plain/multi: content signature → the WHOLE assembled
-            #                dict of the section — a steady-state
-            #                rebuild whose election outcome is
-            #                byte-identical re-lands the section as one
-            #                C-speed dict.update, no per-class loop
-            cell = self._uni_cache.pop(slot_gen, None)
-            if cell is None or cell.get("gen") != view_gen:
-                cell = {"gen": view_gen, "entries": {}, "classdicts": {}}
-            self._uni_cache[slot_gen] = cell
-            while len(self._uni_cache) > self._mpls_fingerprint_cap:
-                self._uni_cache.pop(next(iter(self._uni_cache)))
-        if len(plain_p):
-            entries = cell["entries"]
-            classdicts = cell["classdicts"]
-            if len(entries) > max(8192, 4 * len(plain_p)):
-                entries.clear()
-                classdicts.clear()
-                cell.pop("plain", None)
-                cell["cd_total"] = 0
-            # content signature of this rebuild's entire plain section:
-            # membership rows + their class ids + the CONTENT tokens of
-            # every used class (tokens encode first-hop bits + igp, and
-            # the gen guard pins the view arrays the rows index)
-            sig = (
-                idxs.tobytes(),
-                cls.tobytes(),
-                tuple(dest_tokens[int(c)] for c in ucls),
-            )
-            cached_plain = cell.get("plain")
-            unicast = rdb.unicast_routes
-            if cached_plain is not None and cached_plain[0] == sig:
-                unicast.update(cached_plain[1])
-            else:
-                plain_dict: dict = {}
-                for g in _class_groups(cls):
-                    c = int(cls[g[0]])
-                    nhs = class_nhs[c]
-                    if not nhs:
-                        continue
-                    rows = idxs[g]
-                    token = dest_tokens[c]
-                    # membership keyed by the BYTES (not their hash): a
-                    # 64-bit hash collision would silently install
-                    # another class's routes — unacceptable for a RIB
-                    gkey = (token, rows.tobytes())
-                    sub = classdicts.get(gkey)
-                    if sub is None:
-                        sub = {}
-                        igp_c = int(igp[rows[0]])
-                        for i in rows.tolist():
-                            key = (i, token)
-                            e = entries.get(key)
-                            if e is None:
-                                p = plain_p[i]
-                                e = RibEntry(
-                                    prefix=p,
-                                    nexthops=nhs,
-                                    best_node=plain_n[i],
-                                    best_nodes=(plain_n[i],),
-                                    best_entry=plain_e[i],
-                                    igp_cost=igp_c,
-                                )
-                                entries[key] = e
-                            sub[e.prefix] = e
-                        # bound by TOTAL cached route objects, not key
-                        # count: under churn every rebuild mints new
-                        # tokens and each stale key pins a whole sub-dict
-                        cell["cd_total"] = cell.get("cd_total", 0) + len(sub)
-                        if cell["cd_total"] > 4 * max(len(plain_p), 4096):
-                            classdicts.clear()
-                            cell["cd_total"] = len(sub)
-                        classdicts[gkey] = sub
-                    plain_dict.update(sub)
-                cell["plain"] = (sig, plain_dict)
-                unicast.update(plain_dict)
-
-        # ---- unicast: elected multi-advertiser (anycast ECMP) ------------
-        # entry construction per surviving prefix; the nexthop union is
-        # per chosen SET via the memoized factory, so thousands of
-        # anycast prefixes to the same originator set share one group —
-        # and an unchanged election outcome (signature over the
-        # chosen/best masks + igp vector) re-lands last rebuild's
-        # entry dict wholesale, preserving identity for the diff
-        if mel is not None:
-            # the signature must cover the NEXTHOP inputs too, not just
-            # the election outcome: a remote metric change can drop one
-            # of two equal-cost paths without moving d_root or the
-            # chosen set (review finding) — the advertisers' first-hop
-            # columns are gathered into the signature so stale groups
-            # can never be re-landed
-            sig_m = (
-                mel.is_best.tobytes(),
-                mel.chosen.tobytes(),
-                mel.min_igp.tobytes(),
-                fh[:, multi.adv].tobytes(),
-            )
-            cached_m = cell.get("multi")
-            if cached_m is not None and cached_m[0] == sig_m:
-                rdb.unicast_routes.update(cached_m[1])
-            else:
-                mdict: dict = {}
-                for p, best_names, chosen_ids, chosen_names, igp_c, best_e in (
-                    iter_multi_winners(multi, mel)
-                ):
-                    nhs = mk_nexthops_cached(chosen_ids, igp_c)
-                    if not nhs:
-                        continue
-                    mdict[p] = RibEntry(
-                        prefix=p,
-                        nexthops=nhs,
-                        best_node=chosen_names[0],
-                        best_nodes=best_names,
-                        best_entry=best_e,
-                        igp_cost=igp_c,
+                igp = d_root[orig].astype(np.int64)
+                idxs = np.nonzero(reach)[0]
+                cls = dest_cls[orig[idxs]]  # shared per-node classification
+                ucls, uidx = np.unique(cls, return_index=True)
+                class_nhs = {}
+                for c, u in zip(ucls.tolist(), uidx.tolist()):
+                    i = idxs[u]
+                    class_nhs[c] = self._mk_nexthops_union(
+                        slot_cache, fh[:, orig[i]], int(igp[i]), ls.area
                     )
-                cell["multi"] = (sig_m, mdict)
-                rdb.unicast_routes.update(mdict)
-
-        # ---- unicast: general path ---------------------------------------
-        ksp_jobs = self._unicast_general(
-            csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids, lfa,
-            dist, slot_cache, mk_nexthops_cached, complex_items,
-            rdb.unicast_routes,
-        )
-        if ksp_jobs:
-            self._ksp_batch(
-                csr, ls, my_node, my_id, d_root, ksp_jobs,
-                rdb.unicast_routes,
-            )
-
-        t_mpls0 = time.perf_counter()
-        self.last_phase_ms["assembly"] = (t_mpls0 - t_asm0) * 1e3
-
-        # ---- MPLS node segments ------------------------------------------
-        # cross-rebuild cache: under churn most nodes keep the same
-        # (first-hop set, igp), so the per-node SWAP/PHP NextHop
-        # construction — the single hottest host loop in a steady-state
-        # rebuild — is skipped for every unchanged destination. Keyed by
-        # the shared `slot_gen` fingerprint computed above.
-        # re-insert to refresh the fingerprint's LRU position
-        mpls_cache = self._mpls_cache.pop(slot_gen, None) or {}
-        self._mpls_cache[slot_gen] = mpls_cache
-        # evict least-recently-used fingerprints (NOT a full wipe — the
-        # fleet path serves many roots per pass, each a fingerprint, and
-        # a wipe would defeat the cross-rebuild cache it relies on); the
-        # cap is raised by compute_fleet_ribs to cover its root count
-        while len(self._mpls_cache) > self._mpls_fingerprint_cap:
-            self._mpls_cache.pop(next(iter(self._mpls_cache)))
-        if len(mpls_cache) > max(4096, 4 * len(csr.node_names)):
-            mpls_cache.clear()
-        # vectorized per-destination eligibility; the expensive content
-        # key reuses the shared dest_cls/dest_tokens classification, so
-        # the steady-state loop is token-keyed dict hits (no per-node
-        # tobytes/hashing of columns)
-        names = csr.node_names
-        ids = np.arange(n_live, dtype=np.int64)
-        labels_v = self._node_labels(ls, csr, n_live)
-        elig = (
-            (labels_v >= MPLS_LABEL_MIN)
-            & (ids != my_id)
-            & (d_root[:n_live] < INF_DIST)
-            & fh_any[:n_live]
-        )
-        sel = np.nonzero(elig)[0]
-        mpls_routes = rdb.mpls_routes
-        # class-level sub-dict reuse, mirroring the unicast path: a
-        # destination class whose membership, labels, and (fh, igp)
-        # token are unchanged since a previous rebuild is ONE dict
-        # update. base_version is in the key because rows are node IDS
-        # (the name↔id interning changes with the topology base).
-        mcell = self._mpls_cls_cache.pop(slot_gen, None) or {
-            "groups": {}, "total": 0
-        }
-        self._mpls_cls_cache[slot_gen] = mcell
-        while len(self._mpls_cls_cache) > self._mpls_fingerprint_cap:
-            self._mpls_cls_cache.pop(next(iter(self._mpls_cls_cache)))
-        mcls = mcell["groups"]
-        cls_sel = dest_cls[sel]
-        for g in _class_groups(cls_sel):
-            rows = sel[g]
-            token = dest_tokens[int(cls_sel[g[0]])]
-            lab = labels_v[rows]
-            # bytes, not hashes, for the same reason as the unicast path
-            gkey = (csr.base_version, token, rows.tobytes(), lab.tobytes())
-            sub = mcls.get(gkey)
-            if sub is None:
-                sub = {}
-                igp = int(d_root[rows[0]])
-                for i in rows.tolist():
-                    node = names[i]
-                    label = int(labels_v[i])
-                    key = (label, node, token, igp)
-                    entry = mpls_cache.get(key)
-                    if entry is None:
-                        nhs = self._mpls_wrap(
-                            mk_nexthops_cached(np.array([i]), igp),
-                            node, label,
-                        )
+        with profiling.annotate("spf:rib_unicast"):
+            cell = None
+            if len(plain_p) or mel is not None:
+                # cross-rebuild RibEntry caches (same shape as the MPLS
+                # entry cache below): under churn most plain prefixes keep
+                # the same (first-hop set, igp) class, and the frozen
+                # RibEntry can be reused as-is — which also lets the
+                # Decision/Fib diffs skip field-by-field equality via
+                # identity. Three levels, all scoped to the slot fingerprint
+                # and the solver_view generation:
+                #   entries:    (view row, class token) → RibEntry
+                #   classdicts: (token, membership fp) → {prefix: RibEntry}
+                #   plain/multi: content signature → the WHOLE assembled
+                #                dict of the section — a steady-state
+                #                rebuild whose election outcome is
+                #                byte-identical re-lands the section as one
+                #                C-speed dict.update, no per-class loop
+                cell = self._uni_cache.pop(slot_gen, None)
+                if cell is None or cell.get("gen") != view_gen:
+                    cell = {"gen": view_gen, "entries": {}, "classdicts": {}}
+                self._uni_cache[slot_gen] = cell
+                while len(self._uni_cache) > self._mpls_fingerprint_cap:
+                    self._uni_cache.pop(next(iter(self._uni_cache)))
+            if len(plain_p):
+                entries = cell["entries"]
+                classdicts = cell["classdicts"]
+                if len(entries) > max(8192, 4 * len(plain_p)):
+                    entries.clear()
+                    classdicts.clear()
+                    cell.pop("plain", None)
+                    cell["cd_total"] = 0
+                # content signature of this rebuild's entire plain section:
+                # membership rows + their class ids + the CONTENT tokens of
+                # every used class (tokens encode first-hop bits + igp, and
+                # the gen guard pins the view arrays the rows index)
+                sig = (
+                    idxs.tobytes(),
+                    cls.tobytes(),
+                    tuple(dest_tokens[int(c)] for c in ucls),
+                )
+                cached_plain = cell.get("plain")
+                unicast = rdb.unicast_routes
+                if cached_plain is not None and cached_plain[0] == sig:
+                    unicast.update(cached_plain[1])
+                else:
+                    plain_dict: dict = {}
+                    for g in _class_groups(cls):
+                        c = int(cls[g[0]])
+                        nhs = class_nhs[c]
                         if not nhs:
                             continue
-                        entry = RibMplsEntry(label=label, nexthops=nhs)
-                        mpls_cache[key] = entry
-                    sub[label] = entry
-                mcell["total"] += len(sub)
-                if mcell["total"] > 4 * max(n_live, 4096):
-                    mcls.clear()
-                    mcell["total"] = len(sub)
-                mcls[gkey] = sub
-            mpls_routes.update(sub)
+                        rows = idxs[g]
+                        token = dest_tokens[c]
+                        # membership keyed by the BYTES (not their hash): a
+                        # 64-bit hash collision would silently install
+                        # another class's routes — unacceptable for a RIB
+                        gkey = (token, rows.tobytes())
+                        sub = classdicts.get(gkey)
+                        if sub is None:
+                            sub = {}
+                            igp_c = int(igp[rows[0]])
+                            for i in rows.tolist():
+                                key = (i, token)
+                                e = entries.get(key)
+                                if e is None:
+                                    p = plain_p[i]
+                                    e = RibEntry(
+                                        prefix=p,
+                                        nexthops=nhs,
+                                        best_node=plain_n[i],
+                                        best_nodes=(plain_n[i],),
+                                        best_entry=plain_e[i],
+                                        igp_cost=igp_c,
+                                    )
+                                    entries[key] = e
+                                sub[e.prefix] = e
+                            # bound by TOTAL cached route objects, not key
+                            # count: under churn every rebuild mints new
+                            # tokens and each stale key pins a whole sub-dict
+                            cell["cd_total"] = (
+                                cell.get("cd_total", 0) + len(sub)
+                            )
+                            if cell["cd_total"] > 4 * max(len(plain_p), 4096):
+                                classdicts.clear()
+                                cell["cd_total"] = len(sub)
+                            classdicts[gkey] = sub
+                        plain_dict.update(sub)
+                    cell["plain"] = (sig, plain_dict)
+                    unicast.update(plain_dict)
 
-        # ---- MPLS adjacency labels ---------------------------------------
-        my_db = ls.adjacency_db(my_node)
-        if my_db:
-            for a in my_db.adjacencies:
-                if a.adj_label < MPLS_LABEL_MIN:
-                    continue
-                if a.other_node_name not in csr.name_to_id or a.is_overloaded:
-                    continue
-                if ls.link_drained_by_peer(my_node, a):
-                    continue  # far side soft-drained the link
-                rdb.mpls_routes[a.adj_label] = RibMplsEntry(
-                    label=a.adj_label,
-                    nexthops=(
-                        NextHop(
-                            address=a.other_node_name,
-                            if_name=a.if_name,
-                            metric=int(a.metric),
-                            neighbor_node=a.other_node_name,
-                            area=ls.area,
-                            mpls_action=MplsAction(action=MplsActionType.PHP),
-                        ),
-                    ),
+            # ---- unicast: elected multi-advertiser (anycast ECMP) --------
+            # entry construction per surviving prefix; the nexthop union is
+            # per chosen SET via the memoized factory, so thousands of
+            # anycast prefixes to the same originator set share one group —
+            # and an unchanged election outcome (signature over the
+            # chosen/best masks + igp vector) re-lands last rebuild's
+            # entry dict wholesale, preserving identity for the diff
+            if mel is not None:
+                # the signature must cover the NEXTHOP inputs too, not just
+                # the election outcome: a remote metric change can drop one
+                # of two equal-cost paths without moving d_root or the
+                # chosen set (review finding) — the advertisers' first-hop
+                # columns are gathered into the signature so stale groups
+                # can never be re-landed
+                sig_m = (
+                    mel.is_best.tobytes(),
+                    mel.chosen.tobytes(),
+                    mel.min_igp.tobytes(),
+                    fh[:, multi.adv].tobytes(),
                 )
-        self.last_phase_ms["mpls"] = (time.perf_counter() - t_mpls0) * 1e3
+                cached_m = cell.get("multi")
+                if cached_m is not None and cached_m[0] == sig_m:
+                    rdb.unicast_routes.update(cached_m[1])
+                else:
+                    mdict: dict = {}
+                    for (
+                        p, best_names, chosen_ids, chosen_names, igp_c, best_e
+                    ) in iter_multi_winners(multi, mel):
+                        nhs = mk_nexthops_cached(chosen_ids, igp_c)
+                        if not nhs:
+                            continue
+                        mdict[p] = RibEntry(
+                            prefix=p,
+                            nexthops=nhs,
+                            best_node=chosen_names[0],
+                            best_nodes=best_names,
+                            best_entry=best_e,
+                            igp_cost=igp_c,
+                        )
+                    cell["multi"] = (sig_m, mdict)
+                    rdb.unicast_routes.update(mdict)
+
+            # ---- unicast: general path -----------------------------------
+            ksp_jobs = self._unicast_general(
+                csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids, lfa,
+                dist, slot_cache, mk_nexthops_cached, complex_items,
+                rdb.unicast_routes,
+            )
+            if ksp_jobs:
+                self._ksp_batch(
+                    csr, ls, my_node, my_id, d_root, ksp_jobs,
+                    rdb.unicast_routes,
+                )
+
+        with profiling.annotate("spf:rib_mpls"):
+
+            # ---- MPLS node segments --------------------------------------
+            # cross-rebuild cache: under churn most nodes keep the same
+            # (first-hop set, igp), so the per-node SWAP/PHP NextHop
+            # construction — the single hottest host loop in a steady-state
+            # rebuild — is skipped for every unchanged destination. Keyed by
+            # the shared `slot_gen` fingerprint computed above.
+            # re-insert to refresh the fingerprint's LRU position
+            mpls_cache = self._mpls_cache.pop(slot_gen, None) or {}
+            self._mpls_cache[slot_gen] = mpls_cache
+            # evict least-recently-used fingerprints (NOT a full wipe — the
+            # fleet path serves many roots per pass, each a fingerprint, and
+            # a wipe would defeat the cross-rebuild cache it relies on); the
+            # cap is raised by compute_fleet_ribs to cover its root count
+            while len(self._mpls_cache) > self._mpls_fingerprint_cap:
+                self._mpls_cache.pop(next(iter(self._mpls_cache)))
+            if len(mpls_cache) > max(4096, 4 * len(csr.node_names)):
+                mpls_cache.clear()
+            # vectorized per-destination eligibility; the expensive content
+            # key reuses the shared dest_cls/dest_tokens classification, so
+            # the steady-state loop is token-keyed dict hits (no per-node
+            # tobytes/hashing of columns)
+            names = csr.node_names
+            ids = np.arange(n_live, dtype=np.int64)
+            labels_v = self._node_labels(ls, csr, n_live)
+            elig = (
+                (labels_v >= MPLS_LABEL_MIN)
+                & (ids != my_id)
+                & (d_root[:n_live] < INF_DIST)
+                & fh_any[:n_live]
+            )
+            sel = np.nonzero(elig)[0]
+            mpls_routes = rdb.mpls_routes
+            # class-level sub-dict reuse, mirroring the unicast path: a
+            # destination class whose membership, labels, and (fh, igp)
+            # token are unchanged since a previous rebuild is ONE dict
+            # update. base_version is in the key because rows are node IDS
+            # (the name↔id interning changes with the topology base).
+            mcell = self._mpls_cls_cache.pop(slot_gen, None) or {
+                "groups": {}, "total": 0
+            }
+            self._mpls_cls_cache[slot_gen] = mcell
+            while len(self._mpls_cls_cache) > self._mpls_fingerprint_cap:
+                self._mpls_cls_cache.pop(next(iter(self._mpls_cls_cache)))
+            mcls = mcell["groups"]
+            cls_sel = dest_cls[sel]
+            for g in _class_groups(cls_sel):
+                rows = sel[g]
+                token = dest_tokens[int(cls_sel[g[0]])]
+                lab = labels_v[rows]
+                # bytes, not hashes, for the same reason as the unicast path
+                gkey = (csr.base_version, token, rows.tobytes(), lab.tobytes())
+                sub = mcls.get(gkey)
+                if sub is None:
+                    sub = {}
+                    igp = int(d_root[rows[0]])
+                    for i in rows.tolist():
+                        node = names[i]
+                        label = int(labels_v[i])
+                        key = (label, node, token, igp)
+                        entry = mpls_cache.get(key)
+                        if entry is None:
+                            nhs = self._mpls_wrap(
+                                mk_nexthops_cached(np.array([i]), igp),
+                                node, label,
+                            )
+                            if not nhs:
+                                continue
+                            entry = RibMplsEntry(label=label, nexthops=nhs)
+                            mpls_cache[key] = entry
+                        sub[label] = entry
+                    mcell["total"] += len(sub)
+                    if mcell["total"] > 4 * max(n_live, 4096):
+                        mcls.clear()
+                        mcell["total"] = len(sub)
+                    mcls[gkey] = sub
+                mpls_routes.update(sub)
+
+            # ---- MPLS adjacency labels -----------------------------------
+            my_db = ls.adjacency_db(my_node)
+            if my_db:
+                for a in my_db.adjacencies:
+                    if a.adj_label < MPLS_LABEL_MIN:
+                        continue
+                    if (
+                        a.other_node_name not in csr.name_to_id
+                        or a.is_overloaded
+                    ):
+                        continue
+                    if ls.link_drained_by_peer(my_node, a):
+                        continue  # far side soft-drained the link
+                    rdb.mpls_routes[a.adj_label] = RibMplsEntry(
+                        label=a.adj_label,
+                        nexthops=(
+                            NextHop(
+                                address=a.other_node_name,
+                                if_name=a.if_name,
+                                metric=int(a.metric),
+                                neighbor_node=a.other_node_name,
+                                area=ls.area,
+                                mpls_action=MplsAction(
+                                    action=MplsActionType.PHP
+                                ),
+                            ),
+                        ),
+                    )
         return rdb
 
     def _elect_multi(self, multi, d_root, fh_any, my_id, view_gen):

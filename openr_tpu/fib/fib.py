@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import time
 from typing import Iterable, Protocol
 
 from openr_tpu.common import constants as C
@@ -20,7 +19,7 @@ from openr_tpu.common.backoff import ExponentialBackoff, stable_rng
 from openr_tpu.common.eventbase import OpenrModule
 from openr_tpu.config import Config
 from openr_tpu.messaging import QueueClosedError, ReplicateQueue, RQueue
-from openr_tpu.monitor import perf, work_ledger
+from openr_tpu.monitor import perf, profiling, work_ledger
 from openr_tpu.types.network import IpPrefix, MplsRoute, UnicastRoute
 from openr_tpu.types.routes import (
     RibEntry,
@@ -425,12 +424,13 @@ class Fib(OpenrModule):
             await self._dirty.wait()
             self._dirty.clear()
             try:
-                t0 = time.perf_counter()
                 # traces folded in while _program_once awaits the handler
                 # belong to the NEXT pass — only this many were covered
                 # by the desired-state snapshot programmed below
                 n_covered = len(self._pending_perf)
-                await self._program_once()
+                # one clock pair: the trace's span and fib.program_ms
+                with profiling.annotate("fib:program") as programmed:
+                    await self._program_once()
                 self.backoff.report_success()
                 if self._fail_streak:
                     self._fail_streak = 0
@@ -443,8 +443,7 @@ class Fib(OpenrModule):
                     self.counters.increment("fib.program_ok")
                     if self._have_rib:
                         self.counters.add_value(
-                            "fib.program_ms",
-                            (time.perf_counter() - t0) * 1e3,
+                            "fib.program_ms", programmed.ms
                         )
                     # refresh work.* gauges at the program edge too —
                     # a fib-only process (no Decision rebuilds) still

@@ -23,11 +23,10 @@ all riding the existing Counters/Prometheus path:
     sanitizer), so capture adds zero XLA compiles.
   * **HBM gauges** — per-device ``memory_stats()`` samples exported as
     ``device.<i>.hbm_bytes_in_use`` / ``hbm_peak_bytes`` /
-    ``hbm_limit_bytes``, taken at annotate boundaries
-    (monitor/profiling.py) and decision rebuild edges. CPU backends
-    return ``None`` from ``memory_stats()``: the first all-None sample
-    latches availability off and every later call is a single flag
-    test — graceful degradation, no per-span probe cost.
+    ``hbm_limit_bytes``, taken at decision rebuild edges (a profiling
+    span's exit makes no device call). CPU backends return ``None``
+    from ``memory_stats()``: the first all-None sample latches
+    availability off and every later call is a single flag test.
   * **Shard rows** — per-device layout of a sharded output array read
     from its ``Sharding`` metadata WITHOUT touching ``shard.data``
     (which dispatches a ``_multi_slice`` program — a compile + a
@@ -250,8 +249,8 @@ class DeviceTelemetry:
         """Per-device ``memory_stats()`` rows, or None when the backend
         exposes none (CPU). With ``counters``, live/peak/limit bytes are
         also stamped as ``device.<i>.*`` gauges. The first all-None
-        sample latches availability off so annotate-boundary sampling
-        costs one flag test per span on CPU."""
+        sample latches availability off so rebuild-edge sampling costs
+        one flag test on CPU."""
         if self._hbm_state is False:
             return None
         try:

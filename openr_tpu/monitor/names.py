@@ -234,6 +234,51 @@ TEMPLATES: dict[str, str | None] = {
     "platform.*": None,
 }
 
+# ----------------------------------------------------------------- spans
+
+#: the spans of one route rebuild, publication to push, in the order a
+#: rebuild meets them (monitor/profiling.py; docs/Monitor.md "Spans").
+#: Decision.last_breakdown_ms publishes every one of them each rebuild,
+#: 0.0 where the branch did not run. Indented = opened inside the one
+#: above. The prefix is what perfbench/trace_reduce.py keeps from the
+#: host planes of a profiler trace: ^(spf|decision|fib|kvstore):
+REBUILD_SPANS: tuple[str, ...] = (
+    "decision:debounce_wait",    # first publication buffered → rebuild
+    "decision:rebuild",          # the rebuild coroutine, all of it
+    "decision:decode",           #   serde decode of the batch (thread)
+    "decision:apply_snapshot",   #   LSDB apply, dirt, snapshot (loop)
+    "decision:compute_diff",     #   the solver thread, as the loop waits
+    "decision:compute_rib",      #     per-area compute + merge
+    "spf:to_csr",                #       LinkState → CSR snapshot
+    "spf:prepare",               #       cold: to_csr + pads + dispatch
+    "spf:dispatch",              #       device tables: hit, patch, build
+    "spf:patch_scatter",         #         journal suffix → eager scatters
+    "spf:batched_solve",         #       cold fused kernel + packed fetch
+    "spf:batched_dist",          #       unfused kernels, dispatch only
+    "spf:sharded_solve",         #       mesh kernel, dispatch only
+    "spf:native_solve",          #       C++ host engine
+    "spf:unpack",                #       packed buffer → d_root, fh, lfa
+    "spf:rib_assembly",          #       election + assembly + mpls
+    "spf:rib_election",          #         classes, advertiser election
+    "spf:election",              #           device election (big tables)
+    "spf:rib_unicast",           #         unicast RibEntries
+    "spf:rib_mpls",              #         node-label routes
+    "spf:ksp",                   #         KSP prefixes' batched paths
+    "spf:dist_mirror",           #       warm: [vp, B] matrix → host
+    "spf:warm_cone",             #       warm: host cone walk
+    "spf:warm_scatter",          #       warm: cone → INF, eager scatters
+    "spf:warm_solve",            #       warm kernel + packed fetch
+    "spf:warm_unpack",           #       warm: unpack + change mask
+    "spf:warm_reassemble",       #       warm: scoped routes + MPLS
+    "decision:diff",             #     RIB delta / work-ledger commit
+    "decision:export_counters",  #   markers, trim policy, counters
+    "decision:publish",          #   merge book + route_updates.push
+)
+
+#: every span name the program opens (tests/test_profiling.py checks the
+#: call sites against it; docs/Monitor.md lists them)
+SPANS: frozenset[str] = frozenset(REBUILD_SPANS) | {"fib:program"}
+
 #: the queue counter FIELD vocabulary the messaging seams may emit —
 #: OR007 statically cross-checks messaging/__init__.py's emit sites
 #: against this set (the old ci.sh heredoc #4, now AST-based).

@@ -1,5 +1,6 @@
 """Kernel tracing surface (SURVEY §5.1: "JAX profiler + xprof traces
-for the SPF kernel, plus the same counter surface").
+for the SPF kernel, plus the same counter surface") and the one span
+source of the event path (docs/Monitor.md "Spans").
 
 Wraps jax.profiler so the rest of the framework never imports jax for
 observability alone, and so tracing degrades to a no-op on hosts where
@@ -12,22 +13,35 @@ Usage:
       ...
   with profiling.annotate("spf:solve", counters=node_counters):
       ...  # ALSO records wall ms into the `profile.spf:solve_ms` stat
+  with profiling.collect() as rec:             # one request's record
+      with profiling.annotate("spf:solve"):
+          ...
+  rec.ms["spf:solve"], rec.spans
 
-bench.py honors OPENR_BENCH_TRACE=<dir> and wraps its timed iterations;
-TpuSpfSolver annotates solve/assembly phases so the xprof timeline
-separates device solve time from host RIB assembly.
+One span, three sinks:
 
-With a :class:`Counters` registry passed, every annotated span ALSO
-records its wall duration into the windowed ``profile.<span>_ms``
-histogram stat — so solver phase timings land on the same Prometheus
-surface (and `breeze monitor fleet` distributions) as every other
-latency in the system, whether or not an xprof session is active
-(docs/Monitor.md).
+  * a ``jax.profiler.TraceAnnotation`` — a row on the xprof timeline,
+    i.e. on the device trace's clock, so an idle gap of the device can
+    be given to what the host was doing in it;
+  * with a :class:`Counters` registry passed, the wall duration lands in
+    the windowed ``profile.<span>_ms`` histogram stat — the Prometheus
+    surface every other latency of the system is on;
+  * with a collector open (:func:`collect`), ``(name, parent, start,
+    end)`` is appended to the request's :class:`SpanRecord`. The record
+    rides a ``contextvars.ContextVar``, so it follows
+    ``asyncio.to_thread`` into the solver thread. ``Decision.
+    last_breakdown_ms`` and ``TpuSpfSolver.last_phase_ms`` are views of
+    such records.
+
+With no collector open and no ``counters`` a span writes to the trace
+alone. Leaving a span never calls the device (the HBM gauges are sampled
+at rebuild edges, decision.py).
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import logging
 import time
 
@@ -61,68 +75,164 @@ def trace(trace_dir: str | None):
             log.warning("jax profiler trace export failed", exc_info=True)
 
 
-def annotate(name: str, counters=None):
-    """Named trace span (xprof timeline row); no-op without jax. With
-    `counters`, the span's wall duration is additionally recorded into
-    the ``profile.<name>_ms`` Counters histogram — device-side phase
-    closure onto the common metric surface."""
-    inner = _raw_annotation(name)
-    if counters is None:
-        return inner
-    return _TimedSpan(name, counters, inner)
+# ------------------------------------------------------------ the record
+
+#: the open collector's record and the innermost open span's name, per
+#: context: a task or a `to_thread` worker sees its caller's
+_RECORD: contextvars.ContextVar["SpanRecord | None"] = contextvars.ContextVar(
+    "openr_span_record", default=None
+)
+_PARENT: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "openr_span_parent", default=None
+)
 
 
-def _raw_annotation(name: str):
-    try:
-        import jax
+class SpanRecord:
+    """The spans of one request (one route rebuild, one solver call):
+    ``spans`` holds ``(name, parent, start, end)`` in closing order,
+    times in ``time.perf_counter()`` seconds, ``parent`` the name of the
+    span that was innermost when this one opened (None at the top). A
+    record belongs to one request whose phases run one after another,
+    possibly on two threads; ``list.append`` is all that touches it
+    while spans close, the sums are taken when read."""
 
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        return contextlib.nullcontext()
+    __slots__ = ("spans", "t0")
+
+    def __init__(self):
+        self.spans: list[tuple[str, str | None, float, float]] = []
+        self.t0 = time.perf_counter()
+
+    @property
+    def ms(self) -> dict[str, float]:
+        """Wall milliseconds by span name (same-named spans add up)."""
+        out: dict[str, float] = {}
+        for name, _parent, start, end in self.spans:
+            out[name] = out.get(name, 0.0) + (end - start) * 1e3
+        return out
+
+    def elapsed_ms(self) -> float:
+        return (time.perf_counter() - self.t0) * 1e3
 
 
-class _TimedSpan:
-    """Context manager wrapping the (possibly no-op) jax annotation with
-    a wall-clock timer recorded into Counters on exit. Nested spans each
-    record their own duration (the outer includes the inner, as xprof
-    timelines do). Re-entrant only via fresh instances — annotate()
-    returns a new one per call."""
+class collect:
+    """Open a record for the spans closed under this block. Nested under
+    another collector, the inner record is the caller's alone while
+    open, and its spans are handed to the outer one on the way out (top
+    level spans become children of the outer block's open span)."""
 
-    __slots__ = ("name", "counters", "inner", "_t0")
+    __slots__ = ("record", "_tokens")
 
-    def __init__(self, name: str, counters, inner):
-        self.name = name
-        self.counters = counters
-        self.inner = inner
-        self._t0 = 0.0
+    def __init__(self):
+        self.record = SpanRecord()
+        self._tokens = None
 
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        try:
-            self.inner.__enter__()
-        except Exception:  # noqa: BLE001 — profiling must never break prod
-            self.inner = contextlib.nullcontext()
-            self.inner.__enter__()
-        return self
+    def __enter__(self) -> SpanRecord:
+        self._tokens = (_RECORD.set(self.record), _PARENT.set(None))
+        return self.record
 
     def __exit__(self, exc_type, exc, tb):
-        try:
-            self.inner.__exit__(exc_type, exc, tb)
-        except Exception:  # noqa: BLE001
-            log.warning("trace annotation exit failed", exc_info=True)
-        self.counters.add_value(
-            f"profile.{self.name}_ms",
-            (time.perf_counter() - self._t0) * 1e3,
-        )
-        # annotate-boundary HBM sample (docs/Monitor.md "Device
-        # telemetry"): on backends with memory_stats this stamps the
-        # device.<i>.hbm_* gauges right after the device work the span
-        # wrapped; on CPU the first probe latches availability off and
-        # this is a single flag test per span
-        try:
-            from openr_tpu.monitor import device as _device
-
-            _device.sample_hbm(self.counters)
-        except Exception:  # noqa: BLE001 — profiling must never break prod
-            pass
+        rec_token, parent_token = self._tokens
+        _PARENT.reset(parent_token)
+        _RECORD.reset(rec_token)
+        outer = _RECORD.get()
+        if outer is not None:
+            parent = _PARENT.get()
+            outer.spans.extend(
+                (n, parent if p is None else p, s, e)
+                for n, p, s, e in self.record.spans
+            )
         return False
+
+
+# -------------------------------------------------------------- the span
+
+#: jax.profiler.TraceAnnotation, resolved on first use: None = not yet
+#: looked up, False = no jax here (spans are clocks only)
+_ANNOTATION_CLS = None
+
+
+def _annotation(name: str):
+    global _ANNOTATION_CLS
+    if _ANNOTATION_CLS is None:
+        try:
+            import jax
+
+            _ANNOTATION_CLS = jax.profiler.TraceAnnotation
+        except Exception:  # noqa: BLE001 — no jax: the control plane runs on
+            _ANNOTATION_CLS = False
+    if _ANNOTATION_CLS is False:
+        return None
+    try:
+        return _ANNOTATION_CLS(name)
+    except Exception:  # noqa: BLE001 — profiling must never break prod
+        return None
+
+
+def annotate(name: str, counters=None) -> "Span":
+    """Named trace span (xprof timeline row; a clock pair alone without
+    jax). With `counters`, the span's wall duration is additionally
+    recorded into the ``profile.<name>_ms`` Counters histogram; under an
+    open collector it is appended to the request's record. `.ms` holds
+    the duration once the block is left."""
+    return Span(name, counters)
+
+
+def start(name: str) -> "Span":
+    """A span entered now and left by `Span.stop(record)`, for a phase
+    that begins in one task and ends in another of the same thread
+    (Decision's debounce hold). It is nobody's parent."""
+    return Span(name)._open()
+
+
+class Span:
+    """The timed span: the (possibly absent) jax annotation, a
+    `perf_counter` pair, and on the way out the Counters stat and the
+    open record. Nested spans each record their own duration (the outer
+    includes the inner, as xprof timelines do). `ms` holds the duration
+    once the span is closed. One use per instance."""
+
+    __slots__ = ("name", "counters", "ms", "_inner", "_t0", "_token")
+
+    def __init__(self, name: str, counters=None):
+        self.name = name
+        self.counters = counters
+        self.ms = 0.0
+        self._inner = None
+        self._t0 = 0.0
+        self._token = None
+
+    def _open(self) -> "Span":
+        self._inner = _annotation(self.name)
+        if self._inner is not None:
+            try:
+                self._inner.__enter__()
+            except Exception:  # noqa: BLE001 — must never break prod
+                self._inner = None
+        self._t0 = time.perf_counter()
+        return self
+
+    def _close(self, record: SpanRecord | None, parent: str | None) -> None:
+        t1 = time.perf_counter()
+        if self._inner is not None:
+            try:
+                self._inner.__exit__(None, None, None)
+            except Exception:  # noqa: BLE001
+                log.warning("trace annotation exit failed", exc_info=True)
+        self.ms = (t1 - self._t0) * 1e3
+        if record is not None:
+            record.spans.append((self.name, parent, self._t0, t1))
+        if self.counters is not None:
+            self.counters.add_value(f"profile.{self.name}_ms", self.ms)
+
+    def __enter__(self) -> "Span":
+        self._token = _PARENT.set(self.name)
+        return self._open()
+
+    def __exit__(self, exc_type, exc, tb):
+        _PARENT.reset(self._token)
+        self._close(_RECORD.get(), _PARENT.get())
+        return False
+
+    def stop(self, record: SpanRecord | None = None) -> None:
+        """Leave a span opened by `start`, into `record` at its top."""
+        self._close(record, None)

@@ -330,29 +330,26 @@ def pick_gs_chunks(vp: int) -> int:
     return 1
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "has_overloads", "tail_threshold", "tail_cap", "tail_rounds_cap",
-        "gs_chunks",
-    ),
-)
-def batched_sssp_split(
-    base_nbr: jax.Array,   # [vp, W]
-    base_wgt: jax.Array,   # [vp, W]
-    ov_ids: jax.Array,     # [Go]
-    ov_nbr: jax.Array,     # [Go, Wo]
-    ov_wgt: jax.Array,     # [Go, Wo]
-    out_nbr: jax.Array,    # [vp, Wout]
-    node_overloaded: jax.Array,  # [vp] bool
-    roots: jax.Array,      # [B]
-    has_overloads: bool = False,
-    tail_threshold: int = 1024,
-    tail_cap: int = 8192,
-    tail_rounds_cap: int = 64,
-    gs_chunks: int | None = None,
-) -> jax.Array:
-    """Distances [vp, B] from each root. See module docstring."""
+#: the loop counters a solve hands back, in the order of the packed
+#: buffer's int32 trailer (`rib_buffer_trailer`)
+SOLVE_COUNTERS = ("dense_sweeps", "tail_rounds", "net_sweeps", "spilled")
+
+
+def _solve_counters(dense_sweeps, tail_rounds, net_sweeps, spilled):
+    return jnp.stack([
+        dense_sweeps, tail_rounds, net_sweeps, spilled.astype(jnp.int32)
+    ]).astype(jnp.int32)
+
+
+def _split_solve(
+    base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr, node_overloaded,
+    roots, has_overloads, tail_threshold, tail_cap, tail_rounds_cap,
+    gs_chunks,
+) -> tuple[jax.Array, jax.Array]:
+    """The cold solve, traced into its jitted callers: distances [vp, B]
+    and the int32[4] loop counters `SOLVE_COUNTERS` (sweeps of phase 1,
+    rounds of the compacted tail, sweeps of the exactness net, whether
+    the tail spilled) — the `it` each loop carries anyway."""
     vp = base_nbr.shape[0]
     b = roots.shape[0]
     w = base_nbr.shape[1]
@@ -390,12 +387,14 @@ def batched_sssp_split(
         changed = (new < dist).any(axis=1)
         return new, changed, changed.sum(), it + 1
 
-    dist, changed_mask, n_changed, _ = jax.lax.while_loop(
-        cond1, body1,
-        (dist, init_changed, jnp.int32(tail_threshold + 1), jnp.int32(0)),
-    )
+    with jax.named_scope("dense_sweep"):
+        dist, changed_mask, n_changed, dense_sweeps = jax.lax.while_loop(
+            cond1, body1,
+            (dist, init_changed, jnp.int32(tail_threshold + 1),
+             jnp.int32(0)),
+        )
 
-    # ---- phase 2: compacted tail --------------------------------------
+    # ---- phase 2: compacted tail, phase 3: exactness net -------------
     frontier = _compact_ids(
         jnp.where(changed_mask, iota, vp), vp, tail_cap, dead
     )
@@ -403,66 +402,162 @@ def batched_sssp_split(
     # (tail_threshold counts rows, tail_cap bounds the array): spill
     # straight to the dense safety net rather than silently truncating
     entry_spill = n_changed > tail_cap
+    dist, tail_rounds, net_sweeps, spilled = _tail_then_net(
+        dist, frontier, entry_spill, dense_sweep,
+        base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
+        over_base, over_ov, roots, has_overloads, tail_cap,
+        tail_rounds_cap, pull_frontier=False,
+    )
+    return dist, _solve_counters(
+        dense_sweeps, tail_rounds, net_sweeps, spilled
+    )
 
-    def cond2(state):
+
+def _tail_then_net(
+    dist, frontier, entry_spill, dense_sweep,
+    base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
+    over_base, over_ov, roots, has_overloads, tail_cap, tail_rounds_cap,
+    pull_frontier,
+):
+    """Frontier rounds over compacted row sets, then dense sweeps to the
+    fixpoint if the tail spilled or hit its round cap with work left:
+    shared by the cold kernel (after its dense phase) and the warm-start
+    kernel (from its seeds). Returns (dist, tail rounds, net sweeps,
+    spilled).
+
+    `pull_frontier`: the rows whose pull could change are the
+    out-neighbors of the frontier (decrease propagation) and, for the
+    warm start, the frontier ITSELF — cone nodes must re-pull their
+    boundary tentatives, their in-neighbors did not change; the cold
+    tail's frontier is always "rows that just changed" and needs only
+    the former."""
+    vp = base_nbr.shape[0]
+    dead = vp - 1
+
+    def cond_t(state):
         _dist, frontier, spilled, it = state
         return (frontier[0] != dead) & (~spilled) & (it < tail_rounds_cap)
 
-    def body2(state):
+    def body_t(state):
         dist, frontier, _sp, it = state
-        # rows whose pull could change = out-neighbors of the frontier
-        exp = jnp.sort(out_nbr[frontier].reshape(-1))
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), exp[1:] != exp[:-1]]
-        ) & (exp != dead)
-        spilled = first.sum() > tail_cap
-        rows = _compact_ids(jnp.where(first, exp, vp), vp, tail_cap, dead)
-        sub_new = _relax_rows(
-            dist, base_nbr[rows], base_wgt[rows],
-            over_base[rows] if has_overloads else None,
-            roots, has_overloads,
-        )
-        # overflow in-edges: the ov tables are tiny — relax them all
-        ov_new = _relax_rows(
-            dist, ov_nbr, ov_wgt, over_ov, roots, has_overloads
-        )
-        dist2 = dist.at[rows].min(sub_new)
-        dist2 = dist2.at[ov_ids].min(ov_new)
-        changed_rows = (dist2[rows] < dist[rows]).any(axis=1)
-        ov_changed = (dist2[ov_ids] < dist[ov_ids]).any(axis=1)
-        both = jnp.concatenate(
-            [
-                jnp.where(changed_rows, rows, vp),
-                jnp.where(ov_changed, ov_ids, vp),
-            ]
-        )
-        srt = jnp.sort(both)
-        firstb = jnp.concatenate(
-            [jnp.ones((1,), bool), srt[1:] != srt[:-1]]
-        ) & (srt < vp)
-        # the next frontier must also fit: a truncated changed-set would
-        # silently drop pending updates (exactness bug), so spill to the
-        # dense phase instead
-        spilled = spilled | (firstb.sum() > tail_cap)
-        nf = _compact_ids(jnp.where(firstb, srt, vp), vp, tail_cap, dead)
+        with jax.named_scope("tail/expand"):
+            reach = out_nbr[frontier].reshape(-1)
+            if pull_frontier:
+                reach = jnp.concatenate([reach, frontier])
+            exp = jnp.sort(reach)
+            first = jnp.concatenate(
+                [jnp.ones((1,), bool), exp[1:] != exp[:-1]]
+            ) & (exp != dead)
+        with jax.named_scope("tail/compact"):
+            spilled = first.sum() > tail_cap
+            rows = _compact_ids(
+                jnp.where(first, exp, vp), vp, tail_cap, dead
+            )
+        with jax.named_scope("tail/relax"):
+            sub_new = _relax_rows(
+                dist, base_nbr[rows], base_wgt[rows],
+                over_base[rows] if has_overloads else None,
+                roots, has_overloads,
+            )
+            # overflow in-edges: the ov tables are tiny — relax them all
+            ov_new = _relax_rows(
+                dist, ov_nbr, ov_wgt, over_ov, roots, has_overloads
+            )
+            dist2 = dist.at[rows].min(sub_new)
+            dist2 = dist2.at[ov_ids].min(ov_new)
+        with jax.named_scope("tail/next_frontier"):
+            changed_rows = (dist2[rows] < dist[rows]).any(axis=1)
+            ov_changed = (dist2[ov_ids] < dist[ov_ids]).any(axis=1)
+            both = jnp.concatenate(
+                [
+                    jnp.where(changed_rows, rows, vp),
+                    jnp.where(ov_changed, ov_ids, vp),
+                ]
+            )
+            srt = jnp.sort(both)
+            firstb = jnp.concatenate(
+                [jnp.ones((1,), bool), srt[1:] != srt[:-1]]
+            ) & (srt < vp)
+            # the next frontier must also fit: a truncated changed-set
+            # would silently drop pending updates (exactness bug), so
+            # spill to the dense phase instead
+            spilled = spilled | (firstb.sum() > tail_cap)
+            nf = _compact_ids(
+                jnp.where(firstb, srt, vp), vp, tail_cap, dead
+            )
         return dist2, nf, spilled, it + 1
 
-    dist, frontier, spilled, _ = jax.lax.while_loop(
-        cond2, body2, (dist, frontier, entry_spill, jnp.int32(0))
+    dist, frontier, spilled, tail_rounds = jax.lax.while_loop(
+        cond_t, body_t, (dist, frontier, entry_spill, jnp.int32(0))
     )
 
-    # ---- phase 3: exactness net — dense to fixpoint if the tail bailed
-    def cond3(state):
+    def cond_d(state):
         _dist, changed, it = state
         return changed & (it < vp)
 
-    def body3(state):
+    def body_d(state):
         dist, _c, it = state
         new = dense_sweep(dist)
         return new, jnp.any(new < dist), it + 1
 
-    dist, _, _ = jax.lax.while_loop(
-        cond3, body3, (dist, spilled | (frontier[0] != dead), jnp.int32(0))
+    with jax.named_scope("net"):
+        dist, _, net_sweeps = jax.lax.while_loop(
+            cond_d, body_d,
+            (dist, spilled | (frontier[0] != dead), jnp.int32(0)),
+        )
+    return dist, tail_rounds, net_sweeps, spilled
+
+
+def _pack_rib(dist, counters, nbr_metric, nbr_ids, nbr_over, my_id, with_lfa):
+    """The host-bound outputs of a RIB solve in ONE uint8 buffer (layout:
+    `unpack_rib_buffer`, trailer: `rib_buffer_trailer`)."""
+    with jax.named_scope("first_hop"):
+        fh = first_hop_matrix(dist, nbr_metric, nbr_ids, nbr_over)
+        lfa = (
+            lfa_matrix(dist, my_id, nbr_ids, nbr_over) if with_lfa else None
+        )
+    with jax.named_scope("pack"):
+        parts = [
+            jax.lax.bitcast_convert_type(dist[:, 0], jnp.uint8).reshape(-1),
+            jnp.packbits(fh, axis=1).reshape(-1),
+        ]
+        if lfa is not None:
+            parts.append(jnp.packbits(lfa, axis=1).reshape(-1))
+        parts.append(
+            jax.lax.bitcast_convert_type(counters, jnp.uint8).reshape(-1)
+        )
+        return jnp.concatenate(parts)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "has_overloads", "tail_threshold", "tail_cap", "tail_rounds_cap",
+        "gs_chunks",
+    ),
+)
+def batched_sssp_split(
+    base_nbr: jax.Array,   # [vp, W]
+    base_wgt: jax.Array,   # [vp, W]
+    ov_ids: jax.Array,     # [Go]
+    ov_nbr: jax.Array,     # [Go, Wo]
+    ov_wgt: jax.Array,     # [Go, Wo]
+    out_nbr: jax.Array,    # [vp, Wout]
+    node_overloaded: jax.Array,  # [vp] bool
+    roots: jax.Array,      # [B]
+    has_overloads: bool = False,
+    tail_threshold: int = 1024,
+    tail_cap: int = 8192,
+    tail_rounds_cap: int = 64,
+    gs_chunks: int | None = None,
+) -> jax.Array:
+    """Distances [vp, B] from each root. See module docstring. (The RIB
+    entries below return the loops' counters too, in their packed
+    buffer; this one has no host-bound buffer to carry them in.)"""
+    dist, _counters = _split_solve(
+        base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
+        node_overloaded, roots, has_overloads, tail_threshold, tail_cap,
+        tail_rounds_cap, gs_chunks,
     )
     return dist
 
@@ -505,30 +600,22 @@ def batched_sssp_split_rib(
     column and the first-hop BITS; this kernel returns exactly those,
     packed:
 
-        buf = [ d_root as 4·Vp uint8 | packbits(fh) | packbits(lfa)? ]
+        buf = [ d_root as 4·Vp uint8 | packbits(fh) | packbits(lfa)?
+              | the loops' counters, 4 int32 ]
 
     ≈ 0.8 MB instead of ~16 MB. The full distance matrix is returned as
     a device array and transferred only if a caller materializes it
-    (KSP oracle checks, tests).
+    (KSP oracle checks, tests). The 16-byte trailer rides the same
+    transfer: the sweep counts reach the host with no sync of their own.
     """
-    dist = batched_sssp_split(
+    dist, counters = _split_solve(
         base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
-        node_overloaded, roots,
-        has_overloads=has_overloads,
-        tail_threshold=tail_threshold,
-        tail_cap=tail_cap,
-        tail_rounds_cap=tail_rounds_cap,
-        gs_chunks=gs_chunks,
+        node_overloaded, roots, has_overloads, tail_threshold, tail_cap,
+        tail_rounds_cap, gs_chunks,
     )
-    fh = first_hop_matrix(dist, nbr_metric, nbr_ids, nbr_over)
-    parts = [
-        jax.lax.bitcast_convert_type(dist[:, 0], jnp.uint8).reshape(-1),
-        jnp.packbits(fh, axis=1).reshape(-1),
-    ]
-    if with_lfa:
-        lfa = lfa_matrix(dist, my_id, nbr_ids, nbr_over)
-        parts.append(jnp.packbits(lfa, axis=1).reshape(-1))
-    return dist, jnp.concatenate(parts)
+    return dist, _pack_rib(
+        dist, counters, nbr_metric, nbr_ids, nbr_over, my_id, with_lfa
+    )
 
 
 @functools.partial(
@@ -572,10 +659,10 @@ def batched_sssp_split_warm_rib(
     they reach — bounded-region cost, truncated exactly where old
     distances already stand (Bounded Dijkstra 1903.00436) — with the
     cold kernel's spill-to-dense safety net keeping exactness if the
-    frontier outgrows its static capacity.
+    frontier outgrows its static capacity. The trailer's `dense_sweeps`
+    is 0: a warm start has no dense phase before its tail.
     """
     vp = base_nbr.shape[0]
-    b = roots.shape[0]
     dead = vp - 1
     iota = jnp.arange(vp, dtype=jnp.int32)
 
@@ -591,85 +678,22 @@ def batched_sssp_split_warm_rib(
         base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt,
         over_base, over_ov, roots, has_overloads, gs,
     )
-
-    dist = dist0
     frontier = _compact_ids(
         jnp.where(seed_mask, iota, vp), vp, tail_cap, dead
     )
     entry_spill = seed_mask.sum() > tail_cap
-
-    def cond_t(state):
-        _dist, frontier, spilled, it = state
-        return (frontier[0] != dead) & (~spilled) & (it < tail_rounds_cap)
-
-    def body_t(state):
-        dist, frontier, _sp, it = state
-        # rows whose pull could change = the frontier ITSELF (cone
-        # nodes must re-pull their boundary tentatives — their
-        # in-neighbors did not change) ∪ its out-neighbors (decrease
-        # propagation); the cold tail only needs the latter because its
-        # frontier is always "rows that just changed"
-        exp = jnp.sort(
-            jnp.concatenate([out_nbr[frontier].reshape(-1), frontier])
-        )
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), exp[1:] != exp[:-1]]
-        ) & (exp != dead)
-        spilled = first.sum() > tail_cap
-        rows = _compact_ids(jnp.where(first, exp, vp), vp, tail_cap, dead)
-        sub_new = _relax_rows(
-            dist, base_nbr[rows], base_wgt[rows],
-            over_base[rows] if has_overloads else None,
-            roots, has_overloads,
-        )
-        ov_new = _relax_rows(
-            dist, ov_nbr, ov_wgt, over_ov, roots, has_overloads
-        )
-        dist2 = dist.at[rows].min(sub_new)
-        dist2 = dist2.at[ov_ids].min(ov_new)
-        changed_rows = (dist2[rows] < dist[rows]).any(axis=1)
-        ov_changed = (dist2[ov_ids] < dist[ov_ids]).any(axis=1)
-        both = jnp.concatenate(
-            [
-                jnp.where(changed_rows, rows, vp),
-                jnp.where(ov_changed, ov_ids, vp),
-            ]
-        )
-        srt = jnp.sort(both)
-        firstb = jnp.concatenate(
-            [jnp.ones((1,), bool), srt[1:] != srt[:-1]]
-        ) & (srt < vp)
-        spilled = spilled | (firstb.sum() > tail_cap)
-        nf = _compact_ids(jnp.where(firstb, srt, vp), vp, tail_cap, dead)
-        return dist2, nf, spilled, it + 1
-
-    dist, frontier, spilled, _ = jax.lax.while_loop(
-        cond_t, body_t, (dist, frontier, entry_spill, jnp.int32(0))
+    dist, tail_rounds, net_sweeps, spilled = _tail_then_net(
+        dist0, frontier, entry_spill, dense_sweep,
+        base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
+        over_base, over_ov, roots, has_overloads, tail_cap,
+        tail_rounds_cap, pull_frontier=True,
     )
-
-    # exactness net: dense sweeps to fixpoint if the tail spilled or hit
-    # its round cap with work left (identical to the cold kernel's)
-    def cond_d(state):
-        _dist, changed, it = state
-        return changed & (it < vp)
-
-    def body_d(state):
-        dist, _c, it = state
-        new = dense_sweep(dist)
-        return new, jnp.any(new < dist), it + 1
-
-    dist, _, _ = jax.lax.while_loop(
-        cond_d, body_d, (dist, spilled | (frontier[0] != dead), jnp.int32(0))
+    counters = _solve_counters(
+        jnp.int32(0), tail_rounds, net_sweeps, spilled
     )
-
-    fh = first_hop_matrix(dist, nbr_metric, nbr_ids, nbr_over)
-    packed = jnp.concatenate(
-        [
-            jax.lax.bitcast_convert_type(dist[:, 0], jnp.uint8).reshape(-1),
-            jnp.packbits(fh, axis=1).reshape(-1),
-        ]
+    return dist, _pack_rib(
+        dist, counters, nbr_metric, nbr_ids, nbr_over, None, False
     )
-    return dist, packed
 
 
 _BYTE_ORDER_OK: bool | None = None
@@ -709,7 +733,8 @@ def unpack_rib_buffer(
 
         [ d_root: vp int32 as 4·vp bytes
         | fh:     (b-1) rows × vp/8 packbits bytes
-        | lfa:    (b-1) rows × vp/8 packbits bytes, iff with_lfa ]
+        | lfa:    (b-1) rows × vp/8 packbits bytes, iff with_lfa
+        | the loop counters, 16 bytes: see `rib_buffer_trailer` ]
 
     Returns (d_root int32 [vp], fh bool [b-1, vp], lfa or None).
     """
@@ -725,3 +750,14 @@ def unpack_rib_buffer(
     fh = unpack(vp * 4)
     lfa = unpack(vp * 4 + (b - 1) * row) if with_lfa else None
     return d_root, fh, lfa
+
+
+def rib_buffer_trailer(buf: np.ndarray) -> dict[str, int]:
+    """The kernel's own loop counters from the packed buffer's last 16
+    bytes, `SOLVE_COUNTERS` as four int32 after the last section (so the
+    offsets `unpack_rib_buffer` reads never moved): sweeps of the dense
+    phase, rounds of the compacted tail, sweeps of the exactness net,
+    and whether the tail spilled into it."""
+    _check_byte_order()
+    tail = buf[-4 * len(SOLVE_COUNTERS):].view(np.int32)
+    return dict(zip(SOLVE_COUNTERS, tail.tolist()))

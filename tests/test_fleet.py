@@ -148,3 +148,18 @@ def test_fleet_with_mesh_solver_equals_single_device():
     for node in want:
         assert got[node].unicast_routes == want[node].unicast_routes, node
         assert got[node].mpls_routes == want[node].mpls_routes, node
+
+
+def test_fleet_pass_feeds_no_rib_assembly_stat():
+    """`profile.spf:rib_assembly_ms` counts `compute_routes` calls: the
+    span with `counters=` sits at that call site, so a fleet pass, which
+    assembles a RIB per node through `_assemble_routes`, adds none."""
+    from openr_tpu.monitor.counters import Counters
+
+    ls, ps = _state(*topogen.grid(3, 3))
+    c = Counters()
+    solver = TpuSpfSolver(native_rib="off", counters=c)
+    assert len(compute_fleet_ribs(ls, ps, solver=solver)) == 9
+    assert "profile.spf:rib_assembly_ms" not in c.stats
+    solver.compute_routes(ls, ps, "node-0")
+    assert c.stats["profile.spf:rib_assembly_ms"].count == 1

@@ -465,3 +465,91 @@ def test_warm_trim_frees_state_and_rearms():
         assert_parity(d)
 
     run(body())
+
+
+# ------------------------------------------------ the rebuild's span record
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_last_breakdown_ms_is_a_view_of_the_rebuilds_span_record(backend):
+    """`Decision.last_breakdown_ms` comes from the rebuild's span record
+    (docs/Monitor.md "Spans"): the five older keys keep their names and
+    still nest, every span of the rebuild path is there in every rebuild
+    (0.0 where the branch did not run), and on the device engine a
+    metric flap fills the warm path's spans and counters."""
+    from openr_tpu.monitor import names
+
+    async def body():
+        d = mk_decision(backend)
+        adj_dbs, prefix_dbs = topogen.grid(5, 5, metric=10)
+        d.process_publication(adj_pub(adj_dbs))
+        d.process_publication(prefix_pub(prefix_dbs))
+        await asyncio.sleep(0.002)  # the debounce span is open meanwhile
+        await d._rebuild_routes()
+        full = dict(d.last_breakdown_ms)
+        adj_cur = {db.this_node_name: db for db in adj_dbs}
+        # raise node-1 -> node-2, a tree edge of root node-0: node-2's
+        # distance rises, so the increase cone is not empty
+        k = [a.other_node_name for a in adj_cur["node-1"].adjacencies].index(
+            "node-2"
+        )
+        d.process_publication(flap_pub(adj_cur, "node-1", k, 30, 2))
+        await d._rebuild_routes()
+        assert d.counters.get("decision.rebuild.topo_delta") == 1
+        warm = dict(d.last_breakdown_ms)
+        await d._rebuild_routes()  # nothing pending: an empty rebuild
+        return d, full, warm, dict(d.last_breakdown_ms)
+
+    d, full, warm, empty = run(body())
+    old = {"decode", "apply_snapshot", "compute_diff", "compute_rib", "diff"}
+    renamed = {f"decision:{k}" for k in old}
+    for bd in (full, warm, empty):
+        assert set(bd) == old | (set(names.REBUILD_SPANS) - renamed)
+        assert all(v >= 0.0 for v in bd.values())
+        assert bd["compute_rib"] + bd["diff"] <= bd["compute_diff"]
+        assert (
+            bd["decode"] + bd["apply_snapshot"] + bd["compute_diff"]
+            + bd["decision:export_counters"] + bd["decision:publish"]
+            <= bd["decision:rebuild"]
+        )
+    assert full["decode"] > 0.0 and full["decision:debounce_wait"] >= 2.0
+    assert warm["decision:debounce_wait"] > 0.0
+    assert empty["decode"] == 0.0 and empty["decision:debounce_wait"] == 0.0
+    warm_spans = (
+        "spf:dist_mirror", "spf:warm_cone", "spf:dispatch",
+        "spf:warm_scatter", "spf:warm_solve", "spf:warm_unpack",
+        "spf:warm_reassemble",
+    )
+    if backend == "tpu":
+        # cold call first: its six phases, under compute_rib
+        cold = ("spf:prepare", "spf:batched_solve", "spf:unpack",
+                "spf:rib_assembly")
+        assert all(full[n] > 0.0 for n in cold)
+        assert sum(full[n] for n in cold) <= full["compute_rib"]
+        assert all(full[n] == 0.0 for n in warm_spans if n != "spf:dispatch")
+        # then the warm start: every host phase of it under a name
+        assert all(warm[n] > 0.0 for n in warm_spans)
+        assert warm["spf:batched_solve"] == 0.0
+        assert (
+            warm["spf:to_csr"] + sum(warm[n] for n in warm_spans)
+            <= warm["compute_rib"]
+        )
+        st = d._tpu.spf_kernel_stats
+        assert st["warm_tail_rounds"] >= 1 and st["dense_sweeps"] >= 1
+        assert st["warm_cone_cells"] >= 1
+        assert d._tpu.dev_cache_stats["scatter_calls"] >= 2
+        assert d.counters.get("decision.spf.warm_tail_rounds") >= 1
+        assert d.counters.get("decision.dev_cache.scatter_calls") >= 2
+        # the cold call's six phases; the three of the election feed
+        # the stats they always fed, the others make no stat of theirs
+        assert set(d._tpu.last_phase_ms) == {
+            "prepare", "solve", "unpack", "election", "assembly", "mpls",
+        }
+        elect = {k for k in d.counters.stats if k.startswith("decision.elect.")}
+        assert elect == {
+            f"decision.elect.{k}_ms" for k in ("election", "assembly", "mpls")
+        }
+        # one cold compute_routes call, one reading of its assembly
+        assert d.counters.stats["profile.spf:rib_assembly_ms"].count == 1
+    else:
+        assert all(warm[n] == 0.0 for n in warm_spans)
