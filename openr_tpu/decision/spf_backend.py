@@ -74,7 +74,7 @@ from openr_tpu.types.routes import (
 log = logging.getLogger(__name__)
 
 # Warm-start cone-scatter pad tiers. pad_batch's power-of-two buckets
-# would compile a distinct eager scatter variant per cone-size bucket —
+# would compile a distinct scatter program per cone-size bucket —
 # up to ~17 over a churn run, and a fresh one can land long after
 # warmup (the compile ledger's zero-steady-state-recompile gate caught
 # exactly this). Three fixed tiers bound the variant count at 3 for the
@@ -82,6 +82,26 @@ log = logging.getLogger(__name__)
 # slots repeat the last (row, col) and a duplicate .set of the same
 # INF_DIST is a no-op. Cones beyond the top tier chunk by it.
 _WARM_SCATTER_TIERS = (8192, 131_072, 1_048_576)
+
+
+@jax.jit
+def _scatter_set(arr, index, values):
+    """`arr.at[index].set(values)` as ONE program: `index` (a tuple of
+    int32 arrays, one per axis) and `values` (an array of the same
+    length, or a scalar) are arguments, so host numpy arrays ride the
+    launch and a program is named by arr's shape and dtype, the index
+    rank and length, and whether `values` is a scalar — nothing a
+    flap changes. The caller hands over no two values for one cell
+    (XLA leaves the winner among duplicate indices unspecified) and
+    only indices inside `arr`: XLA drops an update that is not, and the
+    default mode adds nothing to that, so there is no
+    promise_in_bounds to gain.
+
+    Not donated: the warm start's `arr` is the previous artifact's
+    distance matrix, which Decision may still hold, and the device
+    copy is 0.03 ms — what a patch costs is its dispatch."""
+    return arr.at[index].set(values)
+
 
 #: TpuSpfSolver.last_phase_ms as a view of a compute_routes call's span
 #: record: six phases that follow one another and cover the call (only
@@ -295,7 +315,7 @@ class TpuSpfSolver:
         # observability: full table (re)builds+uploads vs in-place patch
         # scatters vs pure hits — under metric-only churn, `uploads`
         # must stay flat after warmup (tested)
-        # scatter_calls: eager scatter programs dispatched (table
+        # scatter_calls: _scatter_set programs dispatched (table
         # patches + the warm start's INF scatters), each a host→device
         # round of its own
         self.dev_cache_stats = {
@@ -455,33 +475,42 @@ class TpuSpfSolver:
         return dset
 
     def _apply_patch_suffix(self, cache, csr) -> None:
-        """Scatter the unapplied journal suffix into every resident set."""
+        """Scatter the unapplied journal suffix into every resident set,
+        one compiled scatter (`_set`) per patched array.
+
+        A suffix can name one cell twice with different values (a flap
+        fully reverted inside one debounce window solves nothing, so its
+        patch waits here for the next flap of the same link): the last
+        patch of a cell is the one scattered."""
         if cache["version"] == csr.version:
             return
         done = cache.get("journal_len", 0)
         if len(csr.patches) > done:
             self.dev_cache_stats["patches"] += 1
             with profiling.annotate("spf:patch_scatter"):
-                new_patches = list(csr.patches[done:])
+                # edge_idx names the cell in all three layouts: its
+                # dense slot is (dst, edge_idx - row_start[dst])
+                last = {p.edge_idx: p for p in csr.patches[done:]}
                 # pad the patch arrays to a bucket (repeating the last patch
                 # — duplicate .set of the same value is a no-op): without
                 # this, every distinct patch COUNT is a new traced shape and
                 # the scatter re-compiles on every churn rebuild
                 # (~130 ms/cycle measured in round 1)
-                n = len(new_patches)
-                nb = pad_batch(n)
-                patches = new_patches + [new_patches[-1]] * (nb - n)
+                patches = list(last.values())
+                patches += [patches[-1]] * (
+                    pad_batch(len(patches)) - len(patches)
+                )
                 rows = np.array([p.dense_row for p in patches], np.int32)
                 cols = np.array([p.dense_col for p in patches], np.int32)
                 idxs = np.array([p.edge_idx for p in patches], np.int32)
                 vals = np.array([p.metric for p in patches], np.int32)
                 for name, dset in cache["sets"].items():
                     if name == "dense":
-                        dset["wgt"] = self._eager_set(
+                        dset["wgt"] = self._set(
                             dset["wgt"], (rows, cols), vals
                         )
                     elif name == "edge":
-                        dset["metric"] = self._eager_set(
+                        dset["metric"] = self._set(
                             dset["metric"], (idxs,), vals
                         )
                     elif name == "split":
@@ -497,7 +526,7 @@ class TpuSpfSolver:
                             br = np.where(in_base, rows, rows[in_base][0])
                             bc = np.where(in_base, cols, cols[in_base][0])
                             bv = np.where(in_base, vals, vals[in_base][0])
-                            dset["base_wgt"] = self._eager_set(
+                            dset["base_wgt"] = self._set(
                                 dset["base_wgt"], (br, bc), bv
                             )
                         if (~in_base).any():
@@ -509,20 +538,18 @@ class TpuSpfSolver:
                                 sel, cols - w, cols[sel][0] - w
                             )
                             ov = np.where(sel, vals, vals[sel][0])
-                            dset["ov_wgt"] = self._eager_set(
+                            dset["ov_wgt"] = self._set(
                                 dset["ov_wgt"], (orow, ocol), ov
                             )
             cache["journal_len"] = len(csr.patches)
         cache["version"] = csr.version
 
-    def _eager_set(self, arr, index: tuple, values):
-        """`arr.at[index].set(values)` from host index arrays, dispatched
-        eagerly: one scatter program and a dozen tiny index-shaping ones
-        a call, counted as dev_cache_stats["scatter_calls"]."""
+    def _set(self, arr, index: tuple, values):
+        """One `_scatter_set` program on `arr`'s device, its host
+        `index` arrays and `values` (array or scalar) transferred with
+        the launch; counted as dev_cache_stats["scatter_calls"]."""
         self.dev_cache_stats["scatter_calls"] += 1
-        if isinstance(values, np.ndarray):
-            values = jnp.asarray(values)
-        return arr.at[tuple(jnp.asarray(i) for i in index)].set(values)
+        return _scatter_set(arr, index, values)
 
     def trim_caches(self, fingerprint_cap: int = 8) -> None:
         """Reclaim assembly-cache memory (e.g. after a fleet pass on a
@@ -1264,7 +1291,7 @@ class TpuSpfSolver:
                     cols[:n_sc] = cols_all
                     top = _WARM_SCATTER_TIERS[-1]
                     for off in range(0, nb, top):
-                        dist_dev = self._eager_set(
+                        dist_dev = self._set(
                             dist_dev,
                             (rows[off : off + top], cols[off : off + top]),
                             INF_DIST,

@@ -252,7 +252,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:to_csr",                #       LinkState → CSR snapshot
     "spf:prepare",               #       cold: to_csr + pads + dispatch
     "spf:dispatch",              #       device tables: hit, patch, build
-    "spf:patch_scatter",         #         journal suffix → eager scatters
+    "spf:patch_scatter",         #         journal suffix → compiled scatters
     "spf:batched_solve",         #       cold fused kernel + packed fetch
     "spf:batched_dist",          #       unfused kernels, dispatch only
     "spf:sharded_solve",         #       mesh kernel, dispatch only
@@ -266,7 +266,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:ksp",                   #         KSP prefixes' batched paths
     "spf:dist_mirror",           #       warm: [vp, B] matrix → host
     "spf:warm_cone",             #       warm: host cone walk
-    "spf:warm_scatter",          #       warm: cone → INF, eager scatters
+    "spf:warm_scatter",          #       warm: cone → INF, compiled scatter
     "spf:warm_solve",            #       warm kernel + packed fetch
     "spf:warm_unpack",           #       warm: unpack + change mask
     "spf:warm_reassemble",       #       warm: scoped routes + MPLS

@@ -553,3 +553,301 @@ def test_last_breakdown_ms_is_a_view_of_the_rebuilds_span_record(backend):
         assert d.counters.stats["profile.spf:rib_assembly_ms"].count == 1
     else:
         assert all(warm[n] == 0.0 for n in warm_spans)
+
+
+# ------------------------------------------------- the compiled patch scatter
+
+
+def _split_tables_of(csr):
+    """The split tables built from scratch out of a (patched) CSR."""
+    from openr_tpu.ops.spf_split import build_split_tables
+
+    return build_split_tables(
+        csr.edge_src, csr.edge_dst, csr.edge_metric, csr.num_nodes
+    )
+
+
+def _adj_index(adj_cur, node, other):
+    """Position of `node`'s adjacency to `other`, as flap_pub takes it."""
+    return [a.other_node_name for a in adj_cur[node].adjacencies].index(other)
+
+
+def _star_ls():
+    """Hub n00 with 20 leaves: the hub's 20 in-edges overflow the split
+    tables' base width (8), so its patches split between base_wgt and
+    ov_wgt; every leaf's one in-edge sits in the base table."""
+    from openr_tpu.decision.linkstate import LinkState
+    from openr_tpu.types.topology import Adjacency, AdjacencyDatabase
+
+    def adj(me, other, metric):
+        return Adjacency(
+            other_node_name=other, if_name=f"{me}-{other}",
+            other_if_name=f"{other}-{me}", metric=metric,
+        )
+
+    leaves = [f"n{i:02d}" for i in range(1, 21)]
+    dbs = {
+        "n00": AdjacencyDatabase(
+            this_node_name="n00",
+            adjacencies=tuple(adj("n00", x, 10) for x in leaves),
+        )
+    }
+    for x in leaves:
+        dbs[x] = AdjacencyDatabase(
+            this_node_name=x, adjacencies=(adj(x, "n00", 10),)
+        )
+    ls = LinkState("0")
+    for db in dbs.values():
+        ls.update_adjacency_db(db)
+
+    def set_metric(me, other, metric):
+        db = dbs[me]
+        dbs[me] = dataclasses.replace(db, adjacencies=tuple(
+            dataclasses.replace(a, metric=metric)
+            if a.other_node_name == other else a
+            for a in db.adjacencies
+        ))
+        assert ls.update_adjacency_db(dbs[me])
+
+    return ls, set_metric
+
+
+@pytest.fixture(scope="module")
+def patched_star():
+    """Every table set resident, then ONE journal suffix of four patches
+    scattered into all of them: three distinct cells (padded to a batch
+    of 8), one of them patched twice with different values, two in the
+    hub's row on either side of the base width. Returns {site: (what
+    the device holds, the numpy reference)}: the reference applies the
+    suffix in journal order to the unpatched host tables."""
+    import jax.numpy as jnp
+
+    from openr_tpu.decision.spf_backend import (
+        TpuSpfSolver,
+        _warm_scatter_pad,
+    )
+    from openr_tpu.ops.spf import INF_DIST
+
+    ls, set_metric = _star_ls()
+    solver = TpuSpfSolver(native_rib="off")
+    csr0 = ls.to_csr()
+    for want in ("dense", "edge", "split"):
+        solver._device_arrays(csr0, want)
+    t0 = _split_tables_of(csr0)
+    w, ov_pos = t0["base_nbr"].shape[1], t0["ov_pos"]
+    assert w == 8 and ov_pos[csr0.name_to_id["n00"]] >= 0
+    ref = {
+        "dense_wgt": csr0.dense_tables()[1].copy(),
+        "edge_metric": csr0.edge_metric.copy(),
+        "split_base_wgt": t0["base_wgt"].copy(),
+        "split_ov_wgt": t0["ov_wgt"].copy(),
+    }
+    set_metric("n03", "n00", 7)   # hub row, a base column
+    set_metric("n15", "n00", 9)   # hub row, an overflow column
+    ls.to_csr()                   # journalled, no solve: not on the device
+    set_metric("n03", "n00", 4)   # the same cell again, another value
+    set_metric("n00", "n05", 6)   # a leaf's row
+    csr = ls.to_csr()
+    suffix = csr.patches[len(csr0.patches):]
+    assert [p.metric for p in suffix] == [7, 9, 4, 6]
+    assert len({p.edge_idx for p in suffix}) == 3
+    assert {p.dense_col < w for p in suffix} == {True, False}
+    calls0 = solver.dev_cache_stats["scatter_calls"]
+    solver._device_arrays(csr, "split")
+    # one program a patched array: dense, edge, split base, split overflow
+    assert solver.dev_cache_stats["scatter_calls"] - calls0 == 4
+    for p in suffix:
+        ref["dense_wgt"][p.dense_row, p.dense_col] = p.metric
+        ref["edge_metric"][p.edge_idx] = p.metric
+        if p.dense_col < w:
+            ref["split_base_wgt"][p.dense_row, p.dense_col] = p.metric
+        else:
+            ref["split_ov_wgt"][ov_pos[p.dense_row], p.dense_col - w] = p.metric
+    # the reference is the patched CSR's own tables, built from scratch
+    t1 = _split_tables_of(csr)
+    np.testing.assert_array_equal(ref["dense_wgt"], csr.dense_tables()[1])
+    np.testing.assert_array_equal(ref["edge_metric"], csr.edge_metric)
+    np.testing.assert_array_equal(ref["split_base_wgt"], t1["base_wgt"])
+    np.testing.assert_array_equal(ref["split_ov_wgt"], t1["ov_wgt"])
+    sets = solver._dev[csr.base_version]["sets"]
+    got = {
+        "dense_wgt": sets["dense"]["wgt"],
+        "edge_metric": sets["edge"]["metric"],
+        "split_base_wgt": sets["split"]["base_wgt"],
+        "split_ov_wgt": sets["split"]["ov_wgt"],
+    }
+    # the warm start's site: a cone's cells to INF_DIST, padded to the
+    # first tier by repeating the last cell; the matrix handed in is the
+    # previous artifact's and keeps its values (no donation)
+    rng = np.random.default_rng(5)
+    host = rng.integers(0, 1000, (t0["vp"], 8)).astype(np.int32)
+    dist_dev = jnp.asarray(host)
+    cells = [(3, 0), (7, 0), (7, 5), (t0["vp"] - 2, 7)]
+    nb = _warm_scatter_pad(len(cells))
+    rows = np.full(nb, cells[-1][0], np.int32)
+    cols = np.full(nb, cells[-1][1], np.int32)
+    rows[: len(cells)], cols[: len(cells)] = zip(*cells)
+    got["dist_matrix"] = solver._set(dist_dev, (rows, cols), INF_DIST)
+    ref["dist_matrix"] = host.copy()
+    ref["dist_matrix"][rows, cols] = INF_DIST
+    np.testing.assert_array_equal(np.asarray(dist_dev), host)
+    return {k: (np.asarray(got[k]), ref[k]) for k in ref}
+
+
+@pytest.mark.parametrize(
+    "site",
+    ["dense_wgt", "edge_metric", "split_base_wgt", "split_ov_wgt",
+     "dist_matrix"],
+)
+def test_compiled_scatter_equals_numpy_reference(patched_star, site):
+    """Each of the five sites that patch a device array through
+    `_scatter_set` holds what numpy's sequential assignment gives: a
+    padded batch, one cell twice in a suffix (the last value wins), a
+    suffix split between the base and the overflow table."""
+    got, ref = patched_star[site]
+    assert got.dtype == ref.dtype and got.shape == ref.shape
+    np.testing.assert_array_equal(got, ref)
+
+
+def _split_tables_equal_patched_csr(d):
+    """The solver's resident split tables against tables built from
+    scratch out of the LSDB's patched CSR."""
+    (ls, _ps), = d._snapshot_states().values()
+    csr = ls.to_csr()
+    want = _split_tables_of(csr)
+    dset = d._tpu._dev[csr.base_version]["sets"]["split"]
+    for k in ("base_wgt", "ov_wgt"):
+        np.testing.assert_array_equal(np.asarray(dset[k]), want[k], k)
+
+
+def test_flap_reverted_in_one_window_then_reflapped():
+    """A flap fully reverted inside one debounce window changes nothing:
+    the rebuild solves nothing and its patch stays in the journal. (The
+    LSDB has to see both values for that: Decision keeps one value a key
+    until something drains the buffer — an LSDB reader, or the rebuild's
+    own decode await with the revert arriving during it.) The next flap
+    of the same link then scatters a suffix that names the cell twice,
+    [(e, 10), (e, 30)] — the device tables must hold the last value, and
+    the RIB must equal the oracle's after each rebuild."""
+    from openr_tpu.decision.oracle import compute_routes as oracle_routes
+
+    def assert_oracle(d, step):
+        (ls, ps), = d._snapshot_states().values()
+        ref = oracle_routes(ls, ps, d.node_name)
+        assert d.rib.unicast_routes == ref.unicast_routes, step
+        assert d.rib.mpls_routes == ref.mpls_routes, step
+
+    async def body():
+        d = mk_decision("tpu")
+        adj_dbs, prefix_dbs = topogen.grid(5, 5, metric=10)
+        d.process_publication(adj_pub(adj_dbs))
+        d.process_publication(prefix_pub(prefix_dbs))
+        await d._rebuild_routes()
+        assert_oracle(d, "initial")
+        adj_cur = {db.this_node_name: db for db in adj_dbs}
+        k = _adj_index(adj_cur, "node-1", "node-2")
+        st = d._tpu.dev_cache_stats
+        warm0, patches0 = d._tpu.warm_solves, st["patches"]
+        # up and back down inside one window: one rebuild, no solve
+        d.process_publication(flap_pub(adj_cur, "node-1", k, 30, 2))
+        d._drain_pending()  # as every LSDB reader does
+        d.process_publication(flap_pub(adj_cur, "node-1", k, 10, 3))
+        await d._rebuild_routes()
+        assert d.counters.get("decision.rebuild.topo_delta") == 1
+        assert d.counters.get("decision.rebuild.full") == 1
+        assert d._tpu.warm_solves == warm0 and st["patches"] == patches0
+        assert_oracle(d, "reverted in one window")
+        # the same link again: both patches of the cell in one suffix
+        d.process_publication(flap_pub(adj_cur, "node-1", k, 30, 4))
+        await d._rebuild_routes()
+        assert d.counters.get("decision.rebuild.full") == 1
+        assert d._tpu.warm_solves == warm0 + 1
+        assert st["patches"] == patches0 + 1
+        (ls, _ps), = d._snapshot_states().values()
+        assert [p.metric for p in ls.to_csr().patches[-2:]] == [10, 30]
+        assert_oracle(d, "flapped again")
+        _split_tables_equal_patched_csr(d)
+        # and back: a lowered edge, the tables follow
+        d.process_publication(flap_pub(adj_cur, "node-1", k, 10, 5))
+        await d._rebuild_routes()
+        assert d._tpu.warm_solves == warm0 + 2
+        assert_oracle(d, "restored")
+        _split_tables_equal_patched_csr(d)
+
+    run(body())
+
+
+def test_a_warm_flap_patches_with_one_program_a_scatter(monkeypatch):
+    """After warm-up a warm flap compiles nothing, and what it dispatches
+    inside `spf:patch_scatter` and `spf:warm_scatter` is `_scatter_set`
+    alone, at most two of them. The programs are counted through the
+    compile ledger: with jax's caches dropped, every program a span
+    dispatches compiles again and so shows there by name (an eager
+    `.at[].set` shows as less, add, select_n, ..., scatter)."""
+    import contextlib
+
+    import jax
+
+    from openr_tpu.monitor import compile_ledger, profiling
+
+    led = compile_ledger.ledger()
+    assert led.installed
+    scatter_spans = ("spf:patch_scatter", "spf:warm_scatter")
+    seen: dict[str, dict[str, int]] = {n: {} for n in scatter_spans}
+    real_annotate = profiling.annotate
+
+    @contextlib.contextmanager
+    def spy(name, counters=None):
+        before = led.snapshot()
+        with real_annotate(name, counters) as span:
+            yield span
+        if name in seen:
+            for fn, n in before.delta(led.snapshot()).items():
+                seen[name][fn] = seen[name].get(fn, 0) + n
+
+    async def body():
+        d = mk_decision("tpu")
+        adj_dbs, prefix_dbs = topogen.grid(5, 5, metric=10)
+        d.process_publication(adj_pub(adj_dbs))
+        d.process_publication(prefix_pub(prefix_dbs))
+        await d._rebuild_routes()
+        adj_cur = {db.this_node_name: db for db in adj_dbs}
+        k = _adj_index(adj_cur, "node-1", "node-2")
+        version = 1
+
+        async def flap(metric):
+            nonlocal version
+            version += 1
+            d.process_publication(
+                flap_pub(adj_cur, "node-1", k, metric, version)
+            )
+            await d._rebuild_routes()
+
+        for metric in (30, 10, 30, 10):  # warm-up: raise and restore
+            await flap(metric)
+        st = d._tpu.dev_cache_stats
+        before, calls0 = led.snapshot(), st["scatter_calls"]
+        await flap(30)
+        assert before.delta(led.snapshot()) == {}  # compiled nothing
+        assert 1 <= st["scatter_calls"] - calls0 <= 2
+        await flap(10)
+        # the same two flaps again, every program compiling anew
+        jax.clear_caches()
+        monkeypatch.setattr(profiling, "annotate", spy)
+        calls0 = st["scatter_calls"]
+        await flap(30)
+        await flap(10)
+        assert d.counters.get("decision.rebuild.full") == 1
+        assert_parity(d)
+        return st["scatter_calls"] - calls0
+
+    calls = run(body())
+    # a raise: one table patch, one cone scatter; a restore: its table
+    # patch alone (nothing rises, the cone is empty)
+    assert calls == 3
+    assert set(seen["spf:patch_scatter"]) == {"_scatter_set"}
+    assert set(seen["spf:warm_scatter"]) == {"_scatter_set"}
+    # two programs in all (the table's shape and the matrix's), compiled
+    # once each however many flaps dispatch them
+    assert seen["spf:patch_scatter"]["_scatter_set"] == 1
+    assert seen["spf:warm_scatter"]["_scatter_set"] == 1
