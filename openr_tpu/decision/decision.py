@@ -1284,10 +1284,34 @@ class Decision(OpenrModule):
                 held.stop(rec)
             with profiling.annotate("decision:rebuild"):
                 done = await self._rebuild(rec)
-        if done:
-            # published breakdown (round-2 verdict item 3): where a
-            # steady-state churn rebuild actually spends its time
+            if done:
+                # published breakdown (round-2 verdict item 3): where a
+                # steady-state churn rebuild actually spends its time
+                self.last_breakdown_ms = _breakdown_view(rec)
+                if self._tpu is not None and self._rebuild_path == "full":
+                    await self._prewarm_flap_programs(rec)
+
+    async def _prewarm_flap_programs(self, rec: profiling.SpanRecord) -> None:
+        """After a rebuild that solved an area in full: have every
+        program the next metric-only event can need compiled before that
+        event's rebuild begins (TpuSpfSolver.prewarm_flap_programs,
+        docs/Decision.md "Pre-warmed flap programs"). The routes are out
+        already and Fib programs them meanwhile; this coroutine is the
+        debounce's, so no rebuild starts until it returns. In the worker
+        thread, as the solve was: a compile must not hold the loop."""
+        arts = [
+            c["art"] for c in self._area_cache.values()
+            if c["art"] is not None
+        ]
+        if arts and await asyncio.to_thread(
+            lambda: sum(map(self._tpu.prewarm_flap_programs, arts))
+        ):
             self.last_breakdown_ms = _breakdown_view(rec)
+            if self.counters:
+                self.counters.set(
+                    "decision.spf.prewarm_programs",
+                    self._tpu.spf_kernel_stats["prewarm_programs"],
+                )
 
     async def _rebuild(self, rec: profiling.SpanRecord) -> bool:
         """One rebuild under the open record `rec`; False when it failed
@@ -1321,7 +1345,8 @@ class Decision(OpenrModule):
                     pe.add_perf_event(
                         perf.DECISION_DEBOUNCED, node=self.node_name
                     )
-                states = self._snapshot_states()
+                with profiling.annotate("decision:snapshot"):
+                    states = self._snapshot_states()
                 # consume the dirt AFTER the snapshot: everything the
                 # snapshot folded in has its dirt recorded by now, and
                 # anything arriving later stays pending for the rebuild
@@ -1437,6 +1462,9 @@ class Decision(OpenrModule):
             self.counters.set(
                 "decision.rebuild.area_solves", self._area_solves
             )
+            # counted in _publish; touched here so that it reads 0, not
+            # nothing, until the first rebuild that changes no route
+            self.counters.increment("decision.rebuild.no_change", 0)
             self.counters.set("decision.spf_ms", self._last_spf_ms)
             # windowed latency stats (exported as .p50/.p99 per window):
             # the solve+assembly+diff core, and the full rebuild
@@ -1527,7 +1555,9 @@ class Decision(OpenrModule):
                     perf.ROUTE_UPDATE_SENT, node=self.node_name
                 )
             update.perf_events = traces
-        # else: the rebuild proved no route change — the traces end here
+        elif self.counters:
+            # the rebuild proved no route change — the traces end here
+            self.counters.increment("decision.rebuild.no_change")
         if first:
             update.type = RouteUpdateType.FULL_SYNC
             self.rib_computed.set()

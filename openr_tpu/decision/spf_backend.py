@@ -338,6 +338,7 @@ class TpuSpfSolver:
         # which run no dense sweep. A spill or a net sweep means the
         # tail's frontier cap was too small (docs/Monitor.md "Spans").
         # warm_cone_cells sizes the warm start's host-side cone walk.
+        # prewarm_programs: programs prewarm_flap_programs ran (set-up).
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
@@ -345,7 +346,13 @@ class TpuSpfSolver:
             "tail_spills": 0,
             "warm_tail_rounds": 0, "warm_net_sweeps": 0,
             "warm_tail_spills": 0, "warm_cone_cells": 0,
+            "prewarm_programs": 0,
         }
+        # what prewarm_flap_programs has run its programs for: one key
+        # per (table shapes, batch, has_overloads, gs_chunks), i.e. per
+        # set of compiled programs — O(log V) over a process's life
+        # (tight_nodes / pad_batch buckets)
+        self._prewarmed: set[tuple] = set()
         # SPF engine invocations (kernel launch OR native solve): the
         # dirty-scoped rebuild's acceptance signal — prefix-only churn
         # must leave this flat while routes still update (tested)
@@ -856,15 +863,7 @@ class TpuSpfSolver:
                     self._nbr_cache.pop(next(iter(self._nbr_cache)))
             n = len(nbr_ids)
             b = pad_batch(1 + n)
-            nbr_metric_real = np.empty(n, dtype=np.int32)
-            for i, d in enumerate(nbr_ids):
-                # same METRIC_MAX clamp as the CSR builder / oracle, or
-                # the first-hop identity breaks for metrics above the
-                # clamp
-                nbr_metric_real[i] = min(
-                    min(det[1] for det in csr.details(my_id, d)),
-                    METRIC_MAX,
-                )
+            nbr_metric_real = self._nbr_metrics(csr, my_id, nbr_ids)
             native = self._use_native()
             if native:
                 self.spf_kernel_stats["engine_native"] += 1
@@ -976,6 +975,19 @@ class TpuSpfSolver:
                 )
             )
         return csr, np.asarray(dist), fh, nbr_ids, lfa
+
+    @staticmethod
+    def _nbr_metrics(csr, my_id: int, nbr_ids: list[int]) -> np.ndarray:
+        """metric(root -> neighbor) per neighbor slot: the least over
+        parallel adjacencies, with the same METRIC_MAX clamp as the CSR
+        builder / oracle, or the first-hop identity breaks for metrics
+        above the clamp."""
+        out = np.empty(len(nbr_ids), dtype=np.int32)
+        for i, d in enumerate(nbr_ids):
+            out[i] = min(
+                min(det[1] for det in csr.details(my_id, d)), METRIC_MAX
+            )
+        return out
 
     def _rib_pad_arrays(
         self, csr, my_id: int, nbr_ids: list[int], nbr_metric_real, b: int
@@ -1176,6 +1188,95 @@ class TpuSpfSolver:
                 cols_all.append(c)
         return rows_all, cols_all, seed, cone_union
 
+    def prewarm_flap_programs(self, art: SolveArtifact) -> int:
+        """Run, once per set of shapes, every program the next
+        metric-only event on `art`'s base can need, so that the event
+        itself compiles nothing: `_scatter_set` on `base_wgt`, on
+        `ov_wgt` where the base has overflow rows (both at the patch
+        bucket one link event pads to), on the [vp, B] distance matrix
+        at the first cone tier, and the warm kernel. Which of them an
+        event meets depends on the link it draws (a raise that is tight
+        in some column walks a cone; a patch lands in the overflow
+        table only at a node whose in-degree passes the base width), so
+        no run of quiet events can promise that the rare ones exist.
+
+        Changes nothing: every result is dropped (`_scatter_set` and
+        the kernel donate no argument, so the tables and the artifact's
+        distance matrix are the arrays they were), the scatters aim at
+        padding that already holds the value they write, and the kernel
+        starts from the solved fixpoint with an empty seed mask. No
+        stat but `prewarm_programs` moves (`_set` and `_dispatch` are
+        not called). Returns the number of programs run: 0 for an
+        artifact `warm_compute_routes` would refuse, and for shapes
+        already met."""
+        if self.enable_lfa or art.solved is None:
+            return 0
+        csr, dist, _fh, nbr_ids, lfa = art.solved
+        cache = self._dev.get(csr.base_version)
+        if (
+            lfa is not None
+            or not isinstance(dist, _LazyDist)
+            or self._pick_table(csr) != "split"
+            or cache is None
+            or "split" not in cache["sets"]
+        ):
+            return 0
+        dev = cache["sets"]["split"]
+        vp = dev["vp"]
+        bb = pad_batch(1 + len(nbr_ids))
+        has_over = bool(csr.node_overloaded.any())
+        gs = pick_gs_chunks(vp)
+        tables = (
+            "base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt",
+            "out_nbr", "over",
+        )
+        key = (*(dev[t].shape for t in tables), bb, has_over, gs)
+        if key in self._prewarmed:
+            return 0
+        self._prewarmed.add(key)
+        with profiling.annotate("spf:prewarm"):
+            dead = vp - 1  # padding row: INF_DIST in every table
+            n_patch = pad_batch(1)
+            rows = np.full(n_patch, dead, np.int32)
+            cols = np.zeros(n_patch, np.int32)
+            vals = np.full(n_patch, INF_DIST, np.int32)
+            _scatter_set(dev["base_wgt"], (rows, cols), vals)
+            programs = 3
+            if (cache["host"]["split"]["ov_pos"] >= 0).any():
+                _scatter_set(dev["ov_wgt"], (cols, cols), vals)
+                programs += 1
+            n_cone = _WARM_SCATTER_TIERS[0]
+            _scatter_set(
+                dist._dev,
+                (np.full(n_cone, dead, np.int32), np.zeros(n_cone, np.int32)),
+                INF_DIST,
+            )
+            my_id = csr.name_to_id[art.my_node]
+            roots, nbr_ids_p, nbr_metric, nbr_over = self._rib_pad_arrays(
+                csr, my_id, nbr_ids,
+                self._nbr_metrics(csr, my_id, nbr_ids), bb,
+            )
+            args = (
+                *(dev[t] for t in tables), jnp.asarray(roots),
+                jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
+                jnp.asarray(nbr_over), dist._dev,
+                jnp.asarray(np.zeros(vp, bool)),
+            )
+            batched_sssp_split_warm_rib(
+                *args, has_overloads=has_over, gs_chunks=gs
+            )
+            # the kernel's cost row, which the first warm start would
+            # otherwise lower and compile for (device.observe)
+            device_telemetry.observe(
+                "batched_sssp_split_warm_rib",
+                lambda: batched_sssp_split_warm_rib.lower(
+                    *args, has_overloads=has_over, gs_chunks=gs
+                ),
+                span="spf:warm_solve",
+            )
+        self.spf_kernel_stats["prewarm_programs"] += programs
+        return programs
+
     def warm_compute_routes(
         self,
         art: SolveArtifact,
@@ -1271,14 +1372,9 @@ class TpuSpfSolver:
             self.spf_kernel_stats["warm_cone_cells"] += len(rows_all)
             _table, dev, has_over = self._dispatch(csr)
             vp = dev["vp"]
-            nbr_metric_real = np.empty(len(nbr_ids), dtype=np.int32)
-            for i, d in enumerate(nbr_ids):
-                nbr_metric_real[i] = min(
-                    min(det[1] for det in csr.details(my_id, d)),
-                    METRIC_MAX,
-                )
             roots, nbr_ids_p, nbr_metric, nbr_over = self._rib_pad_arrays(
-                csr, my_id, nbr_ids, nbr_metric_real, bb
+                csr, my_id, nbr_ids,
+                self._nbr_metrics(csr, my_id, nbr_ids), bb,
             )
             dist_dev = old_dist._dev
             with profiling.annotate("spf:warm_scatter"):

@@ -50,6 +50,8 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.rebuild.cached_areas",
         "decision.rebuild.area_solves",
         "decision.rebuild.failed",
+        # rebuilds published with an empty update: Fib is told nothing
+        "decision.rebuild.no_change",
         # merge-book fallback matrix (docs/Decision.md): scoped = the
         # delta fold patched the persistent merged RIB in place; full =
         # a first-build/policy/mismatch round re-armed it from scratch
@@ -247,6 +249,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "decision:rebuild",          # the rebuild coroutine, all of it
     "decision:decode",           #   serde decode of the batch (thread)
     "decision:apply_snapshot",   #   LSDB apply, dirt, snapshot (loop)
+    "decision:snapshot",         #     the LSDB copy the solver works on
     "decision:compute_diff",     #   the solver thread, as the loop waits
     "decision:compute_rib",      #     per-area compute + merge
     "spf:to_csr",                #       LinkState → CSR snapshot
@@ -273,6 +276,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "decision:diff",             #     RIB delta / work-ledger commit
     "decision:export_counters",  #   markers, trim policy, counters
     "decision:publish",          #   merge book + route_updates.push
+    "spf:prewarm",               # after a full rebuild: the flap's programs
 )
 
 #: every span name the program opens (tests/test_profiling.py checks the
