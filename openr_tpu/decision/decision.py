@@ -1040,10 +1040,22 @@ class Decision(OpenrModule):
 
     def _snapshot_states(self) -> dict[str, tuple[LinkState, PrefixState]]:
         """Taken on the event loop, so the off-thread solve never races
-        _pub_loop's LSDB mutations."""
+        _pub_loop's LSDB mutations. A PrefixState no publication has
+        touched since its last snapshot hands that one out again
+        (counted .prefix_shared); else it copies its outer dict
+        (.prefix_copied)."""
+        link_states = self.link_states  # drains what is pending, once
+        prefix_states = self._prefix_states
+        shared = sum(ps.snapshot_is_current for ps in prefix_states.values())
+        if self.counters:
+            # both every time, so that each reads 0 and not nothing
+            self.counters.increment("decision.snapshot.prefix_shared", shared)
+            self.counters.increment(
+                "decision.snapshot.prefix_copied", len(prefix_states) - shared
+            )
         return {
-            a: (self.link_states[a].snapshot(), self.prefix_states[a].snapshot())
-            for a in self.link_states
+            a: (ls.snapshot(), prefix_states[a].snapshot())
+            for a, ls in link_states.items()
         }
 
     def compute_rib(

@@ -587,6 +587,9 @@ class PrefixState:
         # the solver_view gen is content-stable; across instances it
         # can never collide.
         self._lineage = next(_PS_LINEAGE)
+        # the frozen copy snapshot() handed out last: while _rev stands
+        # where it was built, the same object serves again
+        self._snap: PrefixState | None = None
 
     def update_prefix_db(self, db: PrefixDatabase) -> set[IpPrefix]:
         """Apply a node's prefix advertisement; returns changed prefixes."""
@@ -598,22 +601,43 @@ class PrefixState:
                     changed.add(entry.prefix)
             return changed
         for entry in db.prefix_entries:
-            per_node = self._entries.setdefault(entry.prefix, {})
+            per_node = self._entries.get(entry.prefix, {})
             if per_node.get(node) != entry:
-                per_node[node] = entry
+                # rebind, never write in place: snapshots share the
+                # per-prefix dicts (see snapshot())
+                self._entries[entry.prefix] = {**per_node, node: entry}
                 changed.add(entry.prefix)
         if changed:
             self._rev += 1
         return changed
 
     def snapshot(self) -> "PrefixState":
-        """Consistent copy for off-thread solves (entries are frozen)."""
-        snap = PrefixState(self.area)
-        snap._entries = {p: dict(per) for p, per in self._entries.items()}  # orlint: disable=OR013 — LSDB snapshot copy for the off-thread solve, measured by decision.rebuild_ms; not a dataflow stage
-        snap._rev = self._rev
-        snap._view_cell = self._view_cell  # shared cell, rev-keyed
-        snap._lineage = self._lineage  # same lineage: gen stays stable
-        return snap
+        """Frozen view for off-thread solves, shared by revision.
+
+        While ``_rev`` stands this returns the object it returned last
+        time. When ``_rev`` has moved it builds one: a copy of the outer
+        dict only. The per-prefix dicts are values, shared by the live
+        object and every snapshot: the three writers (update_prefix_db,
+        withdraw, withdraw_node) rebind ``_entries[prefix]`` to a new
+        dict and never write one in place, and nothing else may write
+        ``_entries`` once a snapshot exists. The live outer dict is
+        never handed out, so a later mutation cannot show in a snapshot.
+        A snapshot is read-only.
+        """
+        if not self.snapshot_is_current:
+            snap = PrefixState(self.area)
+            snap._entries = dict(self._entries)
+            snap._rev = self._rev
+            snap._view_cell = self._view_cell  # shared cell, rev-keyed
+            snap._lineage = self._lineage  # same lineage: gen stays stable
+            self._snap = snap
+        return self._snap
+
+    @property
+    def snapshot_is_current(self) -> bool:
+        """Whether snapshot() would return the object it returned last
+        (nothing changed since) rather than build one."""
+        return self._snap is not None and self._snap._rev == self._rev
 
     def election_view(self, name_to_id: dict, base_version: int):
         """Cached columnar election classification for RIB assembly
@@ -662,9 +686,13 @@ class PrefixState:
     def withdraw(self, node: str, prefix: IpPrefix) -> bool:
         per_node = self._entries.get(prefix)
         if per_node and node in per_node:
-            del per_node[node]
-            if not per_node:
+            if len(per_node) == 1:
                 del self._entries[prefix]
+            else:
+                # rebind, never write in place (see snapshot())
+                self._entries[prefix] = {
+                    n: e for n, e in per_node.items() if n != node
+                }
             self._rev += 1
             return True
         return False

@@ -52,6 +52,10 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.rebuild.failed",
         # rebuilds published with an empty update: Fib is told nothing
         "decision.rebuild.no_change",
+        # a rebuild's PrefixState snapshot: handed out again (no prefix
+        # changed since the last one) / built (one outer-dict copy)
+        "decision.snapshot.prefix_shared",
+        "decision.snapshot.prefix_copied",
         # merge-book fallback matrix (docs/Decision.md): scoped = the
         # delta fold patched the persistent merged RIB in place; full =
         # a first-build/policy/mismatch round re-armed it from scratch
@@ -249,7 +253,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "decision:rebuild",          # the rebuild coroutine, all of it
     "decision:decode",           #   serde decode of the batch (thread)
     "decision:apply_snapshot",   #   LSDB apply, dirt, snapshot (loop)
-    "decision:snapshot",         #     the LSDB copy the solver works on
+    "decision:snapshot",         #     the LSDB view the solver works on
     "decision:compute_diff",     #   the solver thread, as the loop waits
     "decision:compute_rib",      #     per-area compute + merge
     "spf:to_csr",                #       LinkState → CSR snapshot
