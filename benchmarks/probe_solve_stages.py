@@ -69,8 +69,7 @@ for it in range(ITERS):
     nbr_over[:n] = csr.node_overloaded[np.array(nbr_ids, dtype=np.int64)]
     roots = np.full(b, my_id, dtype=np.int32)
     roots[1 : 1 + n] = nbr_ids
-    table, dev, has_over = tpu._dispatch(csr)
-    assert table == "split", table
+    dev, has_over = tpu._dispatch(csr)
     vp = dev["vp"]
     gs = tpu._pick_gs_and_count(dev)
     t1 = time.perf_counter()

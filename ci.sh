@@ -156,8 +156,8 @@ JAX_PLATFORMS=cpu python benchmarks/bench_churn.py \
 echo "== device-telemetry smoke (kernel cost ledger + ctrl export) =="
 # the device telemetry gate (docs/Monitor.md "Device telemetry"): on
 # the CPU backend every canonical jitted kernel entry point (split RIB
-# solve, batched split/dense/edge kernels, sharded split over a 2x2
-# mesh, device election, KSP, pallas) must own a captured
+# solve, batched split kernel, sharded split over a 2x2
+# mesh, device election, KSP) must own a captured
 # cost_analysis/memory_analysis row, a live node must serve them
 # through ctrl get_device_telemetry with HBM gauges explicitly
 # degraded, and re-running everything post-warmup must add ZERO XLA

@@ -116,18 +116,6 @@ class DecisionConfig:
     debounce_max_ms: int = C.DECISION_DEBOUNCE_MAX_MS
     # TPU solver knobs (rebuild-specific)
     use_tpu_solver: bool = True  # False → CPU oracle path (tests/tiny nodes)
-    use_dense_kernel: bool | None = None  # None = auto
-    # VMEM-resident Pallas relax kernel — interpreter-mode (CPU) design
-    # reference ONLY. On real TPU backends the solver REFUSES this knob
-    # at construction: the kernel's row gather lowers to
-    # tpu.dynamic_gather, which v5e Mosaic only supports inside one
-    # 8x128 vreg (measured, docs/spf_kernel_profile.md §2) — any
-    # production-size shape fails in the backend compiler. Production
-    # TPU solves use the XLA v3 split kernel (spf_kernel="split").
-    use_pallas_kernel: bool = False
-    # batched kernel implementation: "split" (v3 split-width tables +
-    # compacted tail — the default) or "dense" (the r2 kernel)
-    spf_kernel: str = "split"
     # native C++ radix-heap solver (native/spf) for the single-root RIB
     # path: "auto" (use when built and LFA off), "on", "off"
     native_rib: str = "auto"
@@ -414,8 +402,6 @@ class Config:
                 "decision: ksp_paths must be in 1..16 (the vectorized "
                 "k-disjoint-paths kernel bound — ops/ksp.py)"
             )
-        if d.spf_kernel not in ("split", "dense"):
-            raise ConfigError("decision: spf_kernel must be split|dense")
         if d.native_rib not in ("auto", "on", "off"):
             raise ConfigError(
                 "decision: native_rib must be auto|on|off"

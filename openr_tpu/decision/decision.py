@@ -386,11 +386,8 @@ class Decision(OpenrModule):
                     n_sources=dcfg.mesh_sources, n_graph=dcfg.mesh_graph
                 )
             self._tpu = TpuSpfSolver(
-                use_dense=dcfg.use_dense_kernel,
-                use_pallas=dcfg.use_pallas_kernel,
                 enable_lfa=dcfg.enable_lfa,
                 ksp_k=dcfg.ksp_paths,
-                kernel_impl=dcfg.spf_kernel,
                 native_rib=dcfg.native_rib,
                 mesh=mesh,
                 counters=counters,

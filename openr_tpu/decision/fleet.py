@@ -34,8 +34,8 @@ def compute_fleet_ribs(
     """RouteDatabases for every node in `nodes` (default: all nodes in
     the topology) from batched all-roots solves, chunked at `chunk`
     roots so the [Vp, D, B] relax intermediate stays bounded at fleet
-    scale (same pattern as ops.spf.all_sources_sssp, with the previous
-    chunk's device→host copy overlapping the next chunk's solve)."""
+    scale, with the previous chunk's device→host copy overlapping the
+    next chunk's solve."""
     from openr_tpu.decision.spf_backend import TpuSpfSolver
 
     if solver is None:

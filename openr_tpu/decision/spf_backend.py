@@ -1,7 +1,8 @@
 """TPU SPF backend: the production route-computation path.
 
 reference: openr/decision/SpfSolver.cpp † — but the solve is the batched
-JAX kernel in `openr_tpu.ops.spf` instead of per-root scalar Dijkstra.
+JAX kernel in `openr_tpu.ops.spf_split` instead of per-root scalar
+Dijkstra.
 
 The SPF batch for one node's RIB is {self} ∪ neighbors(self): the root row
 gives distances, and the neighbor rows give the ECMP first-hop matrix (and,
@@ -18,7 +19,6 @@ path and the test suite asserts RouteDatabase equality between the two.
 from __future__ import annotations
 
 import logging
-import os
 
 import jax
 import jax.numpy as jnp
@@ -42,10 +42,6 @@ from openr_tpu.types.topology import ForwardingAlgorithm
 from openr_tpu.ops.spf import (
     INF_DIST,
     METRIC_MAX,
-    batched_sssp,
-    batched_sssp_dense,
-    build_blocked,
-    first_hop_matrix,
     pad_batch,
 )
 from openr_tpu.ops.spf_split import (
@@ -105,10 +101,10 @@ def _scatter_set(arr, index, values):
 
 #: TpuSpfSolver.last_phase_ms as a view of a compute_routes call's span
 #: record: six phases that follow one another and cover the call (only
-#: one of the three solve spans runs in a call)
+#: one of the two solve spans runs in a call)
 _PHASE_SPANS = {
     "prepare": ("spf:prepare",),
-    "solve": ("spf:batched_solve", "spf:batched_dist", "spf:native_solve"),
+    "solve": ("spf:batched_solve", "spf:native_solve"),
     "unpack": ("spf:unpack",),
     "election": ("spf:rib_election",),
     "assembly": ("spf:rib_unicast",),
@@ -218,47 +214,27 @@ class _LazyDist:
 class TpuSpfSolver:
     """Computes a node's RouteDatabase on the TPU from the padded CSR LSDB.
 
-    `use_dense=None` (default) picks the dense in-neighbor-table kernel
-    unless its padding waste exceeds `dense_waste_limit` × the edge count
-    (pathological hub topologies), where it falls back to the edge-list
-    segment-min kernel. Both produce identical distances (tested).
+    One device kernel family solves: the split-width tables
+    (`build_split_tables`, whose base width bounds a hub's padding
+    waste by construction) and the kernels of `ops/spf_split.py` over
+    them — fused with the first-hop / LFA outputs for one root's RIB,
+    plain for a batch of roots, sharded over `mesh` when one is given.
+    Beside it, `native_rib` puts the C++ host engine on the single-root
+    RIB path. Both give identical routes (tested).
     """
 
     def __init__(
         self,
-        use_dense: bool | None = None,
-        dense_waste_limit: int = 8,
-        use_pallas: bool = False,
         enable_lfa: bool = False,
         ksp_k: int = 2,
-        kernel_impl: str = "split",
         native_rib: str = "auto",
         mesh=None,
         counters=None,
     ):
-        self.use_dense = use_dense
-        self.dense_waste_limit = dense_waste_limit
         # optional per-node Counters registry: annotated solver phases
         # then record wall durations into `profile.<span>_ms` stats
         # (monitor/profiling.py) alongside the xprof timeline rows
         self.counters = counters
-        if use_pallas:
-            # fail at construction, not mid-solve: the Pallas kernel is
-            # interpreter-only on current hardware (ops/spf_pallas.py
-            # guard; measured Mosaic dynamic_gather vreg limit) and
-            # this knob is operator-reachable via
-            # DecisionConfig.use_pallas_kernel
-            if (
-                jax.default_backend() != "cpu"
-                and os.environ.get("OPENR_PALLAS_UNSAFE") != "1"
-            ):
-                raise ValueError(
-                    "use_pallas_kernel=True is not supported on TPU "
-                    "backends: v5e Mosaic limits tpu.dynamic_gather to "
-                    "one 8x128 vreg (docs/spf_kernel_profile.md §2). "
-                    "Leave it False (XLA split kernel) on hardware."
-                )
-        self.use_pallas = use_pallas
         # which device the batched kernels run on, logged once so an
         # operator (and chip_smoke.py) can tell a chip from the CPU
         # backend jax falls back to when it finds no accelerator
@@ -288,8 +264,6 @@ class TpuSpfSolver:
         # csr.details. Small FIFO bound at 4× the device-cache cap
         # (entries are tiny; a steady-state node touches one key).
         self._nbr_cache: dict[tuple[int, int], list[int]] = {}
-        # "split" (v3 split-width kernel, default) or "dense" (r2 kernel)
-        self.kernel_impl = kernel_impl
         # "auto" | "on" | "off": the native C++ radix-heap solver for the
         # single-root RIB path (ops/native_spf.py). auto = use when the
         # shared library is built and LFA is off (LFA needs the batched
@@ -406,12 +380,14 @@ class TpuSpfSolver:
     def _device_arrays(self, csr, want: str):
         """Cached (and incrementally patched) device copies of the LSDB.
 
-        `want` selects a table set: "split" (v3 kernel), "dense" (r2
-        kernel / KSP), or "edge" (edge-list fallback). One cache entry
-        per topology base holds every set built so far; metric-only
-        churn patches are scattered into ALL resident sets, so e.g. the
-        KSP dense tables stay warm under churn instead of re-uploading
-        O(E) arrays per rebuild (round-2 verdict item 4).
+        `want` selects a table set: "split", the tables every SPF
+        solve runs on, or "dense", KSP's tables only (`_ksp_batch`: the
+        full-width in-neighbor layout `ops/ksp.py` masks edges in; no
+        SPF solve reads it). One cache entry per topology base holds
+        the sets asked for so far; metric-only churn patches are
+        scattered into each of them, so the KSP tables stay warm under
+        churn instead of re-uploading O(E) arrays per rebuild (round-2
+        verdict item 4).
         """
         cache = self._dev.get(csr.base_version)
         if cache is not None and csr.version >= cache["version"]:
@@ -461,29 +437,20 @@ class TpuSpfSolver:
                 "base_w": t["base_nbr"].shape[1],
                 "ov_pos": t["ov_pos"],
             }
-        elif want == "dense":
+        else:
             nbr, wgt = csr.dense_tables()
             dset = {
                 "nbr": jnp.asarray(nbr),
                 "wgt": jnp.asarray(wgt),
                 "over": jnp.asarray(csr.node_overloaded),
             }
-        else:
-            blocked = build_blocked(
-                csr.edge_metric, csr.edge_src, csr.node_overloaded
-            )
-            dset = {
-                "src": jnp.asarray(csr.edge_src),
-                "dst": jnp.asarray(csr.edge_dst),
-                "metric": jnp.asarray(csr.edge_metric),
-                "blocked": jnp.asarray(blocked),
-            }
         cache["sets"][want] = dset
         return dset
 
     def _apply_patch_suffix(self, cache, csr) -> None:
-        """Scatter the unapplied journal suffix into every resident set,
-        one compiled scatter (`_set`) per patched array.
+        """Scatter the unapplied journal suffix into each set the cache
+        holds (the split tables, and KSP's once a KSP prefix has asked
+        for them), one compiled scatter (`_set`) per patched array.
 
         A suffix can name one cell twice with different values (a flap
         fully reverted inside one debounce window solves nothing, so its
@@ -495,8 +462,8 @@ class TpuSpfSolver:
         if len(csr.patches) > done:
             self.dev_cache_stats["patches"] += 1
             with profiling.annotate("spf:patch_scatter"):
-                # edge_idx names the cell in all three layouts: its
-                # dense slot is (dst, edge_idx - row_start[dst])
+                # edge_idx names the cell in both layouts: its dense
+                # slot is (dst, edge_idx - row_start[dst])
                 last = {p.edge_idx: p for p in csr.patches[done:]}
                 # pad the patch arrays to a bucket (repeating the last patch
                 # — duplicate .set of the same value is a no-op): without
@@ -509,18 +476,13 @@ class TpuSpfSolver:
                 )
                 rows = np.array([p.dense_row for p in patches], np.int32)
                 cols = np.array([p.dense_col for p in patches], np.int32)
-                idxs = np.array([p.edge_idx for p in patches], np.int32)
                 vals = np.array([p.metric for p in patches], np.int32)
                 for name, dset in cache["sets"].items():
                     if name == "dense":
                         dset["wgt"] = self._set(
                             dset["wgt"], (rows, cols), vals
                         )
-                    elif name == "edge":
-                        dset["metric"] = self._set(
-                            dset["metric"], (idxs,), vals
-                        )
-                    elif name == "split":
+                    else:
                         h = cache["host"]["split"]
                         w, ov_pos = h["base_w"], h["ov_pos"]
                         if dset.get("uniform_metric") and bool(
@@ -575,48 +537,21 @@ class TpuSpfSolver:
         # device-resident advertiser matrices: re-uploaded on demand
         self._elect_dev.clear()
 
-    def _pick_table(self, csr) -> str:
-        """Which table set the batched solve uses for this topology.
-
-        Explicit knobs outrank the kernel_impl default: use_dense=False
-        forces the edge-list kernel, use_dense=True (or use_pallas,
-        which consumes the full dense tables) forces the r2 dense
-        kernel; only use_dense=None follows kernel_impl.
-        """
-        if self.use_dense is False:
-            return "edge"
-        if self.use_pallas or self.use_dense is True:
-            return "dense"
-        if self.kernel_impl == "split":
-            # the split builder bounds hub waste by construction
-            # (pick_base_width), so no edge-list escape hatch is needed
-            return "split"
-        # kernel_impl == "dense", auto sizing: check BEFORE materializing
-        # the tables (a single mega-hub node would make D ~ V and the
-        # tables ~ V^2)
-        table_slots = csr.padded_nodes * csr.dense_width()
-        if table_slots > self.dense_waste_limit * max(csr.num_edges, 1):
-            return "edge"
-        return "dense"
-
     def solve_vp(self, csr) -> int:
         """Node-dimension size of the distance matrix `solve` returns
-        (the split kernel uses tight padding, the others the CSR's)."""
-        if self._pick_table(csr) == "split":
-            return tight_nodes(csr.num_nodes)
-        return csr.padded_nodes
+        (the split tables' tight padding, not the CSR's)."""
+        return tight_nodes(csr.num_nodes)
 
-    def _dispatch(self, csr) -> tuple[str, dict, bool]:
+    def _dispatch(self, csr) -> tuple[dict, bool]:
         """Shared dispatch state for every batched-solve entry point:
-        (table kind, device array set, has_overloads). Every device
+        (split table set on the device, has_overloads). Every device
         solve — cold, warm, fleet — passes here exactly once, so this
         is where the device engine is counted."""
         self.spf_kernel_stats["engine_device"] += 1
         with profiling.annotate("spf:dispatch"):
-            table = self._pick_table(csr)
-            dev = self._device_arrays(csr, table)
+            dev = self._device_arrays(csr, "split")
             has_over = bool(csr.node_overloaded.any())
-        return table, dev, has_over
+        return dev, has_over
 
     def _count_kernel_loops(self, buf: np.ndarray, prefix: str = "") -> None:
         """Add the packed buffer's trailer to spf_kernel_stats (`prefix`
@@ -629,129 +564,66 @@ class TpuSpfSolver:
         st[prefix + "net_sweeps"] += got["net_sweeps"]
         st[prefix + "tail_spills"] += got["spilled"]
 
-    def _solve_dist(
-        self, csr, roots: np.ndarray, _dispatched: tuple | None = None
-    ) -> np.ndarray:
-        table, dev, has_over = _dispatched or self._dispatch(csr)
-        if table != "split" and self.mesh is not None:
+    def _solve_dist(self, csr, roots: np.ndarray) -> jax.Array:
+        """[vp, B] distances from `roots` (a device array; the call
+        returns at the dispatch): the sharded split kernel where a mesh
+        is configured and divides the shape, else the single-device
+        one."""
+        dev, has_over = self._dispatch(csr)
+        if self.mesh is not None:
+            if self._mesh_fits(dev, roots):
+                from openr_tpu.parallel import sharded_sssp_split
+
+                # per-shard span: dispatch wall only (the caller's
+                # materialization pays completion); the output's
+                # per-device shard layout is kept for ctrl/breeze
+                with profiling.annotate(
+                    "spf:sharded_solve", counters=self.counters
+                ):
+                    out = sharded_sssp_split(
+                        dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
+                        dev["ov_nbr"], dev["ov_wgt"], dev["over"],
+                        jnp.asarray(roots), self.mesh,
+                        has_overloads=has_over,
+                    )
+                device_telemetry.observe(
+                    "sharded_sssp_split",
+                    lambda: sharded_sssp_split.lower(
+                        dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
+                        dev["ov_nbr"], dev["ov_wgt"], dev["over"],
+                        jnp.asarray(roots), self.mesh,
+                        has_overloads=has_over,
+                    ),
+                    span="spf:sharded_solve",
+                    # dispatch-only span (async return)
+                    span_complete=False,
+                )
+                self.last_shard_rows = device_telemetry.shard_rows(out)
+                return out
             if not self._mesh_fallback_warned:
-                # r3 advisor finding: a configured mesh meeting the
-                # dense/edge table path fell back to single-device with
-                # no signal at all
                 self._mesh_fallback_warned = True
                 log.warning(
-                    "configured mesh is only used by the split kernel; "
-                    "%r-table solve runs single-device (leave "
-                    "use_dense unset/None with spf_kernel='split' to "
-                    "shard — use_dense=False forces the unsharded "
-                    "edge kernel)",
-                    table,
+                    "configured mesh %s does not divide solve shape "
+                    "(vp=%d, b=%d) — falling back to single-device "
+                    "(use power-of-two axis sizes)",
+                    dict(self.mesh.shape), dev["vp"], len(roots),
                 )
-        if table == "split":
-            if self.mesh is not None:
-                if self._mesh_fits(dev, roots):
-                    from openr_tpu.parallel import sharded_sssp_split
-
-                    # per-shard span: dispatch wall only (the caller's
-                    # materialization pays completion — same contract as
-                    # the other _solve_dist paths); the output's
-                    # per-device shard layout is kept for ctrl/breeze
-                    with profiling.annotate(
-                        "spf:sharded_solve", counters=self.counters
-                    ):
-                        out = sharded_sssp_split(
-                            dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                            dev["ov_nbr"], dev["ov_wgt"], dev["over"],
-                            jnp.asarray(roots), self.mesh,
-                            has_overloads=has_over,
-                        )
-                    device_telemetry.observe(
-                        "sharded_sssp_split",
-                        lambda: sharded_sssp_split.lower(
-                            dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                            dev["ov_nbr"], dev["ov_wgt"], dev["over"],
-                            jnp.asarray(roots), self.mesh,
-                            has_overloads=has_over,
-                        ),
-                        span="spf:sharded_solve",
-                        # dispatch-only span (async return)
-                        span_complete=False,
-                    )
-                    self.last_shard_rows = device_telemetry.shard_rows(out)
-                    return out
-                if not self._mesh_fallback_warned:
-                    self._mesh_fallback_warned = True
-                    log.warning(
-                        "configured mesh %s does not divide solve shape "
-                        "(vp=%d, b=%d) — falling back to single-device "
-                        "(use power-of-two axis sizes)",
-                        dict(self.mesh.shape), dev["vp"], len(roots),
-                    )
-            gs = self._pick_gs_and_count(dev)
-            out = batched_sssp_split(
-                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"], dev["over"],
-                jnp.asarray(roots), has_overloads=has_over, gs_chunks=gs,
-            )
-            device_telemetry.observe(
-                "batched_sssp_split",
-                lambda: batched_sssp_split.lower(
-                    dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                    dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                    dev["over"], jnp.asarray(roots),
-                    has_overloads=has_over, gs_chunks=gs,
-                ),
-                span="spf:batched_dist",
-                span_complete=False,  # dispatch-only span (async return)
-            )
-            return out
-        if table == "dense":
-            if self.use_pallas:
-                from openr_tpu.ops.spf_pallas import (
-                    batched_sssp_pallas,
-                    fits_vmem,
-                )
-
-                if fits_vmem(
-                    csr.padded_nodes, len(roots), csr.dense_width()
-                ):
-                    return batched_sssp_pallas(
-                        dev["nbr"], dev["wgt"], dev["over"],
-                        jnp.asarray(roots), has_overloads=has_over,
-                    )
-            out = batched_sssp_dense(
-                dev["nbr"],
-                dev["wgt"],
-                dev["over"],
-                jnp.asarray(roots),
-                has_overloads=has_over,
-            )
-            device_telemetry.observe(
-                "batched_sssp_dense",
-                lambda: batched_sssp_dense.lower(
-                    dev["nbr"], dev["wgt"], dev["over"],
-                    jnp.asarray(roots), has_overloads=has_over,
-                ),
-                span="spf:batched_dist",
-                span_complete=False,  # dispatch-only span (async return)
-            )
-            return out
-        out = batched_sssp(
-            dev["src"],
-            dev["dst"],
-            dev["metric"],
-            dev["blocked"],
-            jnp.asarray(roots),
-            csr.padded_nodes,
+        gs = self._pick_gs_and_count(dev)
+        out = batched_sssp_split(
+            dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
+            dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"], dev["over"],
+            jnp.asarray(roots), has_overloads=has_over, gs_chunks=gs,
         )
+        # no span: nothing times this dispatch (the caller's
+        # materialization pays completion)
         device_telemetry.observe(
-            "batched_sssp",
-            lambda: batched_sssp.lower(
-                dev["src"], dev["dst"], dev["metric"], dev["blocked"],
-                jnp.asarray(roots), csr.padded_nodes,
+            "batched_sssp_split",
+            lambda: batched_sssp_split.lower(
+                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
+                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
+                dev["over"], jnp.asarray(roots),
+                has_overloads=has_over, gs_chunks=gs,
             ),
-            span="spf:batched_dist",
-            span_complete=False,  # dispatch-only span (async return)
         )
         return out
 
@@ -834,9 +706,10 @@ class TpuSpfSolver:
         RIB; returns (csr, dist, fh, neighbor_ids, lfa) — lfa is the
         [N, Vp] loop-free-alternate matrix or None when enable_lfa is
         off — or None if my_node is not in the topology. fh/lfa are
-        host numpy; dist is host numpy on the native/dense/edge paths
-        and a `_LazyDist` on the split path (root column pre-fetched,
-        full [Vp, B] matrix transferred only if indexed/np.asarray'd).
+        host numpy; dist is host numpy ([Vp, 1], the root's column) from
+        the native engine and a `_LazyDist` from the device (root column
+        pre-fetched, full [Vp, B] matrix transferred only if
+        indexed/np.asarray'd).
 
         Two interchangeable engines (identical results, tested):
           * native C++ radix-heap Dijkstra + first-hop DAG propagation —
@@ -874,7 +747,7 @@ class TpuSpfSolver:
                         csr, my_id, nbr_ids, nbr_metric_real, b
                     )
                 )
-                table, dev, has_over = self._dispatch(csr)
+                dev, has_over = self._dispatch(csr)
 
         if native:
             with profiling.annotate("spf:native_solve"):
@@ -889,92 +762,47 @@ class TpuSpfSolver:
                 fh[:n] = fh_n
             return csr, dist, fh, nbr_ids, None
 
-        if table == "split":
-            # fused single-dispatch path with packed outputs: ~0.8 MB
-            # instead of ~16 MB of device→host traffic per rebuild at
-            # the 100k shape (see ops.spf_split.batched_sssp_split_rib)
-            vp = dev["vp"]
-            gs = self._pick_gs_and_count(dev)
-            with profiling.annotate("spf:batched_solve", counters=self.counters):
-                dist_dev, packed = batched_sssp_split_rib(
-                    dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                    dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                    dev["over"], jnp.asarray(roots),
-                    jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
-                    jnp.asarray(nbr_over), jnp.int32(my_id),
-                    has_overloads=has_over,
-                    with_lfa=self.enable_lfa,
-                    gs_chunks=gs,
-                )
-                buf = np.asarray(packed)
-                compile_ledger.record_transfer(buf.nbytes)
-            # kernel cost ledger (docs/Monitor.md "Device telemetry"):
-            # only re-lowers when the compile ledger saw a fresh compile
-            # of this fn — a pure dict probe in steady state
-            device_telemetry.observe(
-                "batched_sssp_split_rib",
-                lambda: batched_sssp_split_rib.lower(
-                    dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                    dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                    dev["over"], jnp.asarray(roots),
-                    jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
-                    jnp.asarray(nbr_over), jnp.int32(my_id),
-                    has_overloads=has_over,
-                    with_lfa=self.enable_lfa,
-                    gs_chunks=gs,
-                ),
-                span="spf:batched_solve",
+        # fused single-dispatch path with packed outputs: ~0.8 MB
+        # instead of ~16 MB of device→host traffic per rebuild at
+        # the 100k shape (see ops.spf_split.batched_sssp_split_rib)
+        vp = dev["vp"]
+        gs = self._pick_gs_and_count(dev)
+        with profiling.annotate("spf:batched_solve", counters=self.counters):
+            dist_dev, packed = batched_sssp_split_rib(
+                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
+                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
+                dev["over"], jnp.asarray(roots),
+                jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
+                jnp.asarray(nbr_over), jnp.int32(my_id),
+                has_overloads=has_over,
+                with_lfa=self.enable_lfa,
+                gs_chunks=gs,
             )
-            with profiling.annotate("spf:unpack"):
-                d_root, fh, lfa = unpack_rib_buffer(
-                    buf, vp, b, self.enable_lfa
-                )
-                self._count_kernel_loops(buf)
-            return csr, _LazyDist(dist_dev, d_root), fh, nbr_ids, lfa
-
-        # distinct span from the fused split-RIB path's
-        # spf:batched_solve: this one ends at the ASYNC dispatch return
-        # (fh materializes below, outside it) — pooling its sub-ms
-        # samples into the completion-walled stat would drag that p50
-        # below any real solve and corrupt the efficiency join
-        # (review finding)
-        with profiling.annotate("spf:batched_dist", counters=self.counters):
-            dist = self._solve_dist(
-                csr, roots, _dispatched=(table, dev, has_over)
-            )
-        with profiling.annotate("spf:unpack"):
-            fh = np.asarray(
-                first_hop_matrix(
-                    dist,
-                    jnp.asarray(nbr_metric),
-                    jnp.asarray(nbr_ids_p),
-                    jnp.asarray(nbr_over),
-                )
-            )
+            buf = np.asarray(packed)
+            compile_ledger.record_transfer(buf.nbytes)
+        # kernel cost ledger (docs/Monitor.md "Device telemetry"):
+        # only re-lowers when the compile ledger saw a fresh compile
+        # of this fn — a pure dict probe in steady state
         device_telemetry.observe(
-            "first_hop_matrix",
-            lambda: first_hop_matrix.lower(
-                dist,
-                jnp.asarray(nbr_metric),
-                jnp.asarray(nbr_ids_p),
-                jnp.asarray(nbr_over),
+            "batched_sssp_split_rib",
+            lambda: batched_sssp_split_rib.lower(
+                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
+                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
+                dev["over"], jnp.asarray(roots),
+                jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
+                jnp.asarray(nbr_over), jnp.int32(my_id),
+                has_overloads=has_over,
+                with_lfa=self.enable_lfa,
+                gs_chunks=gs,
             ),
-            span="spf:batched_dist",
-            span_complete=False,  # dispatch-only span (async return)
+            span="spf:batched_solve",
         )
-        lfa = None
-        if self.enable_lfa:
-            from openr_tpu.ops.spf import lfa_matrix
-
-            lfa = np.asarray(
-                lfa_matrix(
-                    dist,
-                    jnp.int32(my_id),
-                    jnp.asarray(nbr_ids_p),
-                    jnp.asarray(nbr_over),
-                )
+        with profiling.annotate("spf:unpack"):
+            d_root, fh, lfa = unpack_rib_buffer(
+                buf, vp, b, self.enable_lfa
             )
-        return csr, np.asarray(dist), fh, nbr_ids, lfa
+            self._count_kernel_loops(buf)
+        return csr, _LazyDist(dist_dev, d_root), fh, nbr_ids, lfa
 
     @staticmethod
     def _nbr_metrics(csr, my_id: int, nbr_ids: list[int]) -> np.ndarray:
@@ -1216,7 +1044,6 @@ class TpuSpfSolver:
         if (
             lfa is not None
             or not isinstance(dist, _LazyDist)
-            or self._pick_table(csr) != "split"
             or cache is None
             or "split" not in cache["sets"]
         ):
@@ -1295,7 +1122,7 @@ class TpuSpfSolver:
 
         Returns (rdb, new_artifact, touched_prefixes, touched_labels,
         region_nodes) or None to demand a full solve. Fallback
-        conditions (None): LFA enabled, non-split table path, native
+        conditions (None): LFA enabled, native
         single-root artifact (no neighbor distance columns to warm),
         structural CSR base change, root-incident change (my own
         nexthop slot metrics moved), delta or cone exceeding
@@ -1305,13 +1132,11 @@ class TpuSpfSolver:
             return None
         old_csr, old_dist, old_fh, nbr_ids, lfa = art.solved
         if lfa is not None or not isinstance(old_dist, _LazyDist):
-            return None  # native/dense-path artifact: no warm columns
+            return None  # native artifact: no warm columns
         with profiling.annotate("spf:to_csr"):
             csr = ls.to_csr()
         if csr.base_version != old_csr.base_version:
             return None  # structural change: interning/base moved
-        if self._pick_table(csr) != "split":
-            return None
         my_id = csr.name_to_id.get(my_node)
         if my_id is None:
             return None
@@ -1370,7 +1195,7 @@ class TpuSpfSolver:
                 return None
             rows_all, cols_all, seed, cone_union = cone
             self.spf_kernel_stats["warm_cone_cells"] += len(rows_all)
-            _table, dev, has_over = self._dispatch(csr)
+            dev, has_over = self._dispatch(csr)
             vp = dev["vp"]
             roots, nbr_ids_p, nbr_metric, nbr_over = self._rib_pad_arrays(
                 csr, my_id, nbr_ids,

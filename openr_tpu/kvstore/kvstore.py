@@ -81,12 +81,6 @@ class _Peer:
         # Reset on peer flap (the _Peer is rebuilt), so an upgraded
         # neighbor is re-probed with the delta form.
         self.legacy_sync = False
-        # set after the first successful transport connect: a later
-        # successful connect on the SAME _Peer is a reconnect (the far
-        # process died and came back, or the TCP session was torn down
-        # mid-flood) — counted as kvstore.peer_reconnects so kill/
-        # restart chaos is observable separately from first contact
-        self.ever_connected = False
         # pending flood state (coalesced by key: versions only grow, so
         # replacing an unsent value with a newer one is always correct)
         self.pending_keys: dict[str, Value] = {}
@@ -124,6 +118,18 @@ class KvStore(OpenrModule):
             a: KvStoreDb(a, counters=counters) for a in config.area_ids()
         }
         self.peers: dict[tuple[str, str], _Peer] = {}  # (area, node) -> peer
+        # (area, node) of every neighbor a transport connect has reached:
+        # a later successful connect to the same neighbor is a reconnect
+        # (the far process died and came back, or the TCP session was
+        # torn down mid-flood) — counted as kvstore.peer_reconnects so
+        # kill/restart chaos is observable separately from first
+        # contact. Kept here and not on the _Peer: Spark reports a
+        # SIGKILLed neighbor's return either as NEIGHBOR_RESTARTED (its
+        # unsolicited handshake came first: the _Peer stays) or as
+        # NEIGHBOR_DOWN + NEIGHBOR_UP (its first hello, which does not
+        # hear us yet, came first: the _Peer is rebuilt), by which
+        # packet wins, and the count must not depend on that.
+        self._connected_once: set[tuple[str, str]] = set()
         self.initial_sync_done = asyncio.Event()
         # flood tracing (docs/Monitor.md): deterministic head-sampling
         # of local originations. The phase offset is a stable hash of
@@ -304,7 +310,7 @@ class KvStore(OpenrModule):
                         peer.spec.node_name, peer.spec.endpoint,
                         counters=self.counters,
                     )
-                    if peer.ever_connected:
+                    if key in self._connected_once:
                         if self.counters is not None:
                             self.counters.increment(
                                 "kvstore.peer_reconnects"
@@ -314,7 +320,7 @@ class KvStore(OpenrModule):
                                 peer=peer.spec.node_name,
                                 area=area,
                             )
-                    peer.ever_connected = True
+                    self._connected_once.add(key)
                 own_hash = db.store_hash()
                 # delta sync (docs/Wire.md): after the first successful
                 # sync, open with a digestless store-hash probe — a

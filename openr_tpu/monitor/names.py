@@ -261,7 +261,6 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:dispatch",              #       device tables: hit, patch, build
     "spf:patch_scatter",         #         journal suffix → compiled scatters
     "spf:batched_solve",         #       cold fused kernel + packed fetch
-    "spf:batched_dist",          #       unfused kernels, dispatch only
     "spf:sharded_solve",         #       mesh kernel, dispatch only
     "spf:native_solve",          #       C++ host engine
     "spf:unpack",                #       packed buffer → d_root, fh, lfa

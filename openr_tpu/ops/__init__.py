@@ -1,8 +1,9 @@
-"""Device compute kernels (JAX/XLA/Pallas) — the TPU Decision hot path.
+"""Device compute kernels (JAX/XLA) — the TPU Decision hot path.
 
 The reference's equivalent is the scalar C++ SPF core
 (reference: openr/decision/LinkState.cpp † runSpf + SpfSolver †). Here it is
-a batched, masked, fixed-shape JAX program; see `spf.py`.
+a batched, masked, fixed-shape JAX program; see `spf.py` and
+`spf_split.py`.
 
 Every jitted entry point lives under this package, so its import is the
 one place that every JAX user of the repo passes (product, bench.py,
@@ -28,7 +29,6 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 
 from openr_tpu.ops.spf import (  # noqa: E402,F401
     INF_DIST,
-    batched_sssp,
     batched_sssp_dense,
     build_dense_tables,
     first_hop_matrix,

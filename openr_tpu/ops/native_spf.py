@@ -3,7 +3,7 @@
 reference: openr/decision/LinkState.cpp † runSpf. The native solver is a
 radix-heap Dijkstra with ECMP first-hop bitmask propagation — the
 latency-optimal shape for a SINGLE root on the host, complementing the
-batched TPU fixpoint kernel (ops/spf.py) which owns multi-root /
+batched TPU fixpoint kernel (ops/spf_split.py) which owns multi-root /
 all-sources shapes. `Decision` picks a backend per solve (config knob
 `decision.spf_backend`), and the bench uses this as the in-run oracle.
 

@@ -1,34 +1,42 @@
-"""Batched SSSP on TPU: the SpfSolver compute core.
+"""Shared pieces of the SPF compute core: the numeric contract, the
+first-hop / LFA identities, KSP's table layout and the reference solve.
 
 reference: openr/decision/LinkState.cpp † runSpf — a per-root scalar
 Dijkstra with a std::priority_queue. A priority queue is the wrong shape for
 a TPU: data-dependent control flow, scalar pops, pointer chasing. The
-TPU-native formulation is **batched edge-relaxation to fixpoint**
-(Bellman-Ford over the padded CSR edge list):
+TPU-native formulation is **batched relaxation to fixpoint** (Bellman-Ford
+over in-neighbor tables):
 
     dist[v, b] = min(dist[v, b], min over edges (u→v): dist[u, b] + w(u,v))
 
-iterated under `lax.while_loop` until no distance changes (≤ hop-diameter
-iterations — 4 for a fat-tree, O(log V) for random graphs). Every step is a
-gather + elementwise add + segmented min over the dst-sorted edge list:
-static shapes, no host sync, fuses into a handful of XLA ops, and the batch
-dimension B (SPF roots) vectorizes for free. ECMP/LFA/nexthops then fall out
-of pure elementwise comparisons on the resulting distance matrix
-(`first_hop_matrix`) instead of predecessor bookkeeping inside the loop.
+iterated until no distance changes, as a row gather + elementwise add +
+axis-min: static shapes, no host sync, no scatter, and the batch dimension
+B (SPF roots) vectorizes for free. ECMP/LFA/nexthops then fall out of pure
+elementwise comparisons on the resulting distance matrix
+(`first_hop_matrix`, `lfa_matrix`) instead of predecessor bookkeeping
+inside the loop.
+
+The kernels the program solves with are in `ops/spf_split.py` (split-width
+tables; its fused RIB program calls `first_hop_matrix` / `lfa_matrix`).
+What lives here beside the identities: `build_dense_tables`, the
+full-width in-neighbor layout `ops/ksp.py` masks edges in, and
+`batched_sssp_dense`, the plain recurrence over that layout which tests
+hold the split kernel and the C++ engine to.
 
 Layout notes (TPU):
-  * node-major [Vp, B] / edge-major [Ep, B]: B is the minor (lane) dim;
-    pad B to a multiple of 8 — callers use `pad_batch`.
+  * node-major [Vp, B]: B is the minor (lane) dim; pad B to a multiple
+    of 8 — callers use `pad_batch`.
   * distances are **int32** (exact integer metrics, like the reference's
     int metrics). INF_DIST = 2^30; valid metrics ≤ METRIC_MAX = 2^30-1
     (clamped by the CSR builder — covers the reference's practical metric
     range), and the relax computes min(dist + metric, INF) guarded by
     dist < INF, so the sum never exceeds INT32_MAX — no overflow. Path
     costs saturate at INF (≥ INF ⇒ unreachable); the oracle saturates
-    identically. Padding slots carry edge_metric == INF_DIST exactly.
-  * overload (no-transit) is a per-edge boolean `blocked`; the SPF root's
-    own out-edges are exempted at init (reference: SpfSolver † lets an
-    overloaded node source/sink traffic, never transit it).
+    identically. Padding slots carry metric == INF_DIST exactly.
+  * overload (no-transit) is a per-element mask on the source node of an
+    edge, lifted in the batch column whose root IS that node (reference:
+    SpfSolver † lets an overloaded node source/sink traffic, never
+    transit it).
 """
 
 from __future__ import annotations
@@ -47,65 +55,6 @@ from openr_tpu.common.util import pad_bucket as pad_batch  # roots bucket
 INF_DIST = np.int32(_C.DIST_INF)
 METRIC_MAX = np.int32(_C.METRIC_MAX)
 DIST_DTYPE = jnp.int32
-
-
-@functools.partial(jax.jit, static_argnames=("num_nodes",))
-def batched_sssp(
-    edge_src: jax.Array,  # [Ep] i32
-    edge_dst: jax.Array,  # [Ep] i32, ascending (padding → dead slot)
-    edge_metric: jax.Array,  # [Ep] i32; valid ≤ METRIC_MAX, padding == INF_DIST
-    edge_blocked: jax.Array,  # [Ep] bool: padding ∪ overloaded-src edges
-    roots: jax.Array,  # [B] i32 node id per batch column (may repeat)
-    num_nodes: int,  # static: padded node count Vp
-) -> jax.Array:
-    """Distances from each root: dist [Vp, B] int32 (INF_DIST = unreachable).
-
-    `edge_blocked` must already contain the overloaded-transit edges
-    (see `build_blocked`); the root exemption — an overloaded root may still
-    relax its own out-edges — happens here at init.
-    """
-    metric = edge_metric.astype(DIST_DTYPE)
-
-    # Init: penalty-free relax of each root's own out-edges (padding slots
-    # have metric == INF_DIST so they contribute nothing), then dist=0 at
-    # the root itself. Blocked edges never relax after this point — which is
-    # exactly the "overloaded nodes don't transit" rule.
-    is_root_edge = edge_src[:, None] == roots[None, :]  # [Ep, B]
-    init_cand = jnp.where(is_root_edge, metric[:, None], INF_DIST)
-    dist = jax.ops.segment_min(
-        init_cand,
-        edge_dst,
-        num_segments=num_nodes,
-        indices_are_sorted=True,
-    )
-    dist = jnp.minimum(dist, INF_DIST)
-    dist = dist.at[roots, jnp.arange(roots.shape[0])].set(0)
-
-    usable = (~edge_blocked)[:, None]  # [Ep, 1]
-
-    def relax(state):
-        dist, _changed, it = state
-        d_src = dist[edge_src]  # [Ep, B] gather
-        cand = jnp.where(
-            usable & (d_src < INF_DIST),
-            jnp.minimum(d_src + metric[:, None], INF_DIST),
-            INF_DIST,
-        )
-        new = jax.ops.segment_min(
-            cand,
-            edge_dst,
-            num_segments=num_nodes,
-            indices_are_sorted=True,
-        )
-        new = jnp.minimum(new, dist)
-        return new, jnp.any(new < dist), it + 1
-
-    def cond(state):
-        _dist, changed, it = state
-        return changed & (it < num_nodes)
-
-    dist, _, _ = jax.lax.while_loop(cond, relax, (dist, jnp.bool_(True), 0))
-    return dist
 
 
 @jax.jit
@@ -222,12 +171,16 @@ def batched_sssp_dense(
     roots: jax.Array,  # [B] i32
     has_overloads: bool = True,
 ) -> jax.Array:
-    """Dense-table batched SSSP → dist [Vp, B] int32 (see build_dense_tables).
+    """The plain Bellman-Ford over the full-width tables → dist [Vp, B]
+    int32 (see build_dense_tables): what the split kernel's and the
+    native engine's tests compare against at sizes where the Python
+    oracle is too slow, and the recurrence `ops/ksp.py` repeats under
+    its edge bans. No caller in the program.
 
-    The overloaded-transit rule is a fused per-element mask here — an edge
+    The overloaded-transit rule is a fused per-element mask — an edge
     from an overloaded node relaxes only in the batch column whose root IS
-    that node — which also subsumes the root-exemption init of the edge-list
-    kernel (`has_overloads=False` drops the mask entirely: the common case).
+    that node (`has_overloads=False` drops the mask entirely: the common
+    case).
     """
     num_nodes = nbr.shape[0]
     b = roots.shape[0]
@@ -257,49 +210,3 @@ def batched_sssp_dense(
 
     dist, _, _ = jax.lax.while_loop(cond, relax, (dist, jnp.bool_(True), 0))
     return dist
-
-
-def build_blocked(
-    edge_metric: np.ndarray,
-    edge_src: np.ndarray,
-    node_overloaded: np.ndarray,
-) -> np.ndarray:
-    """Host-side: edges that can never carry transit traffic — padding /
-    invalid slots plus every edge leaving an overloaded node (the per-root
-    exemption happens inside the kernel init)."""
-    return (edge_metric >= int(INF_DIST)) | node_overloaded[edge_src]
-
-
-def all_sources_sssp(
-    edge_src: jax.Array,
-    edge_dst: jax.Array,
-    edge_metric: jax.Array,
-    edge_blocked: jax.Array,
-    num_nodes: int,
-    chunk: int = 256,
-) -> np.ndarray:
-    """Distances from every node (BASELINE config 3), chunked over sources to
-    bound the [Ep, B] relax intermediate in HBM. Returns [V, V] (row = src).
-
-    Pipelined: each chunk's solve is dispatched asynchronously and the
-    PREVIOUS chunk's device→host transfer happens while the current one
-    computes — the host loop never serializes launch → compute → copy
-    (the full [V, V] result can't live on device at 100k nodes, so a
-    single fused lax.map is not an option; double-buffering is).
-    """
-    rows = []
-    pending = None
-    for start in range(0, num_nodes, chunk):
-        b = min(chunk, num_nodes - start)
-        roots = jnp.arange(start, start + b, dtype=jnp.int32)
-        if b < chunk:  # keep jit shapes stable on the tail chunk
-            roots = jnp.pad(roots, (0, chunk - b))
-        d = batched_sssp(
-            edge_src, edge_dst, edge_metric, edge_blocked, roots, num_nodes
-        )
-        if pending is not None:
-            rows.append(np.asarray(pending[0][:, : pending[1]]).T)
-        pending = (d, b)
-    if pending is not None:
-        rows.append(np.asarray(pending[0][:, : pending[1]]).T)
-    return np.concatenate(rows, axis=0)

@@ -7,17 +7,14 @@ SPF compute across TPU cores —
 
   * ``sources`` axis — batch of SPF roots, embarrassingly parallel (the
     "data parallel" axis; scales all-sources SSSP and per-node fleets).
-  * ``graph`` axis — the edge list partitioned across devices, with an ICI
-    `pmin` all-reduce exchanging relaxed distances each iteration (the
-    "model parallel" axis; scales LSDBs beyond one chip's HBM).
+  * ``graph`` axis — the in-neighbor table rows partitioned across
+    devices, with a tiled ICI `all_gather` exchanging relaxed distances
+    each sweep (the "model parallel" axis; scales LSDBs beyond one chip's
+    HBM).
 
 Collectives ride ICI inside `shard_map`; over DCN, `jax.distributed`
 initialises the same mesh across hosts (see `mesh.py`).
 """
 
 from openr_tpu.parallel.mesh import make_mesh  # noqa: F401
-from openr_tpu.parallel.sharded_spf import (  # noqa: F401
-    sharded_sssp,
-    sharded_sssp_padded,
-    sharded_sssp_split,
-)
+from openr_tpu.parallel.sharded_spf import sharded_sssp_split  # noqa: F401

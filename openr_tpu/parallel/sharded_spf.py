@@ -1,18 +1,18 @@
 """Sharded batched SSSP: sources × graph partitioning under shard_map.
 
-The single-device kernel (`ops/spf.py`) already vectorizes over SPF roots;
-here the same relax-to-fixpoint runs SPMD:
+The single-device kernel (`ops/spf_split.py`) already vectorizes over SPF
+roots; here the same relax-to-fixpoint runs SPMD:
 
   * roots sharded over the ``sources`` mesh axis — each device solves its
     slice of roots independently (no communication);
-  * the edge list sharded over the ``graph`` mesh axis — each device relaxes
-    its edge partition and the partial per-node minima are combined with an
-    ICI ``lax.pmin`` all-reduce every iteration (the frontier exchange; the
-    moral equivalent of the reference's KvStore flood is host-side — this is
-    purely the compute-plane collective).
+  * the base in-neighbor table rows sharded over the ``graph`` mesh axis —
+    each device relaxes its row slice and the full distance matrix is
+    re-assembled with a tiled ICI ``all_gather`` every sweep (the frontier
+    exchange; the moral equivalent of the reference's KvStore flood is
+    host-side — this is purely the compute-plane collective).
 
-Distances stay replicated across the ``graph`` axis (Vp·B int32 — the edge
-arrays dominate HBM, which is exactly what the graph axis shards), so the
+Distances stay replicated across the ``graph`` axis (Vp·B int32 — the
+tables dominate HBM, which is exactly what the graph axis shards), so the
 fixpoint condition is computed identically on every shard: no extra
 convergence collective needed.
 """
@@ -27,80 +27,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from openr_tpu.ops.spf import INF_DIST
 from openr_tpu.parallel.mesh import GRAPH_AXIS, SOURCES_AXIS
-
-
-def _local_sssp(edge_src, edge_dst, edge_metric, edge_blocked, roots, num_nodes):
-    """Per-device body: local edge shard, local root slice, pmin across the
-    graph axis after every segmented relax."""
-    metric = edge_metric.astype(jnp.int32)
-
-    is_root_edge = edge_src[:, None] == roots[None, :]
-    init_cand = jnp.where(is_root_edge, metric[:, None], INF_DIST)
-    dist = jax.ops.segment_min(
-        init_cand, edge_dst, num_segments=num_nodes, indices_are_sorted=True
-    )
-    dist = jax.lax.pmin(jnp.minimum(dist, INF_DIST), GRAPH_AXIS)
-    dist = dist.at[roots, jnp.arange(roots.shape[0])].set(0)
-
-    usable = (~edge_blocked)[:, None]
-
-    def relax(state):
-        dist, _changed, it = state
-        d_src = dist[edge_src]
-        cand = jnp.where(
-            usable & (d_src < INF_DIST),
-            jnp.minimum(d_src + metric[:, None], INF_DIST),
-            INF_DIST,
-        )
-        new = jax.ops.segment_min(
-            cand, edge_dst, num_segments=num_nodes, indices_are_sorted=True
-        )
-        new = jax.lax.pmin(new, GRAPH_AXIS)  # frontier exchange over ICI
-        new = jnp.minimum(new, dist)
-        return new, jnp.any(new < dist), it + 1
-
-    def cond(state):
-        _dist, changed, it = state
-        return changed & (it < num_nodes)
-
-    # initial `changed` must carry the same varying-manual-axes type as
-    # the loop output (jnp.any over the sources-sharded dist): a literal
-    # True is unvarying and check_vma rightly rejects it. Each sources
-    # shard may run a different trip count — safe, because shards in the
-    # same graph-axis group share the same root slice, so the pmin
-    # collectives inside the loop stay aligned.
-    changed0 = jnp.any(dist <= INF_DIST)  # always True, correctly varying
-    dist, _, _ = jax.lax.while_loop(cond, relax, (dist, changed0, 0))
-    return dist
-
-
-@functools.partial(
-    jax.jit, static_argnames=("mesh", "num_nodes")
-)
-def sharded_sssp(
-    edge_src: jax.Array,  # [Ep] — Ep must divide by the graph axis size
-    edge_dst: jax.Array,
-    edge_metric: jax.Array,
-    edge_blocked: jax.Array,
-    roots: jax.Array,  # [B] — B must divide by the sources axis size
-    mesh: Mesh,
-    num_nodes: int,
-) -> jax.Array:
-    """Returns dist [Vp, B] (B sharded over `sources`, rows replicated)."""
-    fn = jax.shard_map(
-        functools.partial(_local_sssp, num_nodes=num_nodes),
-        mesh=mesh,
-        in_specs=(
-            P(GRAPH_AXIS),
-            P(GRAPH_AXIS),
-            P(GRAPH_AXIS),
-            P(GRAPH_AXIS),
-            P(SOURCES_AXIS),
-        ),
-        out_specs=P(None, SOURCES_AXIS),
-        check_vma=True,
-    )
-    return fn(edge_src, edge_dst, edge_metric, edge_blocked, roots)
 
 
 def _local_split_sssp(
@@ -152,7 +78,10 @@ def _local_split_sssp(
         _dist, changed, it = state
         return changed & (it < vp)
 
-    changed0 = jnp.any(dist <= INF_DIST)  # varying True (see _local_sssp)
+    # initial `changed` must carry the same varying-manual-axes type as
+    # the loop output (jnp.any over the sources-sharded dist): a literal
+    # True is unvarying and check_vma rightly rejects it
+    changed0 = jnp.any(dist <= INF_DIST)  # always True, correctly varying
     dist, _, _ = jax.lax.while_loop(cond, sweep, (dist, changed0, 0))
     # dist is replicated in value but varying in type; one identity
     # pmin proves the replication to check_vma for the P(None, sources)
@@ -204,60 +133,3 @@ def sharded_sssp_split(
     return fn(
         base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, node_overloaded, roots
     )
-
-
-def sharded_sssp_padded(
-    edge_src,
-    edge_dst,
-    edge_metric,
-    edge_blocked,
-    roots,
-    mesh: Mesh,
-    num_nodes: int,
-) -> jax.Array:
-    """`sharded_sssp` for arbitrary sizes: pads roots to a multiple of
-    the sources axis (repeating the first root — duplicate columns are
-    dropped from the result) and the edge arrays to a multiple of the
-    graph axis (dead slots: INF metric, blocked). Returns [Vp, len(roots)].
-    """
-    s = mesh.shape[SOURCES_AXIS]
-    g = mesh.shape[GRAPH_AXIS]
-    b = roots.shape[0]
-    bp = -(-b // s) * s
-    if bp != b:
-        roots = jnp.concatenate(
-            [roots, jnp.broadcast_to(roots[0], (bp - b,))]
-        )
-    e = edge_src.shape[0]
-    ep = -(-e // g) * g
-    if ep != e:
-        pad = ep - e
-        edge_src = jnp.concatenate(
-            [edge_src, jnp.zeros(pad, edge_src.dtype)]
-        )
-        edge_dst = jnp.concatenate(
-            [edge_dst, jnp.full(pad, num_nodes - 1, edge_dst.dtype)]
-        )
-        edge_metric = jnp.concatenate(
-            [edge_metric, jnp.full(pad, INF_DIST, edge_metric.dtype)]
-        )
-        edge_blocked = jnp.concatenate(
-            [edge_blocked, jnp.ones(pad, edge_blocked.dtype)]
-        )
-    dist = sharded_sssp(
-        edge_src, edge_dst, edge_metric, edge_blocked, roots, mesh, num_nodes
-    )
-    # kernel cost ledger (docs/Monitor.md "Device telemetry"): guarded
-    # capture of the sharded edge-list kernel's cost/memory analysis
-    from openr_tpu.monitor import device as device_telemetry
-
-    device_telemetry.observe(
-        "sharded_sssp",
-        lambda: sharded_sssp.lower(
-            edge_src, edge_dst, edge_metric, edge_blocked, roots, mesh,
-            num_nodes,
-        ),
-        span="spf:sharded_solve",
-        span_complete=False,  # dispatch-only span (async return)
-    )
-    return dist[:, :b]

@@ -19,11 +19,38 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 
 
 def pytest_report_header(config):
     return f"jax devices: {jax.devices()}"
+
+
+def pytest_configure(config):
+    """Build `native/` once where it is not built and can be, so that a
+    clean checkout and a built tree run the same tests: `native/build/`
+    is not committed, and the native engine's tests skip without it
+    (they still do on a machine with no `make` or C++ compiler). Only
+    the process that starts the run builds — under xdist the controller,
+    before it starts its workers."""
+    import shutil
+    import subprocess
+
+    from openr_tpu.ops.native_spf import native_available
+
+    if hasattr(config, "workerinput") or native_available():
+        return
+    cxx = os.environ.get("CXX", "g++")
+    if shutil.which("make") is None or shutil.which(cxx) is None:
+        return
+    done = subprocess.run(
+        ["make", "-C", os.path.join(_REPO, "native")],
+        capture_output=True, text=True, check=False,
+    )
+    if done.returncode != 0:
+        # not fatal: the native tests skip, and say why
+        print(f"make -C native failed:\n{done.stdout}{done.stderr}")
 
 
 # --------------------------------------------------------------------------

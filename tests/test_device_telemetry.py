@@ -146,10 +146,10 @@ def test_hbm_transient_backend_error_does_not_latch(monkeypatch):
 
 
 def test_dispatch_spans_are_separated_from_completion_spans():
-    """_solve_dist kernels record under spf:batched_dist, never into
-    the completion-walled spf:batched_solve stat the split RIB path
-    owns (review finding: pooled sub-ms dispatch samples would drag
-    that p50 under any real solve)."""
+    """_solve_dist's dispatch records under no span, never into the
+    completion-walled spf:batched_solve stat the split RIB path owns
+    (review finding: pooled sub-ms dispatch samples would drag that
+    p50 under any real solve)."""
     tel = device_telemetry.telemetry()
     tel.reset()
     tpu, ls, ps, csr = _small_solver()
@@ -159,8 +159,7 @@ def test_dispatch_spans_are_separated_from_completion_spans():
     rows = tel.kernel_rows()
     assert rows["batched_sssp_split_rib"].span == "spf:batched_solve"
     assert rows["batched_sssp_split_rib"].span_complete is True
-    assert rows["batched_sssp_split"].span == "spf:batched_dist"
-    assert rows["batched_sssp_split"].span_complete is False
+    assert rows["batched_sssp_split"].span is None
 
 
 def test_annotate_boundary_makes_no_hbm_sample():

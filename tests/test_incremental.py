@@ -157,43 +157,119 @@ def test_repeated_patches_accumulate():
     np.testing.assert_array_equal(csr.edge_metric, ref.edge_metric)
 
 
+def _prefix_state(ls, ksp_nodes=()):
+    """One /32 per node; KSP2_ED_ECMP on those of `ksp_nodes`."""
+    from openr_tpu.decision.linkstate import PrefixState
+    from openr_tpu.types.topology import (
+        ForwardingAlgorithm,
+        PrefixDatabase,
+        PrefixEntry,
+    )
+
+    ps = PrefixState()
+    for i, name in enumerate(ls.nodes):
+        algo = (
+            ForwardingAlgorithm.KSP2_ED_ECMP
+            if name in ksp_nodes
+            else ForwardingAlgorithm.SP_ECMP
+        )
+        ps.update_prefix_db(
+            PrefixDatabase(
+                this_node_name=name,
+                prefix_entries=(
+                    PrefixEntry(
+                        prefix=f"10.7.{i}.1/32", forwarding_algorithm=algo
+                    ),
+                ),
+            )
+        )
+    return ps
+
+
 def test_solver_device_cache_incremental():
     """TpuSpfSolver distances after a device-side patch == a fresh
-    solver's distances on the same topology (both backends)."""
+    solver's distances on the same topology == the reference solve on
+    its full-width tables, and its RIB == the oracle's."""
+    import jax.numpy as jnp
+
+    from openr_tpu.decision.oracle import compute_routes as oracle_routes
     from openr_tpu.decision.spf_backend import TpuSpfSolver
-    from openr_tpu.ops.spf import pad_batch
+    from openr_tpu.ops.spf import batched_sssp_dense, pad_batch
 
     dbs = ring_dbs(8)
     ls = fresh_ls(dbs)
-    engines = [
-        dict(use_dense=None, kernel_impl="split"),
-        dict(use_dense=True, kernel_impl="dense"),
-        dict(use_dense=False),
-    ]
-    for kw in engines:
-        solver = TpuSpfSolver(**kw)
-        csr = ls.to_csr()
-        # root at n3 so the n3→n4 metric bump changes its own distances
-        roots = np.full(
-            pad_batch(4), csr.name_to_id["n3"], dtype=np.int32
+    solver = TpuSpfSolver(native_rib="off")
+    csr = ls.to_csr()
+    n = csr.num_nodes
+    # root at n3 so the n3→n4 metric bump changes its own distances
+    roots = np.full(pad_batch(4), csr.name_to_id["n3"], dtype=np.int32)
+    d0 = np.asarray(solver._solve_dist(csr, roots))
+    ls2 = ls.snapshot()
+    ls2.update_adjacency_db(
+        db("n3", adj("n2", "if32", 10), adj("n4", "if34", 70))
+    )
+    # reverse direction so the bidirectional metric changes too
+    csr2 = ls2.to_csr()
+    assert csr2.patches, "patch path not taken"
+    d1 = np.asarray(solver._solve_dist(csr2, roots))
+    fresh = TpuSpfSolver(native_rib="off")
+    d_ref = np.asarray(fresh._solve_dist(csr2, roots))
+    np.testing.assert_array_equal(d1, d_ref)
+    nbr, wgt = csr2.dense_tables()
+    d_plain = np.asarray(
+        batched_sssp_dense(
+            jnp.asarray(nbr), jnp.asarray(wgt),
+            jnp.asarray(csr2.node_overloaded), jnp.asarray(roots),
+            has_overloads=False,
         )
-        d0 = np.asarray(solver._solve_dist(csr, roots))
-        ls2 = ls.snapshot()
-        ls2.update_adjacency_db(
-            db("n3", adj("n2", "if32", 10), adj("n4", "if34", 70))
-        )
-        # reverse direction so the bidirectional metric changes too
-        csr2 = ls2.to_csr()
-        assert csr2.patches, "patch path not taken"
-        d1 = np.asarray(solver._solve_dist(csr2, roots))
-        fresh = TpuSpfSolver(**kw)
-        d_ref = np.asarray(fresh._solve_dist(csr2, roots))
-        np.testing.assert_array_equal(d1, d_ref)
-        assert (d1 != d0).any()  # the metric change actually moved dists
-        # and solving the ORIGINAL snapshot again still works (backward
-        # version → full re-upload, not corruption)
-        d_back = np.asarray(solver._solve_dist(csr, roots))
-        np.testing.assert_array_equal(d_back, d0)
+    )
+    np.testing.assert_array_equal(d1[:n], d_plain[:n])
+    assert (d1 != d0).any()  # the metric change actually moved dists
+    ps = _prefix_state(ls2)
+    got = solver.compute_routes(ls2, ps, "n3")
+    want = oracle_routes(ls2, ps, "n3")
+    assert got.unicast_routes == want.unicast_routes
+    assert got.mpls_routes == want.mpls_routes
+    # and solving the ORIGINAL snapshot again still works (backward
+    # version → full re-upload, not corruption)
+    d_back = np.asarray(solver._solve_dist(csr, roots))
+    np.testing.assert_array_equal(d_back, d0)
+
+
+@pytest.mark.parametrize(
+    "ksp_nodes,want_sets",
+    [((), {"split"}), (("n6",), {"split", "dense"})],
+    ids=["ksp_off", "one_ksp_prefix"],
+)
+def test_device_cache_holds_only_sets_asked_for(ksp_nodes, want_sets):
+    """After a metric patch the device cache holds exactly the table
+    sets a solve asked for — the split tables, and KSP's full-width
+    tables only once a KSP prefix wants them — and each, patched in
+    place, equals a fresh upload of the patched topology."""
+    from openr_tpu.decision.spf_backend import TpuSpfSolver
+
+    ls = fresh_ls(ring_dbs(8))
+    ps = _prefix_state(ls, ksp_nodes)
+    solver = TpuSpfSolver(native_rib="off")
+    solver.compute_routes(ls, ps, "n0")
+    ls.update_adjacency_db(
+        db("n3", adj("n2", "if32", 10), adj("n4", "if34", 70))
+    )
+    csr = ls.to_csr()
+    assert csr.patches, "patch path not taken"
+    solver.compute_routes(ls, ps, "n0")
+    assert solver.dev_cache_stats["patches"] == 1
+    sets = solver._dev[csr.base_version]["sets"]
+    assert set(sets) == want_sets
+    fresh = TpuSpfSolver(native_rib="off")
+    for name, dset in sets.items():
+        ref = fresh._device_arrays(csr, name)
+        assert set(dset) == set(ref)
+        for key, arr in dset.items():
+            np.testing.assert_array_equal(
+                np.asarray(arr), np.asarray(ref[key]), err_msg=f"{name}.{key}"
+            )
+    assert fresh.dev_cache_stats["patches"] == 0
 
 
 def test_decision_churn_end_to_end_equivalence():

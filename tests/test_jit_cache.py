@@ -4,8 +4,8 @@ distinct inputs.
 The jit cache keys on dtype, weak-type AND commitment — a python int,
 an ``np.int32`` scalar and a ``jnp.int32`` array are three cache
 entries for identical math (measured on jax 0.4.37). The ops layer's
-canonicalizing entry points (``ops/ksp.py``, ``ops/spf_pallas.py``)
-exist so every equivalent call spelling lands on ONE compiled variant,
+canonicalizing entry point (``ops/ksp.py``) exists so every
+equivalent call spelling lands on ONE compiled variant,
 and the padding buckets make every batch size inside a bucket share a
 shape. These tests pin both, two ways: exact ``_cache_size`` deltas on
 the kernels, and the conftest compile sanitizer
@@ -114,42 +114,6 @@ def test_ksp_uncanonicalized_scalars_would_split_the_cache():
         nbr_d, wgt_d, blocked, np.int32(0), dests, k=2, max_hops=n - 1
     )
     assert _ksp_edge_disjoint_dense_jit._cache_size() - size0 == 2
-
-
-@pytest.mark.jit_steady_state
-def test_pallas_cache_stable_across_equivalent_spellings():
-    from openr_tpu.ops.spf_pallas import _relax_once, batched_sssp_pallas
-
-    n = 16
-    nbr, wgt = _line_graph(n)
-    over = np.zeros(n, bool)
-    roots = np.array([0, 3], np.int32)
-
-    spellings = (
-        (nbr, wgt, over, roots),
-        (jnp.asarray(nbr), jnp.asarray(wgt), jnp.asarray(over), roots),
-        (nbr, wgt, over, jnp.asarray(roots)),
-        (nbr, wgt, over, [0, 3]),  # python-int roots list
-    )
-
-    def run_all():
-        outs = [
-            np.asarray(
-                batched_sssp_pallas(*sp, has_overloads=False)
-            )
-            for sp in spellings
-        ]
-        for got in outs[1:]:
-            np.testing.assert_array_equal(outs[0], got)
-        return outs[0]
-
-    run_all()  # warm: one _relax_once variant + per-type eager converts
-    size_after_warm = _relax_once._cache_size()
-    compile_ledger.mark_warm()
-    run_all()  # steady state: zero compiles (fixture enforces eagers)
-    assert _relax_once._cache_size() == size_after_warm, (
-        "equivalent-but-distinct inputs minted new _relax_once variants"
-    )
 
 
 @pytest.mark.jit_steady_state

@@ -2,8 +2,8 @@
 
 Spawns TWO real processes, each with 4 virtual CPU devices, joined via
 jax.distributed into one 8-device global mesh, and runs the sharded SPF
-with the graph axis spanning the process (DCN) boundary — so the pmin
-frontier-exchange collective actually crosses processes. Each worker
+with the graph axis spanning the process (DCN) boundary — so the
+all_gather frontier exchange actually crosses processes. Each worker
 checks its addressable output shards against the host oracle.
 """
 
@@ -39,32 +39,26 @@ import numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from openr_tpu.ops.spf import INF_DIST, build_blocked, pad_batch
-from openr_tpu.parallel import sharded_sssp_padded
+from openr_tpu.ops.spf import INF_DIST, pad_batch
+from openr_tpu.ops.spf_split import build_split_tables
+from openr_tpu.parallel import sharded_sssp_split
 from openr_tpu.parallel.mesh import GRAPH_AXIS, SOURCES_AXIS
 from openr_tpu.utils import topogen
 
 # graph axis = 2 spans the two processes (4 sources x 2 graph over
 # [p0d0..p0d3, p1d0..p1d3] row-major => each graph-axis pair is
-# (p0dX, p1dX)): the pmin rides the process boundary.
+# (p0dX, p1dX)): the per-sweep tiled all_gather (table-row partition)
+# rides the process boundary.
 mesh = distributed.global_mesh(n_graph=2)
 assert mesh.shape[SOURCES_AXIS] == 4 and mesh.shape[GRAPH_AXIS] == 2
 
 es, ed, em, vp, n, e = topogen.erdos_renyi_csr(
     600, avg_degree=6, seed=21, max_metric=32
 )
-blocked = build_blocked(em, es, np.zeros(vp, bool))
 roots_h = np.arange(pad_batch(8), dtype=np.int32) % n
-
-args = [
-    distributed.shard_host_array(jnp.asarray(a), mesh, P(GRAPH_AXIS))
-    for a in (es, ed, em, blocked)
-]
 roots = distributed.shard_host_array(
     jnp.asarray(roots_h), mesh, P(SOURCES_AXIS)
 )
-dist = sharded_sssp_padded(*args, roots, mesh, vp)
-jax.block_until_ready(dist)
 
 # oracle: scipy dijkstra on the full graph (host-side, per process)
 from scipy.sparse import csr_matrix
@@ -76,19 +70,6 @@ m = csr_matrix(
 )
 ref = dijkstra(m, indices=roots_h)
 ref[np.isinf(ref)] = float(INF_DIST)
-
-for shard in dist.addressable_shards:
-    cols = shard.index[1]
-    got = np.asarray(shard.data)
-    want = ref[cols].T  # ref rows = roots; shard cols = root slice
-    assert (got == want.astype(np.int64)).all(), (
-        f"proc {jax.process_index()} shard {cols} mismatch"
-    )
-
-# --- the FLAGSHIP split-width kernel across the same process boundary:
-# its per-sweep tiled all_gather (table-row partition) rides DCN here
-from openr_tpu.ops.spf_split import build_split_tables
-from openr_tpu.parallel import sharded_sssp_split
 
 t = build_split_tables(es, ed, em, n)
 vps = t["vp"]
@@ -111,14 +92,14 @@ jax.block_until_ready(sdist)
 for shard in sdist.addressable_shards:
     cols = shard.index[1]
     got = np.asarray(shard.data)
-    want = ref[cols].T
+    want = ref[cols].T  # ref rows = roots; shard cols = root slice
     live = min(n, got.shape[0], want.shape[0])  # paddings differ
     assert (got[:live] == want[:live].astype(np.int64)).all(), (
         f"proc {jax.process_index()} split-kernel shard {cols} mismatch"
     )
 
 print(f"WORKER_OK proc={jax.process_index()} shards="
-      f"{len(dist.addressable_shards)} split_ok=1")
+      f"{len(sdist.addressable_shards)} split_ok=1")
 """
 
 

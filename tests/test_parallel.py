@@ -8,8 +8,9 @@ import pytest
 
 from openr_tpu.decision.linkstate import LinkState
 from openr_tpu.decision.oracle import run_spf
-from openr_tpu.ops.spf import INF_DIST, build_blocked
-from openr_tpu.parallel import make_mesh, sharded_sssp
+from openr_tpu.ops.spf import INF_DIST, pad_batch
+from openr_tpu.ops.spf_split import batched_sssp_split, build_split_tables
+from openr_tpu.parallel import make_mesh, sharded_sssp_split
 from openr_tpu.utils import topogen
 
 
@@ -20,26 +21,40 @@ def _csr(adj_dbs):
     return ls, ls.to_csr()
 
 
-def _dist(csr, mesh, roots):
-    blocked = build_blocked(csr.edge_metric, csr.edge_src, csr.node_overloaded)
-    return np.asarray(
-        sharded_sssp(
-            jnp.asarray(csr.edge_src),
-            jnp.asarray(csr.edge_dst),
-            jnp.asarray(csr.edge_metric),
-            jnp.asarray(blocked),
-            jnp.asarray(roots),
-            mesh,
-            csr.padded_nodes,
-        )
+def _split_tables(csr):
+    """The split tables of `csr` on the device, as the solver uploads
+    them: (base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt), out_nbr, over."""
+    t = build_split_tables(
+        csr.edge_src, csr.edge_dst, csr.edge_metric, csr.num_nodes
     )
+    over = np.zeros(t["vp"], bool)
+    over[: csr.num_nodes] = csr.node_overloaded[: csr.num_nodes]
+    tables = tuple(
+        jnp.asarray(t[k])
+        for k in ("base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt")
+    )
+    return tables, jnp.asarray(t["out_nbr"]), jnp.asarray(over)
+
+
+def _dist(csr, mesh, roots):
+    """[vp, len(roots)] from the sharded split kernel, the roots padded
+    to their bucket (repeating the first) as `_mesh_fits` expects."""
+    padded = np.full(pad_batch(len(roots)), roots[0], dtype=np.int32)
+    padded[: len(roots)] = roots
+    tables, _out_nbr, over = _split_tables(csr)
+    dist = sharded_sssp_split(
+        *tables, over, jnp.asarray(padded), mesh,
+        has_overloads=bool(csr.node_overloaded.any()),
+    )
+    return np.asarray(dist)[:, : len(roots)]
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
 @pytest.mark.parametrize("shape", [(8, 1), (4, 2), (2, 4), (1, 8)])
 def test_sharded_matches_oracle(shape):
     """Every mesh factorization (pure sources, mixed, pure graph-partition
-    with pmin frontier exchange) must produce identical distances."""
+    with the all_gather frontier exchange) must produce identical
+    distances."""
     s, g = shape
     adj_dbs, _ = topogen.erdos_renyi(64, avg_degree=4, seed=1, max_metric=50)
     ls, csr = _csr(adj_dbs)
@@ -78,27 +93,14 @@ def test_sharded_with_overload():
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
 @pytest.mark.parametrize("n_roots", [1, 5, 13])
 def test_sharded_padded_uneven_roots(n_roots):
-    """Root counts that do NOT divide the sources axis work through the
-    padding wrapper and match the oracle."""
-    from openr_tpu.parallel import sharded_sssp_padded
-
+    """Root counts that do NOT divide the sources axis work once padded
+    to their bucket and match the oracle."""
     adj_dbs, _ = topogen.erdos_renyi(40, avg_degree=5, seed=3, max_metric=20)
     ls, csr = _csr(adj_dbs)
     mesh = make_mesh(n_sources=4, n_graph=2)
     roots = np.linspace(0, 39, n_roots).astype(np.int32)
-    blocked = build_blocked(csr.edge_metric, csr.edge_src, csr.node_overloaded)
-    dist = np.asarray(
-        sharded_sssp_padded(
-            jnp.asarray(csr.edge_src),
-            jnp.asarray(csr.edge_dst),
-            jnp.asarray(csr.edge_metric),
-            jnp.asarray(blocked),
-            jnp.asarray(roots),
-            mesh,
-            csr.padded_nodes,
-        )
-    )
-    assert dist.shape == (csr.padded_nodes, n_roots)
+    dist = _dist(csr, mesh, roots)
+    assert dist.shape[1] == n_roots
     for col, rid in enumerate(roots):
         root = csr.node_names[rid]
         res = run_spf(ls, root)
@@ -135,27 +137,29 @@ def test_sharded_512_nodes_with_overload():
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
-def test_all_sources_pipelined_matches_sharded():
-    """all_sources_sssp (double-buffered chunk pipeline) agrees with the
-    sharded solve column-for-column."""
-    from openr_tpu.ops.spf import all_sources_sssp
-
+def test_all_sources_chunked_matches_sharded():
+    """All sources solved in chunks on one device agree with the sharded
+    solve column-for-column."""
     adj_dbs, _ = topogen.erdos_renyi(96, avg_degree=5, seed=5, max_metric=30)
     ls, csr = _csr(adj_dbs)
-    blocked = build_blocked(csr.edge_metric, csr.edge_src, csr.node_overloaded)
-    full = all_sources_sssp(
-        jnp.asarray(csr.edge_src),
-        jnp.asarray(csr.edge_dst),
-        jnp.asarray(csr.edge_metric),
-        jnp.asarray(blocked),
-        csr.padded_nodes,
-        chunk=32,  # force several chunks + a ragged tail
+    tables, out_nbr, over = _split_tables(csr)
+    full = np.concatenate(
+        [
+            np.asarray(
+                batched_sssp_split(
+                    *tables, out_nbr, over,
+                    jnp.arange(start, start + 32, dtype=jnp.int32),
+                    has_overloads=False,
+                )
+            )
+            for start in range(0, 96, 32)  # several chunks
+        ],
+        axis=1,
     )
     mesh = make_mesh(n_sources=8, n_graph=1)
     roots = np.arange(96, dtype=np.int32)
     dist = _dist(csr, mesh, roots)
-    # all_sources rows are sources; the sharded result is [node, source]
-    np.testing.assert_array_equal(full[:96, :96], dist[:96, :96].T)
+    np.testing.assert_array_equal(full[:96], dist[:96])
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
@@ -164,12 +168,6 @@ def test_sharded_split_kernel_matches_single_device(shape):
     """The flagship v3 split kernel under sources x graph sharding must
     equal the single-device split kernel (and transitively the oracle),
     including with overloaded nodes."""
-    from openr_tpu.ops.spf_split import (
-        batched_sssp_split,
-        build_split_tables,
-    )
-    from openr_tpu.parallel import sharded_sssp_split
-
     es, ed, em, vp, nn, _e = topogen.erdos_renyi_csr(
         700, avg_degree=6, seed=21, max_metric=32
     )

@@ -34,8 +34,8 @@ def _free_ports(n):
 
 def _node_cfg(name, ctrl, kv, udp_local, udp_peer, loopback):
     # long spark hold: the kill/restart window below must be a kvstore
-    # session break, NOT an adjacency loss — peer objects persist, the
-    # TCP reconnect path is what's under test
+    # session break, NOT a hold-timer adjacency loss — the TCP
+    # reconnect path is what's under test
     return {
         "node_name": name,
         "ctrl_port": ctrl,
@@ -141,9 +141,13 @@ def test_bind_collision_fails_fast(tmp_path):
 def test_kill_restart_reconnects_same_peer(tmp_path):
     """SIGKILL one of two daemons mid-adjacency and bring it back on the
     SAME pinned ports: the survivor's kvstore session breaks (RST /
-    ECONNREFUSED under ExponentialBackoff retries), the peer object
-    persists (spark hold ≫ downtime), and the eventual re-sync must be
-    counted as kvstore.peer_reconnects — plus full re-convergence."""
+    ECONNREFUSED under ExponentialBackoff retries), and the eventual
+    re-sync must be counted as kvstore.peer_reconnects — plus full
+    re-convergence. The count holds whichever way Spark sees the fresh
+    instance first: by its unsolicited handshake (NEIGHBOR_RESTARTED,
+    the kvstore peer object stays) or by its first hello, which does
+    not hear us yet (NEIGHBOR_DOWN then NEIGHBOR_UP, the peer object is
+    rebuilt) — which packet wins is a race the survivor cannot steer."""
 
     async def main():
         ctrl_a, ctrl_b, kv_a, kv_b, udp_a, udp_b = _free_ports(6)

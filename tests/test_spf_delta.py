@@ -614,12 +614,13 @@ def _star_ls():
 
 @pytest.fixture(scope="module")
 def patched_star():
-    """Every table set resident, then ONE journal suffix of four patches
-    scattered into all of them: three distinct cells (padded to a batch
-    of 8), one of them patched twice with different values, two in the
-    hub's row on either side of the base width. Returns {site: (what
-    the device holds, the numpy reference)}: the reference applies the
-    suffix in journal order to the unpatched host tables."""
+    """Both table sets resident (the split tables and KSP's), then ONE
+    journal suffix of four patches scattered into both: three distinct
+    cells (padded to a batch of 8), one of them patched twice with
+    different values, two in the hub's row on either side of the base
+    width. Returns {site: (what the device holds, the numpy reference)}:
+    the reference applies the suffix in journal order to the unpatched
+    host tables."""
     import jax.numpy as jnp
 
     from openr_tpu.decision.spf_backend import (
@@ -631,14 +632,13 @@ def patched_star():
     ls, set_metric = _star_ls()
     solver = TpuSpfSolver(native_rib="off")
     csr0 = ls.to_csr()
-    for want in ("dense", "edge", "split"):
+    for want in ("dense", "split"):
         solver._device_arrays(csr0, want)
     t0 = _split_tables_of(csr0)
     w, ov_pos = t0["base_nbr"].shape[1], t0["ov_pos"]
     assert w == 8 and ov_pos[csr0.name_to_id["n00"]] >= 0
     ref = {
         "dense_wgt": csr0.dense_tables()[1].copy(),
-        "edge_metric": csr0.edge_metric.copy(),
         "split_base_wgt": t0["base_wgt"].copy(),
         "split_ov_wgt": t0["ov_wgt"].copy(),
     }
@@ -654,11 +654,10 @@ def patched_star():
     assert {p.dense_col < w for p in suffix} == {True, False}
     calls0 = solver.dev_cache_stats["scatter_calls"]
     solver._device_arrays(csr, "split")
-    # one program a patched array: dense, edge, split base, split overflow
-    assert solver.dev_cache_stats["scatter_calls"] - calls0 == 4
+    # one program a patched array: dense, split base, split overflow
+    assert solver.dev_cache_stats["scatter_calls"] - calls0 == 3
     for p in suffix:
         ref["dense_wgt"][p.dense_row, p.dense_col] = p.metric
-        ref["edge_metric"][p.edge_idx] = p.metric
         if p.dense_col < w:
             ref["split_base_wgt"][p.dense_row, p.dense_col] = p.metric
         else:
@@ -666,13 +665,11 @@ def patched_star():
     # the reference is the patched CSR's own tables, built from scratch
     t1 = _split_tables_of(csr)
     np.testing.assert_array_equal(ref["dense_wgt"], csr.dense_tables()[1])
-    np.testing.assert_array_equal(ref["edge_metric"], csr.edge_metric)
     np.testing.assert_array_equal(ref["split_base_wgt"], t1["base_wgt"])
     np.testing.assert_array_equal(ref["split_ov_wgt"], t1["ov_wgt"])
     sets = solver._dev[csr.base_version]["sets"]
     got = {
         "dense_wgt": sets["dense"]["wgt"],
-        "edge_metric": sets["edge"]["metric"],
         "split_base_wgt": sets["split"]["base_wgt"],
         "split_ov_wgt": sets["split"]["ov_wgt"],
     }
@@ -696,11 +693,10 @@ def patched_star():
 
 @pytest.mark.parametrize(
     "site",
-    ["dense_wgt", "edge_metric", "split_base_wgt", "split_ov_wgt",
-     "dist_matrix"],
+    ["dense_wgt", "split_base_wgt", "split_ov_wgt", "dist_matrix"],
 )
 def test_compiled_scatter_equals_numpy_reference(patched_star, site):
-    """Each of the five sites that patch a device array through
+    """Each of the four sites that patch a device array through
     `_scatter_set` holds what numpy's sequential assignment gives: a
     padded batch, one cell twice in a suffix (the last value wins), a
     suffix split between the base and the overflow table."""

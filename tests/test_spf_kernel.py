@@ -14,12 +14,7 @@ from openr_tpu.decision.linkstate import LinkState, PrefixState
 from openr_tpu.decision.oracle import compute_routes as oracle_routes
 from openr_tpu.decision.oracle import run_spf
 from openr_tpu.decision.spf_backend import TpuSpfSolver
-from openr_tpu.ops.spf import (
-    INF_DIST,
-    all_sources_sssp,
-    batched_sssp,
-    build_blocked,
-)
+from openr_tpu.ops.spf import INF_DIST, pad_batch
 from openr_tpu.types.topology import AdjacencyDatabase
 from openr_tpu.utils import topogen
 
@@ -45,22 +40,31 @@ def _overload(db: AdjacencyDatabase) -> AdjacencyDatabase:
 
 def _assert_rib_equal(ls, ps, node):
     want = oracle_routes(ls, ps, node)
-    # every engine must match the oracle exactly: the v3 split kernel,
-    # the r2 dense kernel, the edge-list segment-min kernel, and the
-    # native C++ radix-heap solver (skipped if the .so isn't built)
-    engines = [
-        dict(use_dense=None, kernel_impl="split", native_rib="off"),
-        dict(use_dense=True, kernel_impl="dense", native_rib="off"),
-        dict(use_dense=False, native_rib="off"),
-    ]
-    from openr_tpu.ops.native_spf import native_available
-
-    if native_available():
-        engines.append(dict(native_rib="on"))
-    for kw in engines:
+    # both engines must match the oracle exactly
+    for kw in _engines():
         got = TpuSpfSolver(**kw).compute_routes(ls, ps, node)
         assert got.unicast_routes == want.unicast_routes, (node, kw)
         assert got.mpls_routes == want.mpls_routes, (node, kw)
+
+
+def _engines():
+    """The split kernel on the device and, where the .so is built, the
+    native C++ radix-heap solver."""
+    from openr_tpu.ops.native_spf import native_available
+
+    engines = [dict(native_rib="off")]
+    if native_available():
+        engines.append(dict(native_rib="on"))
+    return engines
+
+
+def _split_dist(csr, roots):
+    """[vp, B] distances from the split kernel as the solver runs it,
+    roots padded to their bucket by repeating the first."""
+    padded = np.full(pad_batch(len(roots)), roots[0], dtype=np.int32)
+    padded[: len(roots)] = roots
+    dist = TpuSpfSolver(native_rib="off")._solve_dist(csr, padded)
+    return np.asarray(dist)[:, : len(roots)]
 
 
 TOPOLOGIES = {
@@ -141,17 +145,13 @@ def test_kernel_dist_matches_oracle_random():
         for db in adj_dbs:
             ls.update_adjacency_db(db)
         csr = ls.to_csr()
-        blocked = build_blocked(csr.edge_metric, csr.edge_src, csr.node_overloaded)
-        dist = all_sources_sssp(
-            csr.edge_src, csr.edge_dst, csr.edge_metric, blocked,
-            csr.padded_nodes, chunk=64,
-        )
-        for root in ls.nodes[::7]:
+        roots = ls.nodes[::7]
+        dist = _split_dist(csr, [csr.name_to_id[r] for r in roots])
+        for col, root in enumerate(roots):
             res = run_spf(ls, root)
-            rid = csr.name_to_id[root]
             for n, i in csr.name_to_id.items():
                 want = res.dist.get(n)
-                got = int(dist[rid, i])
+                got = int(dist[i, col])
                 if want is None:
                     assert got >= INF_DIST, (root, n)
                 else:
@@ -174,12 +174,10 @@ def test_large_metrics_no_inversion():
     ]
     adj_dbs, prefix_dbs = topogen._mk_dbs(5, edges)
     ls, ps = _state(adj_dbs, prefix_dbs)
-    for use_dense in (True, False):
-        got = TpuSpfSolver(use_dense=use_dense).compute_routes(
-            ls, ps, "node-0"
-        )
+    for kw in _engines():
+        got = TpuSpfSolver(**kw).compute_routes(ls, ps, "node-0")
         r = got.unicast_routes[topogen.loopback(4)]
-        assert r.igp_cost == 3_600_000, (use_dense, r.igp_cost)
+        assert r.igp_cost == 3_600_000, (kw, r.igp_cost)
         assert {nh.neighbor_node for nh in r.nexthops} == {"node-2"}
     _assert_rib_equal(ls, ps, "node-0")
 
@@ -197,9 +195,10 @@ def test_rib_equivalence_metric_above_clamp():
     assert want.unicast_routes  # routes must actually exist
 
 
-def test_dense_selection_avoids_mega_hub_blowup():
-    """A star topology (one hub with huge degree) must auto-select the
-    edge-list kernel without materializing the V*D dense tables."""
+def test_split_builder_bounds_mega_hub_blowup():
+    """A star topology (one hub with huge degree) solves on split tables
+    whose base width stays under the hub's degree, without materializing
+    the V*D full-width tables."""
     n = 40
     edges = []
     for i in range(1, n):
@@ -207,17 +206,14 @@ def test_dense_selection_avoids_mega_hub_blowup():
     adj_dbs, prefix_dbs = topogen._mk_dbs(n, edges)
     ls, ps = _state(adj_dbs, prefix_dbs)
     csr = ls.to_csr()
-    # the size check guards the r2 dense kernel (the split builder bounds
-    # hub waste by construction, so it needs no escape hatch); force the
-    # dense kernel + a tripping limit, and keep native off so the batched
-    # path actually runs
-    solver = TpuSpfSolver(
-        dense_waste_limit=1, kernel_impl="dense", native_rib="off"
-    )
+    # native off so the batched path actually runs
+    solver = TpuSpfSolver(native_rib="off")
     assert csr.dense_width() >= 32
-    assert solver._pick_table(csr) == "edge"
     _ = solver.compute_routes(ls, ps, "node-1")
-    assert csr._dense is None  # tables were never built
+    assert csr._dense is None  # full-width tables were never built
+    # pick_base_width: the hub's in-edges past the base overflow
+    base_w = solver._dev[csr.base_version]["host"]["split"]["base_w"]
+    assert base_w < n - 1
     _assert_rib_equal(ls, ps, "node-1")
 
 
@@ -227,20 +223,7 @@ def test_kernel_repeated_roots_and_padding():
     for db in adj_dbs:
         ls.update_adjacency_db(db)
     csr = ls.to_csr()
-    import jax.numpy as jnp
-
-    blocked = build_blocked(csr.edge_metric, csr.edge_src, csr.node_overloaded)
-    roots = jnp.asarray(np.array([0, 0, 2, 2], dtype=np.int32))
-    dist = np.asarray(
-        batched_sssp(
-            jnp.asarray(csr.edge_src),
-            jnp.asarray(csr.edge_dst),
-            jnp.asarray(csr.edge_metric),
-            jnp.asarray(blocked),
-            roots,
-            csr.padded_nodes,
-        )
-    )
+    dist = _split_dist(csr, [0, 0, 2, 2])
     assert (dist[:, 0] == dist[:, 1]).all()
     assert (dist[:, 2] == dist[:, 3]).all()
     assert dist[0, 0] == 0 and dist[2, 0] == 2

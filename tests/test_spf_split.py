@@ -2,9 +2,10 @@
 
 Mirrors the reference's Decision test style (golden distances on
 synthetic graphs; reference: openr/decision/tests/DecisionTest.cpp †):
-the v3 kernel must produce byte-identical distances to the r2 dense
-kernel — which is itself oracle-tested — on every topology class,
-including overloads, and through its tail/spill phases.
+the v3 kernel must produce byte-identical distances to the plain
+Bellman-Ford over the full-width tables (`batched_sssp_dense`, the
+reference) on every topology class, including overloads, and through
+its tail/spill phases.
 """
 
 from __future__ import annotations
@@ -13,7 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from openr_tpu.ops.spf import batched_sssp_dense, build_dense_tables, pad_batch
+from openr_tpu.ops.spf import (
+    batched_sssp_dense,
+    build_dense_tables,
+    first_hop_matrix,
+    lfa_matrix,
+    pad_batch,
+)
 from openr_tpu.ops.spf_split import (
     batched_sssp_split,
     build_split_tables,
@@ -53,12 +60,16 @@ def _solve_both(es, ed, em, vp, n, roots, over=None, **tail_kw):
 
 
 @pytest.mark.parametrize(
-    "n,deg,mw",
-    [(200, 4, 8), (1000, 8, 64), (2000, 16, 16)],
+    "n,deg,mw,seed",
+    [
+        (200, 4, 8, 3), (1000, 8, 64, 3), (2000, 16, 16, 3),
+        (256, 8, 16, 0), (256, 8, 16, 1),
+        (512, 16, 8, 0), (512, 16, 8, 1),
+    ],
 )
-def test_split_matches_dense_er(n, deg, mw):
+def test_split_matches_dense_er(n, deg, mw, seed):
     es, ed, em, vp, nn, _e = topogen.erdos_renyi_csr(
-        n, avg_degree=deg, seed=3, max_metric=mw
+        n, avg_degree=deg, seed=seed, max_metric=mw
     )
     roots = np.arange(pad_batch(8), dtype=np.int32) % nn
     ref, got = _solve_both(es, ed, em, vp, nn, roots)
@@ -68,7 +79,7 @@ def test_split_matches_dense_er(n, deg, mw):
 # 9000 → vp=9216 ≥ GS_MIN_VP: the DEFAULT picker runs chunked sweeps,
 # so the dense-equality assertion covers the production GS path (the
 # explicit-override coverage is test_split_gs_chunk_counts_all_equal)
-@pytest.mark.parametrize("n", [800, 9000])
+@pytest.mark.parametrize("n", [256, 800, 9000])
 def test_split_matches_dense_overloads(n):
     es, ed, em, vp, nn, _e = topogen.erdos_renyi_csr(
         n, avg_degree=6, seed=5, max_metric=32
@@ -180,44 +191,61 @@ def test_tight_nodes_and_width_picker():
 
 def test_fused_rib_path_matches_dense_and_lazy_dist():
     """batched_sssp_split_rib (fused solve + packed d_root/fh/lfa) must
-    produce byte-identical results to the unfused dense-kernel path, and
-    _LazyDist must serve every spelling of the root column without a
-    full materialization."""
+    produce byte-identical results to the reference solve with the
+    first-hop / LFA identities applied to its distances, and _LazyDist
+    must serve every spelling of the root column without a full
+    materialization."""
+    from openr_tpu.decision.oracle import compute_routes as oracle_routes
     from openr_tpu.decision.spf_backend import TpuSpfSolver, _LazyDist
 
     ls, ps, csr = topogen.erdos_renyi_lsdb(
         220, avg_degree=6, seed=7, max_metric=64
     )
     n = csr.num_nodes
+    my_id = csr.name_to_id["node-0"]
+    nbr, wgt = csr.dense_tables()
     for lfa in (False, True):
         a = TpuSpfSolver(native_rib="off", enable_lfa=lfa)  # fused split
-        b = TpuSpfSolver(
-            native_rib="off", kernel_impl="dense", enable_lfa=lfa
+        sa = a.solve(ls, "node-0")
+        nbr_ids = np.array(sa[3], np.int32)
+        k = len(nbr_ids)
+        over = jnp.asarray(csr.node_overloaded)
+        nbr_over = jnp.asarray(csr.node_overloaded[nbr_ids])
+        ref = batched_sssp_dense(
+            jnp.asarray(nbr), jnp.asarray(wgt), over,
+            jnp.asarray(np.array([my_id, *nbr_ids], np.int32)),
+            has_overloads=bool(csr.node_overloaded.any()),
         )
-        sa, sb = a.solve(ls, "node-0"), b.solve(ls, "node-0")
+        ref_np = np.asarray(ref)
         assert isinstance(sa[1], _LazyDist)
         # root column fast path: several spellings, no materialization
         assert sa[1]._np is None
+        np.testing.assert_array_equal(sa[1][:, 0][:n], ref_np[:n, 0])
+        np.testing.assert_array_equal(sa[1][:n, 0], ref_np[:n, 0])
         np.testing.assert_array_equal(
-            sa[1][:, 0][:n], np.asarray(sb[1])[:n, 0]
-        )
-        np.testing.assert_array_equal(
-            sa[1][:n, 0], np.asarray(sb[1])[:n, 0]
-        )
-        np.testing.assert_array_equal(
-            sa[1][:, np.int32(0)][:n], np.asarray(sb[1])[:n, 0]
+            sa[1][:, np.int32(0)][:n], ref_np[:n, 0]
         )
         assert sa[1]._np is None, "root-column reads must not transfer"
-        # full materialization agrees
+        # full materialization agrees (columns past 1 + k are padding)
         np.testing.assert_array_equal(
-            np.asarray(sa[1])[:n], np.asarray(sb[1])[:n]
+            np.asarray(sa[1])[:n, : 1 + k], ref_np[:n]
         )
-        np.testing.assert_array_equal(sa[2][:, :n], sb[2][:, :n])
+        want_fh = np.asarray(first_hop_matrix(
+            ref, jnp.asarray(a._nbr_metrics(csr, my_id, sa[3])),
+            jnp.asarray(nbr_ids), nbr_over,
+        ))
+        np.testing.assert_array_equal(sa[2][:k, :n], want_fh[:, :n])
+        assert not sa[2][k:].any()
         if lfa:
-            np.testing.assert_array_equal(sa[4][:, :n], sb[4][:, :n])
-        assert a.compute_routes(ls, ps, "node-0") == b.compute_routes(
-            ls, ps, "node-0"
-        )
+            want_lfa = np.asarray(lfa_matrix(
+                ref, jnp.int32(my_id), jnp.asarray(nbr_ids), nbr_over,
+            ))
+            np.testing.assert_array_equal(sa[4][:k, :n], want_lfa[:, :n])
+            assert not sa[4][k:].any()
+        got = a.compute_routes(ls, ps, "node-0")
+        want = oracle_routes(ls, ps, "node-0", enable_lfa=lfa)
+        assert got.unicast_routes == want.unicast_routes
+        assert got.mpls_routes == want.mpls_routes
 
 
 def test_uni_cache_not_fooled_by_parallel_prefix_states():
@@ -348,13 +376,12 @@ def test_backend_kernel_stats_and_patch_clears_uniform():
                 adj(f"n{(i + 1) % n}", f"if{i}b", 10),
             ),
         ))
-    solver = TpuSpfSolver(native_rib="off", use_dense=False)
+    solver = TpuSpfSolver(native_rib="off")
     csr = ls.to_csr()
-    # force the split tables (the picker may choose dense at this size)
     dev = solver._device_arrays(csr, "split")
     assert dev["uniform_metric"] == 10
     roots = np.zeros(pad_batch(2), np.int32)
-    solver._solve_dist(csr, roots, _dispatched=("split", dev, False))
+    solver._solve_dist(csr, roots)
     assert solver.spf_kernel_stats["uniform_metric"] >= 1
     assert (
         solver.spf_kernel_stats["gs_active"]

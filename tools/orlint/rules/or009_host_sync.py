@@ -18,10 +18,9 @@ O(rounds)-round-trip one:
     in that same loop — the classic read-back-per-sweep host loop.
   * ``np.asarray(...)`` inside a loop with no kernel dispatch in the
     same loop — a transfer per iteration with nothing pipelined against
-    it. Loops that also dispatch (the double-buffered chunk pipelines in
-    ``ops/spf.py all_sources_sssp`` and ``decision/fleet.py``) overlap
-    the previous chunk's transfer with the current chunk's compute and
-    are deliberately allowed.
+    it. Loops that also dispatch (the double-buffered chunk pipeline in
+    ``decision/fleet.py``) overlap the previous chunk's transfer with
+    the current chunk's compute and are deliberately allowed.
 
 Fix patterns: fuse the loop into the kernel (``lax.while_loop`` — how
 spf_split keeps its whole fixpoint on device), return packed outputs
