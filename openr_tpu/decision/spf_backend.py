@@ -307,19 +307,23 @@ class TpuSpfSolver:
         # Surfaced as decision.spf.* counters.
         # The kernel's own loop counters, read from the packed
         # buffer's trailer (ops.spf_split.rib_buffer_trailer), add up
-        # here: dense_sweeps / tail_rounds / net_sweeps / tail_spills of
-        # cold solves; the last three warm_-prefixed of warm starts,
-        # which run no dense sweep. A spill or a net sweep means the
-        # tail's frontier cap was too small (docs/Monitor.md "Spans").
+        # here: dense_sweeps / tail_rounds / net_sweeps / tail_spills /
+        # tail_small_rounds of cold solves; the last four warm_-prefixed
+        # of warm starts, which run no dense sweep. A spill or a net
+        # sweep means the tail's frontier cap was too small;
+        # tail_small_rounds well under tail_rounds means frontiers past
+        # the small expansion capacity, rounds that sort at tail_cap
+        # (docs/Monitor.md "Spans").
         # warm_cone_cells sizes the warm start's host-side cone walk.
         # prewarm_programs: programs prewarm_flap_programs ran (set-up).
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
             "dense_sweeps": 0, "tail_rounds": 0, "net_sweeps": 0,
-            "tail_spills": 0,
+            "tail_spills": 0, "tail_small_rounds": 0,
             "warm_tail_rounds": 0, "warm_net_sweeps": 0,
-            "warm_tail_spills": 0, "warm_cone_cells": 0,
+            "warm_tail_spills": 0, "warm_tail_small_rounds": 0,
+            "warm_cone_cells": 0,
             "prewarm_programs": 0,
         }
         # what prewarm_flap_programs has run its programs for: one key
@@ -563,6 +567,7 @@ class TpuSpfSolver:
         st[prefix + "tail_rounds"] += got["tail_rounds"]
         st[prefix + "net_sweeps"] += got["net_sweeps"]
         st[prefix + "tail_spills"] += got["spilled"]
+        st[prefix + "tail_small_rounds"] += got["tail_small_rounds"]
 
     def _solve_dist(self, csr, roots: np.ndarray) -> jax.Array:
         """[vp, B] distances from `roots` (a device array; the call

@@ -28,9 +28,16 @@ sweep — 8.4 M at the 100k benchmark):
      sweeps, then 94k, 83k, ..., 4.4k, 1.6k, 495, ...). Once the count
      is small, the kernel switches — inside the same jit, so the whole
      solve stays one dispatch and the frontier never visits the
-     host — to fixed-capacity compacted rounds: expand the changed
-     rows through the out-neighbor table, dedupe by sort, pull-relax
-     only those rows. If the expansion overflows the static capacity, a
+     host — to compacted rounds: expand the changed rows through the
+     out-neighbor table, dedupe by sort, pull-relax only those rows.
+     A round is sized by what it holds, from the live counts the
+     loop carries anyway: a frontier of at most `tail_cap // 32` ids
+     expands (and sorts) that many slots, any other the full
+     `tail_cap` (`_small_frontier`), and the expansion's rows are
+     relaxed a chunk of `tail_cap // 64` at a time, as many chunks as
+     hold the live ones — the same rows from the same distances
+     either way, so the sizes change the padding a round pays for and
+     nothing else. If the expansion overflows `tail_cap` itself, a
      spill flag routes the solve back to dense sweeps (exactness is
      never traded).
   4. **Chunked Gauss-Seidel dense sweeps** — each dense sweep relaxes
@@ -332,13 +339,45 @@ def pick_gs_chunks(vp: int) -> int:
 
 #: the loop counters a solve hands back, in the order of the packed
 #: buffer's int32 trailer (`rib_buffer_trailer`)
-SOLVE_COUNTERS = ("dense_sweeps", "tail_rounds", "net_sweeps", "spilled")
+SOLVE_COUNTERS = (
+    "dense_sweeps", "tail_rounds", "net_sweeps", "spilled",
+    "tail_small_rounds",
+)
 
 
-def _solve_counters(dense_sweeps, tail_rounds, net_sweeps, spilled):
+def _solve_counters(
+    dense_sweeps, tail_rounds, net_sweeps, spilled, tail_small_rounds
+):
     return jnp.stack([
-        dense_sweeps, tail_rounds, net_sweeps, spilled.astype(jnp.int32)
+        dense_sweeps, tail_rounds, net_sweeps, spilled.astype(jnp.int32),
+        tail_small_rounds,
     ]).astype(jnp.int32)
+
+
+# A tail round is sized twice by what it holds. Its expansion runs at
+# `tail_cap // _SMALL_FRONTIER_DIV` frontier slots where the frontier has
+# no more live ids than that (8192 -> 256), else at `tail_cap`: a
+# capacity of F slots sorts F * (Wout + 1) ids twice. Its rows are
+# relaxed `tail_cap // _RELAX_CHUNKS` at a time (8192 -> 128), as many
+# chunks as hold the live ones. (Values tried on the v5e: PERF.md §6,
+# PR 31.) Under _SMALL_FRONTIER_MIN slots there is one expansion capacity.
+_SMALL_FRONTIER_DIV = 32
+_SMALL_FRONTIER_MIN = 8
+_RELAX_CHUNKS = 64
+
+
+def _small_frontier(tail_cap: int) -> int | None:
+    """Frontier slots of a tail round's small expansion, or None where
+    `tail_cap` is too small to be worth two capacities."""
+    f_small = tail_cap // _SMALL_FRONTIER_DIV
+    return f_small if f_small >= _SMALL_FRONTIER_MIN else None
+
+
+def _relax_chunk(tail_cap: int) -> int:
+    """Rows a tail round relaxes at a time: a divisor of `tail_cap`."""
+    if tail_cap % _RELAX_CHUNKS:
+        return tail_cap
+    return tail_cap // _RELAX_CHUNKS
 
 
 def _split_solve(
@@ -347,9 +386,10 @@ def _split_solve(
     gs_chunks,
 ) -> tuple[jax.Array, jax.Array]:
     """The cold solve, traced into its jitted callers: distances [vp, B]
-    and the int32[4] loop counters `SOLVE_COUNTERS` (sweeps of phase 1,
+    and the int32[5] loop counters `SOLVE_COUNTERS` (sweeps of phase 1,
     rounds of the compacted tail, sweeps of the exactness net, whether
-    the tail spilled) — the `it` each loop carries anyway."""
+    the tail spilled, tail rounds expanded at the small capacity) — the
+    `it` each loop carries anyway."""
     vp = base_nbr.shape[0]
     b = roots.shape[0]
     w = base_nbr.shape[1]
@@ -402,15 +442,13 @@ def _split_solve(
     # (tail_threshold counts rows, tail_cap bounds the array): spill
     # straight to the dense safety net rather than silently truncating
     entry_spill = n_changed > tail_cap
-    dist, tail_rounds, net_sweeps, spilled = _tail_then_net(
+    dist, *tail_counters = _tail_then_net(
         dist, frontier, entry_spill, dense_sweep,
         base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
         over_base, over_ov, roots, has_overloads, tail_cap,
         tail_rounds_cap, pull_frontier=False,
     )
-    return dist, _solve_counters(
-        dense_sweeps, tail_rounds, net_sweeps, spilled
-    )
+    return dist, _solve_counters(dense_sweeps, *tail_counters)
 
 
 def _tail_then_net(
@@ -423,56 +461,94 @@ def _tail_then_net(
     fixpoint if the tail spilled or hit its round cap with work left:
     shared by the cold kernel (after its dense phase) and the warm-start
     kernel (from its seeds). Returns (dist, tail rounds, net sweeps,
-    spilled).
+    spilled, tail rounds whose expansion ran at the small capacity).
 
     `pull_frontier`: the rows whose pull could change are the
     out-neighbors of the frontier (decrease propagation) and, for the
     warm start, the frontier ITSELF — cone nodes must re-pull their
     boundary tentatives, their in-neighbors did not change; the cold
     tail's frontier is always "rows that just changed" and needs only
-    the former."""
+    the former.
+
+    `frontier` is `[tail_cap]`, live ids first (sorted), `dead` after:
+    "at most F live ids" is `frontier[F] == dead`, and then
+    `frontier[:F]` is the whole frontier."""
     vp = base_nbr.shape[0]
     dead = vp - 1
+    f_small = _small_frontier(tail_cap)
+    chunk = _relax_chunk(tail_cap)
 
     def cond_t(state):
-        _dist, frontier, spilled, it = state
+        _dist, frontier, spilled, it, _small_it = state
         return (frontier[0] != dead) & (~spilled) & (it < tail_rounds_cap)
 
-    def body_t(state):
-        dist, frontier, _sp, it = state
+    def expand(frontier, f_cap):
+        """The unique rows the first `f_cap` frontier slots reach:
+        (`[tail_cap]` ids, live ones first, and their count)."""
         with jax.named_scope("tail/expand"):
-            reach = out_nbr[frontier].reshape(-1)
+            fr = frontier[:f_cap]
+            reach = out_nbr[fr].reshape(-1)
             if pull_frontier:
-                reach = jnp.concatenate([reach, frontier])
+                reach = jnp.concatenate([reach, fr])
             exp = jnp.sort(reach)
             first = jnp.concatenate(
                 [jnp.ones((1,), bool), exp[1:] != exp[:-1]]
             ) & (exp != dead)
         with jax.named_scope("tail/compact"):
-            spilled = first.sum() > tail_cap
             rows = _compact_ids(
                 jnp.where(first, exp, vp), vp, tail_cap, dead
             )
-        with jax.named_scope("tail/relax"):
-            sub_new = _relax_rows(
-                dist, base_nbr[rows], base_wgt[rows],
-                over_base[rows] if has_overloads else None,
-                roots, has_overloads,
+        return rows, first.sum()
+
+    def body_t(state):
+        dist, frontier, _sp, it, small_it = state
+        if f_small is None:  # static ints: plain python branch
+            rows, n_rows = expand(frontier, tail_cap)
+            is_small = jnp.int32(0)
+        else:
+            # the frontier's live count picks the expansion's capacity:
+            # f_small or tail_cap slots, (Wout + 1) ids to sort for each
+            fits = frontier[f_small] == dead
+            rows, n_rows = jax.lax.cond(
+                fits,
+                lambda: expand(frontier, f_small),
+                lambda: expand(frontier, tail_cap),
             )
-            # overflow in-edges: the ov tables are tiny — relax them all
+            is_small = fits.astype(jnp.int32)
+        spilled = n_rows > tail_cap
+        with jax.named_scope("tail/relax"):
+            # the rows' live count picks how many chunks of them are
+            # relaxed; every chunk pulls from `dist` (Jacobi, as one
+            # full-width relax would), so the order changes nothing
+            def relax_chunk(i, carry):
+                dist2, changed_ids = carry
+                rc = jax.lax.dynamic_slice(rows, (i * chunk,), (chunk,))
+                new = _relax_rows(
+                    dist, base_nbr[rc], base_wgt[rc],
+                    over_base[rc] if has_overloads else None,
+                    roots, has_overloads,
+                )
+                changed = (new < dist[rc]).any(axis=1)
+                return dist2.at[rc].min(new), jax.lax.dynamic_update_slice(
+                    changed_ids, jnp.where(changed, rc, vp), (i * chunk,)
+                )
+
+            n_chunks = (jnp.minimum(n_rows, tail_cap) + chunk - 1) // chunk
+            dist2, changed_ids = jax.lax.fori_loop(
+                0, n_chunks, relax_chunk,
+                (dist, jnp.full((tail_cap,), vp, jnp.int32)),
+            )
+            # overflow in-edges: every row of the overflow table, every
+            # round (which of them a round reaches takes a device-side
+            # ov_pos; 8192 x 32 slots on the 10k fabric)
             ov_new = _relax_rows(
                 dist, ov_nbr, ov_wgt, over_ov, roots, has_overloads
             )
-            dist2 = dist.at[rows].min(sub_new)
             dist2 = dist2.at[ov_ids].min(ov_new)
         with jax.named_scope("tail/next_frontier"):
-            changed_rows = (dist2[rows] < dist[rows]).any(axis=1)
             ov_changed = (dist2[ov_ids] < dist[ov_ids]).any(axis=1)
             both = jnp.concatenate(
-                [
-                    jnp.where(changed_rows, rows, vp),
-                    jnp.where(ov_changed, ov_ids, vp),
-                ]
+                [changed_ids, jnp.where(ov_changed, ov_ids, vp)]
             )
             srt = jnp.sort(both)
             firstb = jnp.concatenate(
@@ -485,10 +561,11 @@ def _tail_then_net(
             nf = _compact_ids(
                 jnp.where(firstb, srt, vp), vp, tail_cap, dead
             )
-        return dist2, nf, spilled, it + 1
+        return dist2, nf, spilled, it + 1, small_it + is_small
 
-    dist, frontier, spilled, tail_rounds = jax.lax.while_loop(
-        cond_t, body_t, (dist, frontier, entry_spill, jnp.int32(0))
+    dist, frontier, spilled, tail_rounds, small_rounds = jax.lax.while_loop(
+        cond_t, body_t,
+        (dist, frontier, entry_spill, jnp.int32(0), jnp.int32(0)),
     )
 
     def cond_d(state):
@@ -505,7 +582,7 @@ def _tail_then_net(
             cond_d, body_d,
             (dist, spilled | (frontier[0] != dead), jnp.int32(0)),
         )
-    return dist, tail_rounds, net_sweeps, spilled
+    return dist, tail_rounds, net_sweeps, spilled, small_rounds
 
 
 def _pack_rib(dist, counters, nbr_metric, nbr_ids, nbr_over, my_id, with_lfa):
@@ -601,11 +678,11 @@ def batched_sssp_split_rib(
     packed:
 
         buf = [ d_root as 4·Vp uint8 | packbits(fh) | packbits(lfa)?
-              | the loops' counters, 4 int32 ]
+              | the loops' counters, 5 int32 ]
 
     ≈ 0.8 MB instead of ~16 MB. The full distance matrix is returned as
     a device array and transferred only if a caller materializes it
-    (KSP oracle checks, tests). The 16-byte trailer rides the same
+    (KSP oracle checks, tests). The 20-byte trailer rides the same
     transfer: the sweep counts reach the host with no sync of their own.
     """
     dist, counters = _split_solve(
@@ -682,15 +759,13 @@ def batched_sssp_split_warm_rib(
         jnp.where(seed_mask, iota, vp), vp, tail_cap, dead
     )
     entry_spill = seed_mask.sum() > tail_cap
-    dist, tail_rounds, net_sweeps, spilled = _tail_then_net(
+    dist, *tail_counters = _tail_then_net(
         dist0, frontier, entry_spill, dense_sweep,
         base_nbr, base_wgt, ov_ids, ov_nbr, ov_wgt, out_nbr,
         over_base, over_ov, roots, has_overloads, tail_cap,
         tail_rounds_cap, pull_frontier=True,
     )
-    counters = _solve_counters(
-        jnp.int32(0), tail_rounds, net_sweeps, spilled
-    )
+    counters = _solve_counters(jnp.int32(0), *tail_counters)
     return dist, _pack_rib(
         dist, counters, nbr_metric, nbr_ids, nbr_over, None, False
     )
@@ -734,7 +809,7 @@ def unpack_rib_buffer(
         [ d_root: vp int32 as 4·vp bytes
         | fh:     (b-1) rows × vp/8 packbits bytes
         | lfa:    (b-1) rows × vp/8 packbits bytes, iff with_lfa
-        | the loop counters, 16 bytes: see `rib_buffer_trailer` ]
+        | the loop counters, 20 bytes: see `rib_buffer_trailer` ]
 
     Returns (d_root int32 [vp], fh bool [b-1, vp], lfa or None).
     """
@@ -753,11 +828,12 @@ def unpack_rib_buffer(
 
 
 def rib_buffer_trailer(buf: np.ndarray) -> dict[str, int]:
-    """The kernel's own loop counters from the packed buffer's last 16
-    bytes, `SOLVE_COUNTERS` as four int32 after the last section (so the
+    """The kernel's own loop counters from the packed buffer's last 20
+    bytes, `SOLVE_COUNTERS` as five int32 after the last section (so the
     offsets `unpack_rib_buffer` reads never moved): sweeps of the dense
     phase, rounds of the compacted tail, sweeps of the exactness net,
-    and whether the tail spilled into it."""
+    whether the tail spilled into it, and how many of the tail's rounds
+    expanded their frontier at the small capacity (`_small_frontier`)."""
     _check_byte_order()
     tail = buf[-4 * len(SOLVE_COUNTERS):].view(np.int32)
     return dict(zip(SOLVE_COUNTERS, tail.tolist()))

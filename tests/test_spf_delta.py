@@ -539,6 +539,12 @@ def test_last_breakdown_ms_is_a_view_of_the_rebuilds_span_record(backend):
         assert st["warm_cone_cells"] >= 1
         assert d._tpu.dev_cache_stats["scatter_calls"] >= 2
         assert d.counters.get("decision.spf.warm_tail_rounds") >= 1
+        # a frontier this small never leaves the small capacity; the
+        # counters reach decision.spf.* by the loop over every key
+        assert st["warm_tail_small_rounds"] == st["warm_tail_rounds"]
+        assert 0 <= st["tail_small_rounds"] <= st["tail_rounds"]
+        for k in ("warm_tail_small_rounds", "tail_small_rounds"):
+            assert d.counters.get(f"decision.spf.{k}") == st[k]
         assert d.counters.get("decision.dev_cache.scatter_calls") >= 2
         # the cold call's six phases; the three of the election feed
         # the stats they always fed, the others make no stat of theirs

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from openr_tpu.common.constants import DIST_INF
 from openr_tpu.ops.spf import (
     batched_sssp_dense,
     build_dense_tables,
@@ -22,12 +23,26 @@ from openr_tpu.ops.spf import (
     pad_batch,
 )
 from openr_tpu.ops.spf_split import (
+    _small_frontier,
     batched_sssp_split,
+    batched_sssp_split_warm_rib,
     build_split_tables,
     pick_base_width,
+    rib_buffer_trailer,
     tight_nodes,
 )
 from openr_tpu.utils import topogen
+
+
+def _edge_arrays(edges, vp, ep):
+    """(src, dst, metric) triples as the dst-sorted arrays the table
+    builders read, padded to `ep` slots with dead INF edges."""
+    pad = ep - len(edges)
+    es = np.array([e[0] for e in edges] + [0] * pad, np.int32)
+    ed = np.array([e[1] for e in edges] + [vp - 1] * pad, np.int32)
+    em = np.array([e[2] for e in edges] + [DIST_INF] * pad, np.int32)
+    order = np.argsort(ed, kind="stable")
+    return es[order], ed[order], em[order]
 
 
 def _solve_both(es, ed, em, vp, n, roots, over=None, **tail_kw):
@@ -124,25 +139,132 @@ def test_split_entry_spill_exceeding_tail_cap():
         edges += [(0, i, 1 + i % 7), (i, 0, 1 + i % 7)]
     for i in range(100, n):
         edges += [(i - 1, i, 3), (i, i - 1, 3)]
-    src = np.array([e[0] for e in edges], np.int32)
-    dst = np.array([e[1] for e in edges], np.int32)
-    met = np.array([e[2] for e in edges], np.int32)
-    from openr_tpu.common.constants import DIST_INF
-
     vp = 128
-    ep = 512
-    pad = ep - len(src)
-    es = np.concatenate([src, np.zeros(pad, np.int32)])
-    ed = np.concatenate([dst, np.full(pad, vp - 1, np.int32)])
-    em = np.concatenate([met, np.full(pad, DIST_INF, np.int32)])
-    order = np.argsort(ed, kind="stable")
-    es, ed, em = es[order], ed[order], em[order]
+    es, ed, em = _edge_arrays(edges, vp, 512)
     roots = np.zeros(8, dtype=np.int32)
     ref, got = _solve_both(
         es, ed, em, vp, n, roots,
         # threshold bigger than cap: phase 1 exits immediately with a
         # ~99-row changed set that cannot fit the 32-slot tail
         tail_threshold=n, tail_cap=32, tail_rounds_cap=64,
+    )
+    np.testing.assert_array_equal(ref, got)
+
+
+# ---- a tail round sized by its live counts (`_small_frontier`) ------------
+# A ring of RING nodes (out-degree 2) with two hubs hung on it: HUB_A on 40
+# ring nodes, HUB_B on 300. At tail_cap 256 the small expansion has 8
+# frontier slots and a relaxed chunk 4 rows: k ring seeds expand to 3k rows,
+# HUB_A to 41 (the last chunk partly filled), HUB_B to 301 (past tail_cap).
+RING, HUB_A, HUB_B = 1000, 1000, 1001
+TIER_CAP = 256
+
+
+def _ring_with_hubs(overloads):
+    """(es, ed, em, vp, n, node_overloaded, roots)"""
+    rng = np.random.default_rng(31)
+    edges = []
+    for i in range(RING):
+        m = int(rng.integers(1, 6))
+        edges += [(i, (i + 1) % RING, m), ((i + 1) % RING, i, m)]
+    for hub, spokes in ((HUB_A, range(500, 540)), (HUB_B, range(600, 900))):
+        for i in spokes:
+            edges += [(hub, i, 7), (i, hub, 7)]
+    n = RING + 2
+    vp = tight_nodes(n)
+    over = np.zeros(vp, bool)
+    if overloads:
+        over[[3, 255, 520, 700]] = True
+    roots = np.array([0, 450, 950, 3, 605, 1, 2, 4], np.int32)
+    return *_edge_arrays(edges, vp, pad_batch(len(edges))), vp, n, over, roots
+
+
+def _ring_seeds(k):
+    """k ring nodes of out-degree 2, no two adjacent, none a root."""
+    return [10 + 10 * i for i in range(k)]
+
+
+def test_small_frontier_of_the_capacities_the_cases_use():
+    assert _small_frontier(8192) == 256
+    assert _small_frontier(TIER_CAP) == 8
+    assert _small_frontier(255) is None and _small_frontier(32) is None
+
+
+@pytest.mark.parametrize(
+    "seeds,tail_cap,overloads,rounds,small_rounds,spilled",
+    [
+        # frontier of F_s - 1, F_s, F_s + 1 live ids: the last is past
+        # the small capacity by one id, both its rounds expand at tail_cap
+        pytest.param(_ring_seeds(7), TIER_CAP, False, 2, 2, 0, id="F_s-1"),
+        pytest.param(_ring_seeds(8), TIER_CAP, False, 2, 2, 0, id="F_s"),
+        pytest.param(_ring_seeds(9), TIER_CAP, False, 2, 0, 0, id="F_s+1"),
+        # one live id and 41 rows: the last chunk is partly filled
+        pytest.param([HUB_A], TIER_CAP, False, 2, 2, 0, id="hub-41-rows"),
+        # one live id whose expansion passes tail_cap: the only spill
+        pytest.param([HUB_B], TIER_CAP, False, 1, 1, 1, id="over-tail_cap"),
+        pytest.param([HUB_B], 512, False, 2, 2, 0, id="hub-301-rows"),
+        pytest.param(
+            _ring_seeds(20) + [HUB_B], 512, False, 2, 0, 0,
+            id="full-expansion-361-rows",
+        ),
+        # a tail_cap too small for two expansion capacities
+        pytest.param(_ring_seeds(7), 32, False, 2, 0, 0, id="one-capacity"),
+        pytest.param(_ring_seeds(8), TIER_CAP, True, 2, 2, 0, id="overloads"),
+        pytest.param(
+            _ring_seeds(5) + [HUB_A], TIER_CAP, True, 2, 2, 0,
+            id="overloads-hub",
+        ),
+    ],
+)
+def test_tail_sizes_warm_entry(
+    seeds, tail_cap, overloads, rounds, small_rounds, spilled
+):
+    """The warm entry from seeds of each size: the true distances with
+    the seeds' rows at INF are upper bounds, so the kernel must land on
+    `batched_sssp_dense`'s distances at whichever sizes each round ran,
+    and the trailer says which expansion it took."""
+    es, ed, em, vp, n, over, roots = _ring_with_hubs(overloads)
+    nbr, wgt = build_dense_tables(es, ed, em, vp)
+    ref = np.asarray(batched_sssp_dense(
+        jnp.asarray(nbr), jnp.asarray(wgt), jnp.asarray(over),
+        jnp.asarray(roots), has_overloads=overloads,
+    ))
+    t = build_split_tables(es, ed, em, n)
+    assert t["vp"] == vp
+    dist0 = ref.copy()
+    dist0[seeds] = DIST_INF
+    seed_mask = np.zeros(t["vp"], bool)
+    seed_mask[seeds] = True
+    b = len(roots)
+    dist, packed = batched_sssp_split_warm_rib(
+        jnp.asarray(t["base_nbr"]), jnp.asarray(t["base_wgt"]),
+        jnp.asarray(t["ov_ids"]), jnp.asarray(t["ov_nbr"]),
+        jnp.asarray(t["ov_wgt"]), jnp.asarray(t["out_nbr"]),
+        jnp.asarray(over), jnp.asarray(roots),
+        jnp.ones(b - 1, jnp.int32), jnp.asarray(roots[1:]),
+        jnp.zeros(b - 1, bool), jnp.asarray(dist0), jnp.asarray(seed_mask),
+        has_overloads=overloads, tail_cap=tail_cap,
+    )
+    np.testing.assert_array_equal(np.asarray(dist), ref)
+    got = rib_buffer_trailer(np.asarray(packed))
+    assert got["dense_sweeps"] == 0
+    assert got["spilled"] == spilled
+    assert (got["net_sweeps"] >= 1) == bool(spilled)
+    assert got["tail_rounds"] == rounds
+    assert got["tail_small_rounds"] == small_rounds
+
+
+@pytest.mark.parametrize("tail_cap", [32, TIER_CAP, 8192])
+@pytest.mark.parametrize("overloads", [False, True])
+def test_tail_sizes_cold_entry(tail_cap, overloads):
+    """The cold entry straight into the tail (one dense sweep): a wave
+    of two or three rows walks the ring and meets the hubs on its way,
+    so one solve takes rounds of either expansion capacity and of one or
+    several chunks (or spills at 32)."""
+    es, ed, em, vp, n, over, roots = _ring_with_hubs(overloads)
+    ref, got = _solve_both(
+        es, ed, em, vp, n, roots, over=over,
+        tail_threshold=n, tail_cap=tail_cap, tail_rounds_cap=2048,
     )
     np.testing.assert_array_equal(ref, got)
 
@@ -154,20 +276,8 @@ def test_split_disconnected_and_line():
     for i in range(n - 1):
         edges.append((i, i + 1, 3))
         edges.append((i + 1, i, 3))
-    src = np.array([e[0] for e in edges], dtype=np.int32)
-    dst = np.array([e[1] for e in edges], dtype=np.int32)
-    met = np.array([e[2] for e in edges], dtype=np.int32)
-    order = np.argsort(dst, kind="stable")
-    src, dst, met = src[order], dst[order], met[order]
     vp = 128
-    from openr_tpu.common.constants import DIST_INF
-
-    pad = 256 - len(src)
-    es = np.concatenate([src, np.zeros(pad, np.int32)])
-    ed = np.concatenate([dst, np.full(pad, vp - 1, np.int32)])
-    em = np.concatenate([met, np.full(pad, DIST_INF, np.int32)])
-    order = np.argsort(ed, kind="stable")
-    es, ed, em = es[order], ed[order], em[order]
+    es, ed, em = _edge_arrays(edges, vp, 256)
     roots = np.zeros(8, dtype=np.int32)
     ref, got = _solve_both(es, ed, em, vp, n, roots)
     np.testing.assert_array_equal(ref, got)

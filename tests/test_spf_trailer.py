@@ -4,6 +4,9 @@ loops, and the buffer's other sections unmoved by it."""
 
 from __future__ import annotations
 
+import json
+from pathlib import Path
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,6 +15,7 @@ from openr_tpu.ops.spf import first_hop_matrix, lfa_matrix, pad_batch
 from openr_tpu.ops.spf_split import (
     INF_DIST,
     SOLVE_COUNTERS,
+    _small_frontier,
     batched_sssp_split_rib,
     batched_sssp_split_warm_rib,
     build_split_tables,
@@ -33,6 +37,8 @@ class Replay:
         self.dead = self.vp - 1
         self.cap = tail_cap
         self.rounds_cap = tail_rounds_cap
+        # a round's expansion is the small one by its live count alone
+        self.small = _small_frontier(tail_cap) or -1
 
     @staticmethod
     def relax(dist, nbr, wgt):
@@ -65,7 +71,7 @@ class Replay:
 
     def tail_then_net(self, dist, frontier, spilled, pull_frontier):
         t = self.t
-        rounds = 0
+        rounds = small_rounds = 0
         while len(frontier) and not spilled and rounds < self.rounds_cap:
             reach = t["out_nbr"][frontier].reshape(-1)
             if pull_frontier:
@@ -73,6 +79,7 @@ class Replay:
             exp = np.unique(reach)
             exp = exp[exp != self.dead]
             spilled = len(exp) > self.cap
+            small_rounds += len(frontier) <= self.small
             rows = exp[: self.cap]
             dist2 = dist.copy()
             np.minimum.at(
@@ -94,7 +101,7 @@ class Replay:
         while changed and net < self.vp:
             new = self.dense_sweep(dist)
             changed, dist, net = bool((new < dist).any()), new, net + 1
-        return dist, rounds, net, int(spilled)
+        return dist, rounds, net, int(spilled), small_rounds
 
 
 def er_case(n=600, deg=6, seed=3, max_metric=32, batch=8):
@@ -128,7 +135,7 @@ def cold(t, roots, nbr_ids, nbr_metric, nbr_over, **kw):
 @pytest.mark.parametrize(
     "tail_threshold,tail_cap,spills",
     [(64, 1024, False), (16, 1024, False), (10_000, 1024, False),
-     (64, 8, True)],
+     (64, 8, True), (64, 256, True), (16, 256, False), (64, 8192, False)],
 )
 def test_cold_trailer_equals_a_numpy_replay(tail_threshold, tail_cap, spills):
     t, roots, nbr_ids, nbr_metric, nbr_over, _g = er_case()
@@ -140,14 +147,19 @@ def test_cold_trailer_equals_a_numpy_replay(tail_threshold, tail_cap, spills):
     assert tuple(got) == SOLVE_COUNTERS
     rp = Replay(t, tail_cap, 64)
     d0, frontier, spilled, sweeps = rp.cold_start(roots, tail_threshold)
-    want_dist, rounds, net, spilled = rp.tail_then_net(
+    want_dist, rounds, net, spilled, small_rounds = rp.tail_then_net(
         d0, frontier, spilled, pull_frontier=False
     )
     np.testing.assert_array_equal(dist, want_dist)
     assert got == {
         "dense_sweeps": sweeps, "tail_rounds": rounds, "net_sweeps": net,
-        "spilled": spilled,
+        "spilled": spilled, "tail_small_rounds": small_rounds,
     }
+    assert got["tail_small_rounds"] <= got["tail_rounds"]
+    if tail_cap == 8:  # too small for two expansion capacities
+        assert got["tail_small_rounds"] == 0
+    if (tail_threshold, tail_cap) == (64, 8192):  # of either capacity
+        assert 0 < got["tail_small_rounds"] < got["tail_rounds"]
     assert bool(got["spilled"]) is spills
     assert got["dense_sweeps"] >= 1
     if spills:
@@ -166,7 +178,7 @@ def test_unpack_is_unmoved_by_the_trailer_cold(with_lfa):
     )
     sections = 2 if with_lfa else 1
     assert buf.dtype == np.uint8
-    assert buf.size == 4 * vp + sections * (b - 1) * (vp // 8) + 16
+    assert buf.size == 4 * vp + sections * (b - 1) * (vp // 8) + 20
     d_root, fh, lfa = unpack_rib_buffer(buf, vp, b, with_lfa)
     assert d_root.tobytes() == dist[:, 0].astype(np.int32).tobytes()
     want_fh = np.asarray(first_hop_matrix(
@@ -207,21 +219,25 @@ def test_warm_trailer_equals_a_numpy_replay_and_the_cold_result():
     )
     dist, buf = np.asarray(dist), np.asarray(packed)
     rp = Replay(t, 1024, 64)
-    want_dist, rounds, net, spilled = rp.tail_then_net(
+    want_dist, rounds, net, spilled, small_rounds = rp.tail_then_net(
         old_dist.astype(np.int64), np.nonzero(seed)[0], False,
         pull_frontier=True,
     )
     np.testing.assert_array_equal(dist, want_dist)
-    assert rib_buffer_trailer(buf) == {
+    got = rib_buffer_trailer(buf)
+    assert tuple(got) == SOLVE_COUNTERS
+    assert got == {
         "dense_sweeps": 0, "tail_rounds": rounds, "net_sweeps": net,
-        "spilled": 0,
+        "spilled": 0, "tail_small_rounds": small_rounds,
     }
     assert rounds >= 1 and spilled == 0
+    # one seed: the first round expands at the small capacity
+    assert 1 <= small_rounds <= rounds
     # same fixpoint, same bytes before the trailer as a cold solve of
     # the new graph
     cold_dist, cold_buf = cold(t, roots, nbr_ids, nbr_metric, nbr_over)
     np.testing.assert_array_equal(dist, cold_dist)
-    assert buf[:-16].tobytes() == cold_buf[:-16].tobytes()
+    assert buf[:-20].tobytes() == cold_buf[:-20].tobytes()
     d_root, fh, _ = unpack_rib_buffer(buf, t["vp"], len(roots), False)
     assert d_root.tobytes() == dist[:, 0].tobytes()
     assert fh.shape == (len(roots) - 1, t["vp"])
@@ -238,12 +254,51 @@ def test_the_solver_adds_the_trailer_to_its_kernel_stats():
     st = solver.spf_kernel_stats
     assert st["dense_sweeps"] >= 1 and st["tail_spills"] == 0
     first = st["dense_sweeps"] + st["tail_rounds"]
+    small = st["tail_small_rounds"]
+    assert 0 <= small <= st["tail_rounds"]
     solver.compute_routes(ls, ps, "node-0")
     assert st["dense_sweeps"] + st["tail_rounds"] == 2 * first
+    assert st["tail_small_rounds"] == 2 * small
     assert st["warm_tail_rounds"] == 0  # no warm start ran
+    assert st["warm_tail_small_rounds"] == 0
     # the six phases of the call, from its span record
     assert set(solver.last_phase_ms) == {
         "prepare", "solve", "unpack", "election", "assembly", "mpls"
     }
     assert all(v >= 0.0 for v in solver.last_phase_ms.values())
     assert solver.last_phase_ms["solve"] > 0.0
+
+
+@pytest.mark.parametrize(
+    "name,counter,moves",
+    [
+        ("spf_warm_small_rounds", "decision.spf.warm_tail_small_rounds",
+         "event_to_fib_p50_ms"),
+        ("spf_cold_small_tail_rounds", "solver.tail_small_rounds",
+         "full_rib_ms"),
+    ],
+)
+def test_the_small_round_metrics_read_the_counters_the_solver_keeps(
+    name, counter, moves
+):
+    """The benchmark's two metrics name counters that exist: the key of
+    `spf_kernel_stats` behind the `decision.spf.` / `solver.` prefix the
+    exporters put before every key."""
+    from openr_tpu.decision.spf_backend import TpuSpfSolver
+
+    root = Path(__file__).resolve().parents[1]
+    spec = json.loads(
+        (root / "perfbench" / "layer_metrics" / f"{name}.json").read_text()
+    )
+    assert spec["name"] == name and spec["reader"] == "counter_per_event"
+    assert spec["args"] == {"counter": counter}
+    assert spec["layer"] == "SPF kernel" and spec["moves"] == moves
+    key = counter.rsplit(".", 1)[1]
+    assert key in TpuSpfSolver(native_rib="off").spf_kernel_stats
+    entry = [
+        m for m in json.loads((root / "BENCHMARK.json").read_text())["per_layer"]
+        if m["name"] == name
+    ]
+    assert len(entry) == 1 and entry[0]["better"] == "higher"
+    assert entry[0]["source"] == "program_counter"
+    assert entry[0]["moves"] == moves and entry[0]["layer"] == "SPF kernel"
