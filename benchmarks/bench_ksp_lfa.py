@@ -9,6 +9,10 @@ KSP2 and LFA produce real alternates):
 Run: python benchmarks/bench_ksp_lfa.py [--rings 8] [--ring-size 16]
      [--ksp-frac 0.1] [--backend cpu]
 Prints one JSON line (same contract as bench.py).
+
+The measured form of config 4 is now the benchmark's configuration
+`backbone_ksp` (`perfbench/`, PR 32), which the driver runs on the chip;
+this script stays as one of D2's four older benchmarks.
 """
 
 from __future__ import annotations

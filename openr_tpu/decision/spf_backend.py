@@ -316,6 +316,9 @@ class TpuSpfSolver:
         # (docs/Monitor.md "Spans").
         # warm_cone_cells sizes the warm start's host-side cone walk.
         # prewarm_programs: programs prewarm_flap_programs ran (set-up).
+        # ksp_jobs / ksp_chunks / ksp_rounds: KSP prefixes handed to
+        # _ksp_batch, kernel calls they were cut into, and the rounds
+        # (k_eff) those calls were dispatched with, a chunk.
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
@@ -325,6 +328,7 @@ class TpuSpfSolver:
             "warm_tail_spills": 0, "warm_tail_small_rounds": 0,
             "warm_cone_cells": 0,
             "prewarm_programs": 0,
+            "ksp_jobs": 0, "ksp_chunks": 0, "ksp_rounds": 0,
         }
         # what prewarm_flap_programs has run its programs for: one key
         # per (table shapes, batch, has_overloads, gs_chunks), i.e. per
@@ -1027,7 +1031,12 @@ class TpuSpfSolver:
         itself compiles nothing: `_scatter_set` on `base_wgt`, on
         `ov_wgt` where the base has overflow rows (both at the patch
         bucket one link event pads to), on the [vp, B] distance matrix
-        at the first cone tier, and the warm kernel. Which of them an
+        at the first cone tier, and the warm kernel; where the area
+        holds a KSP prefix (its base has the dense tables), `_scatter_set`
+        on the dense `wgt` too, which every patch is scattered into as
+        well, and the KSP kernel at the batch sizes of the area's last
+        KSP batch, which every event runs again whole (`_prewarm_ksp`).
+        Which of them an
         event meets depends on the link it draws (a raise that is tight
         in some column walks a cone; a patch lands in the overflow
         table only at a node whose in-degree passes the base width), so
@@ -1062,7 +1071,15 @@ class TpuSpfSolver:
             "base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt",
             "out_nbr", "over",
         )
-        key = (*(dev[t].shape for t in tables), bb, has_over, gs)
+        # an area with a KSP prefix also holds the dense tables, which
+        # every patch is scattered into as well, and re-runs its whole
+        # KSP batch on every event
+        dense = cache["sets"].get("dense")
+        ksp = cache["host"].get("ksp") if dense is not None else None
+        key = (
+            *(dev[t].shape for t in tables), bb, has_over, gs,
+            None if dense is None else dense["wgt"].shape, ksp,
+        )
         if key in self._prewarmed:
             return 0
         self._prewarmed.add(key)
@@ -1077,6 +1094,15 @@ class TpuSpfSolver:
             if (cache["host"]["split"]["ov_pos"] >= 0).any():
                 _scatter_set(dev["ov_wgt"], (cols, cols), vals)
                 programs += 1
+            if dense is not None:
+                _scatter_set(
+                    dense["wgt"],
+                    (np.full(n_patch, csr.padded_nodes - 1, np.int32), cols),
+                    vals,
+                )
+                programs += 1
+            if ksp is not None:
+                programs += self._prewarm_ksp(dense, ksp)
             n_cone = _WARM_SCATTER_TIERS[0]
             _scatter_set(
                 dist._dev,
@@ -1108,6 +1134,26 @@ class TpuSpfSolver:
             )
         self.spf_kernel_stats["prewarm_programs"] += programs
         return programs
+
+    @staticmethod
+    def _prewarm_ksp(dense: dict, ksp: tuple) -> int:
+        """The KSP kernel at each batch size the area's last KSP batch
+        was cut into (`_ksp_batch` noted them), every job's destination
+        the root itself: no job starts a walk, so the call ends after its
+        first round and bans nothing, whatever the distances and the
+        mask hold. Returns the programs run."""
+        from openr_tpu.ops.ksp import ksp_edge_disjoint_dense
+
+        my_id, k_eff, max_hops, batches = ksp
+        nbr = dense["nbr"]
+        for b in batches:
+            ksp_edge_disjoint_dense(
+                nbr, dense["wgt"], jnp.zeros(nbr.shape, bool),
+                jnp.int32(my_id), jnp.full(b, my_id, jnp.int32),
+                k=k_eff, max_hops=max_hops,
+                dist0=jnp.full(nbr.shape[0], INF_DIST, jnp.int32),
+            )
+        return len(batches)
 
     def warm_compute_routes(
         self,
@@ -1966,6 +2012,15 @@ class TpuSpfSolver:
         # exit already stops one probe round past the true bound, so a
         # loose bucket costs at most that single extra round.
         k_eff = min(self.ksp_k, 1 << (bound - 1).bit_length())
+        # what names this batch's kernel programs, for
+        # prewarm_flap_programs: the batch sizes its chunks pad to
+        self._dev[csr.base_version]["host"]["ksp"] = (
+            my_id, k_eff, max_hops,
+            tuple(sorted({
+                pad_batch(min(chunk, len(jobs) - start))
+                for start in range(0, len(jobs), chunk)
+            })),
+        )
         # round 1 is ban-free and identical for every job — feed the
         # production solve's own root distances (same overload
         # semantics; oracle-equality tested) so the kernel skips one
@@ -2000,25 +2055,39 @@ class TpuSpfSolver:
             b = pad_batch(len(sub))
             dsts = np.full(b, my_id, dtype=np.int32)  # padding: dest==root
             dsts[: len(sub)] = sub
-            costs, paths, _hops = ksp_edge_disjoint_dense(
-                d_nbr,
-                d_wgt,
-                blocked,
-                jnp.int32(my_id),
-                jnp.asarray(dsts),
-                k=k_eff,
-                max_hops=max_hops,
-                dist0=dist0_dev,
-            )
-            costs, paths = np.asarray(costs), np.asarray(paths)
-            for j in range(len(sub)):
-                prefix, reachable, best_nodes = jobs[start + j]
-                host_paths = paths_to_host(costs, paths, csr.node_names, j)
-                entry = ksp_route_from_paths(
-                    ls, my_node, prefix, reachable, best_nodes, host_paths
+            with profiling.annotate("spf:ksp_solve"):
+                costs, paths, _hops = ksp_edge_disjoint_dense(
+                    d_nbr,
+                    d_wgt,
+                    blocked,
+                    jnp.int32(my_id),
+                    jnp.asarray(dsts),
+                    k=k_eff,
+                    max_hops=max_hops,
+                    dist0=dist0_dev,
                 )
-                if entry is not None:
-                    out[prefix] = entry
+                # the small output's transfer is what waits for the
+                # kernel: results ready, with no timing primitive here
+                costs = np.asarray(costs)
+                compile_ledger.record_transfer(costs.nbytes)
+            with profiling.annotate("spf:ksp_fetch"):
+                paths = np.asarray(paths)
+                compile_ledger.record_transfer(paths.nbytes)
+            with profiling.annotate("spf:ksp_decode"):
+                for j in range(len(sub)):
+                    prefix, reachable, best_nodes = jobs[start + j]
+                    host_paths = paths_to_host(
+                        costs, paths, csr.node_names, j
+                    )
+                    entry = ksp_route_from_paths(
+                        ls, my_node, prefix, reachable, best_nodes,
+                        host_paths,
+                    )
+                    if entry is not None:
+                        out[prefix] = entry
+            self.spf_kernel_stats["ksp_chunks"] += 1
+            self.spf_kernel_stats["ksp_rounds"] += k_eff
+        self.spf_kernel_stats["ksp_jobs"] += len(jobs)
 
     @staticmethod
     def _mk_backup_nexthops(

@@ -270,6 +270,9 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:rib_unicast",           #         unicast RibEntries
     "spf:rib_mpls",              #         node-label routes
     "spf:ksp",                   #         KSP prefixes' batched paths
+    "spf:ksp_solve",             #           a chunk: dispatch → costs on the host
+    "spf:ksp_fetch",             #           a chunk: paths → host
+    "spf:ksp_decode",            #           a chunk: paths → RibEntries
     "spf:dist_mirror",           #       warm: [vp, B] matrix → host
     "spf:warm_cone",             #       warm: host cone walk
     "spf:warm_scatter",          #       warm: cone → INF, compiled scatter
