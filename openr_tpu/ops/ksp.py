@@ -304,19 +304,31 @@ ksp_edge_disjoint_dense.cache_size = (
 
 
 def paths_to_host(
-    costs: np.ndarray,  # [k, B]
+    costs,  # [k, B] ints: the fetched array or its .tolist()
     paths: np.ndarray,  # [k, B, L] walk order (dest..root), -1 padded
+    hops,  # [k, B] ints, like costs: the kernel's third output
     node_names: list[str],
     job: int,
 ) -> list[tuple[int, list[str]]]:
     """Device output → the oracle's [(cost, [root..dest names]), ...]
-    sorted by (cost, path) exactly like k_edge_disjoint_paths."""
+    sorted by (cost, path) exactly like k_edge_disjoint_paths.
+
+    Reads the ``hops + 1`` nodes the kernel found and none of the
+    padding behind them. The kernel's ``walk`` writes a path's nodes
+    contiguously from slot 0, sets ``cost = INF_DIST`` exactly where a
+    round failed (the path is then all -1 and ``hops`` 0), and ``hops``
+    is the count of non-negative slots less one: the cost skips the
+    failed rounds and the prefix is the filter
+    (tests/test_ksp_kernel.py pins that contract). A caller with many
+    jobs of one chunk hands ``costs`` and ``hops`` over as lists, turned
+    into Python ints once."""
+    inf = int(INF_DIST)
     out: list[tuple[int, list[str]]] = []
-    for i in range(costs.shape[0]):
-        c = int(costs[i, job])
-        if c >= int(INF_DIST):
+    for cost_row, hop_row, path_rows in zip(costs, hops, paths):
+        c = int(cost_row[job])
+        if c >= inf:
             continue
-        ids = [int(x) for x in paths[i, job] if x >= 0]
+        ids = path_rows[job, : hop_row[job] + 1].tolist()
         ids.reverse()  # walk order is dest→root
         out.append((c, [node_names[n] for n in ids]))
     out.sort(key=lambda cp: (cp[0], cp[1]))

@@ -7,8 +7,9 @@ nothing of the program), and what the deployment added to the program:
 
   * the spans `spf:ksp_solve`, `spf:ksp_fetch`, `spf:ksp_decode` inside
     `spf:ksp`, and the counters `decision.spf.ksp_jobs`, `.ksp_chunks`,
-    `.ksp_rounds`;
-  * `costs` and `paths` fetched through the counted transfer seam;
+    `.ksp_rounds`, and (PR 33) `.ksp_path_nodes`: the decode reads the
+    hops the kernel found, not the padded width of `paths`;
+  * `costs`, `paths` and `hops` fetched through the counted transfer seam;
   * the pre-warm of the dense tables' scatter and of the KSP kernel.
 
 One story of a dozen circuit cost-outs and restores, then four ring-link
@@ -38,6 +39,7 @@ from openr_tpu.fib import Fib, MockFibHandler
 from openr_tpu.fib.fib import CLIENT_ID_OPENR
 from openr_tpu.messaging import ReplicateQueue
 from openr_tpu.monitor import Counters, compile_ledger, names, perf
+from openr_tpu.ops import ksp as ksp_ops
 from openr_tpu.types.kvstore import Publication, Value
 from openr_tpu.types.serde import to_wire
 from perfbench import compare, topo
@@ -133,10 +135,21 @@ async def run_story() -> dict:
     def stat(name):
         return dec._tpu.spf_kernel_stats[name]
 
+    # the nodes of the paths the decode handed to the route builder
+    decoded = {"nodes": 0}
+    real_paths_to_host = ksp_ops.paths_to_host
+
+    def counting_paths_to_host(*args):
+        host_paths = real_paths_to_host(*args)
+        decoded["nodes"] += sum(len(path) for _cost, path in host_paths)
+        return host_paths
+
     out: dict = {"events": [], "graph": g}
     jax.clear_caches()  # so that every program this story needs compiles in it
     await dec.start()
     await fib.start()
+    patch = pytest.MonkeyPatch()
+    patch.setattr(ksp_ops, "paths_to_host", counting_paths_to_host)
     try:
         for key, db in adj_dbs.items():
             area, name = areas[key[0]], db.this_node_name
@@ -156,6 +169,7 @@ async def run_story() -> dict:
         out["first_breakdown"] = dict(dec.last_breakdown_ms)
         out["prewarm_programs"] = counters.get("decision.spf.prewarm_programs")
         out["first_jobs"] = stat("ksp_jobs")
+        out["first_path_nodes"] = (stat("ksp_path_nodes"), decoded["nodes"])
         for (a, b), metric, kind in events:
             area = int(g.meta["edge_area"][g.edge_slot(a, b)])
             g.set_metric(a, b, metric)
@@ -174,6 +188,7 @@ async def run_story() -> dict:
                 "decision.rebuild.topo_delta")}
             jobs0, chunks0, rounds0 = (
                 stat("ksp_jobs"), stat("ksp_chunks"), stat("ksp_rounds"))
+            nodes0, decoded0 = stat("ksp_path_nodes"), decoded["nodes"]
             bytes0, reads0 = led.host_bytes, led.host_reads
             mark = led.snapshot()
             pubs.push(Publication(
@@ -199,6 +214,8 @@ async def run_story() -> dict:
                 "ksp_jobs": stat("ksp_jobs") - jobs0,
                 "ksp_chunks": stat("ksp_chunks") - chunks0,
                 "ksp_rounds": stat("ksp_rounds") - rounds0,
+                "ksp_path_nodes": stat("ksp_path_nodes") - nodes0,
+                "decoded_nodes": decoded["nodes"] - decoded0,
                 "fetched": led.host_bytes - bytes0,
                 "reads": led.host_reads - reads0,
                 "compiled": mark.delta(led.snapshot()),
@@ -210,6 +227,7 @@ async def run_story() -> dict:
             for ls in dec.link_states.values()
         }
     finally:
+        patch.undo()
         await fib.stop()
         await dec.stop()
         for q in (pubs, routes, perf_events):
@@ -313,10 +331,27 @@ def test_costs_and_paths_are_fetched_through_the_counted_seam(stories):
     story, _ = stories
     assert set(story["vp"].values()) == {32}
     for e in story["events"]:
-        # k_eff x B x Vp int32 of paths and k_eff x B of costs, a chunk
-        ksp_bytes = e["ksp_rounds"] * 16 * 32 * 4 + e["ksp_rounds"] * 16 * 4
+        # k_eff x B x Vp int32 of paths, k_eff x B of costs and as much
+        # of hops, a chunk
+        ksp_bytes = e["ksp_rounds"] * 16 * 32 * 4 + 2 * e["ksp_rounds"] * 16 * 4
         assert e["fetched"] >= ksp_bytes, e
-        assert e["reads"] >= 2 * e["ksp_chunks"] + 2  # + mirror + packed
+        assert e["reads"] >= 3 * e["ksp_chunks"] + 2  # + mirror + packed
+
+
+def test_the_decode_reads_the_path_nodes_the_kernel_found(stories):
+    """`decision.spf.ksp_path_nodes` (hops + 1 over the paths found) is
+    what `paths_to_host` handed on, node for node, and a small part of
+    the slots fetched."""
+    story, _ = stories
+    counted, decoded = story["first_path_nodes"]
+    assert counted == decoded > 0  # both areas' cold batches
+    for e in story["events"]:
+        assert e["ksp_path_nodes"] == e["decoded_nodes"] > 0, e
+        # 15 jobs of at least one path of at least two nodes; the kernel
+        # filled k_eff x 16 x 32 slots
+        assert 2 * e["ksp_jobs"] <= e["ksp_path_nodes"] < e["ksp_rounds"] * 16 * 32
+    assert story["counters"]["decision.spf.ksp_path_nodes"] == sum(
+        e["ksp_path_nodes"] for e in story["events"]) + counted
 
 
 def test_the_ksp_spans_account_for_the_batch(stories):
