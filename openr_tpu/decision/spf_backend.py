@@ -321,6 +321,12 @@ class TpuSpfSolver:
         # (k_eff) those calls were dispatched with, a chunk.
         # ksp_path_nodes: path nodes the decode read (hops + 1 over the
         # paths found), beside k_eff x B x padded_nodes slots fetched.
+        # general_prefixes: items that entered _unicast_general, the
+        # scalar election; ucmp_prefixes / ucmp_slot_visits: routes
+        # _mk_nexthops built with weights, and the (chosen advertiser,
+        # first-hop slot) steps of its loop, the weighted election's
+        # host work; multi_scoped: anycast prefixes a warm start's
+        # advertiser matrix named for re-election.
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
@@ -332,6 +338,8 @@ class TpuSpfSolver:
             "prewarm_programs": 0,
             "ksp_jobs": 0, "ksp_chunks": 0, "ksp_rounds": 0,
             "ksp_path_nodes": 0,
+            "general_prefixes": 0, "ucmp_prefixes": 0,
+            "ucmp_slot_visits": 0, "multi_scoped": 0,
         }
         # what prewarm_flap_programs has run its programs for: one key
         # per (table shapes, batch, has_overloads, gs_chunks), i.e. per
@@ -924,10 +932,11 @@ class TpuSpfSolver:
             len(prefixes),
         )
         out: dict = {}
-        ksp_jobs = self._unicast_general(
-            csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids, lfa,
-            dist, slot_cache, mk_nexthops_cached, items, out,
-        )
+        with profiling.annotate("spf:unicast_general"):
+            ksp_jobs = self._unicast_general(
+                csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids, lfa,
+                dist, slot_cache, mk_nexthops_cached, items, out,
+            )
         if ksp_jobs:
             self._ksp_batch(csr, ls, my_node, my_id, d_root, ksp_jobs, out)
         return out
@@ -1332,7 +1341,9 @@ class TpuSpfSolver:
                 # advertiser matrix instead of re-assembling all of them
                 t = view.multi
                 hit = t.known & changed_mask[t.adv]
-                for i in np.unique(t.seg[hit]).tolist():
+                scoped = np.unique(t.seg[hit]).tolist()
+                self.spf_kernel_stats["multi_scoped"] += len(scoped)
+                for i in scoped:
                     touched.add(t.prefixes[i])
             for p, _per in view.complex_items:
                 # UCMP/KSP/constrained prefixes: KSP depends on the whole
@@ -1601,11 +1612,12 @@ class TpuSpfSolver:
                     rdb.unicast_routes.update(mdict)
 
             # ---- unicast: general path -----------------------------------
-            ksp_jobs = self._unicast_general(
-                csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids, lfa,
-                dist, slot_cache, mk_nexthops_cached, complex_items,
-                rdb.unicast_routes,
-            )
+            with profiling.annotate("spf:unicast_general"):
+                ksp_jobs = self._unicast_general(
+                    csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids,
+                    lfa, dist, slot_cache, mk_nexthops_cached, complex_items,
+                    rdb.unicast_routes,
+                )
             if ksp_jobs:
                 self._ksp_batch(
                     csr, ls, my_node, my_id, d_root, ksp_jobs,
@@ -1847,6 +1859,7 @@ class TpuSpfSolver:
         prefixes too). Writes routes into `out`; returns the KSP jobs
         for the caller's single batched `_ksp_batch` device call."""
         ksp_jobs: list[tuple] = []  # (prefix, reachable, best_nodes)
+        self.spf_kernel_stats["general_prefixes"] += len(items)
         for prefix, per_node in items:
             reachable = {}
             for n, e in per_node.items():
@@ -2189,8 +2202,8 @@ class TpuSpfSolver:
         ]
         return self._nh_intern.intern(sorted_nexthops(nhs))
 
-    @staticmethod
     def _mk_nexthops(
+        self,
         csr: CsrGraph,
         my_id: int,
         nbr_ids: list[int],
@@ -2211,8 +2224,10 @@ class TpuSpfSolver:
             slot_cache = TpuSpfSolver._nbr_slot_cache(csr, my_id, nbr_ids)
         slots: dict[tuple[str, str], None] = {}
         wsum: dict[tuple[str, str], int] = {}
+        visits = 0
         for tgt in targets:
             valid = np.nonzero(fh[:, int(tgt)])[0]
+            visits += len(valid)
             for n_idx in valid:
                 for key in slot_cache[int(n_idx)]:
                     slots[key] = None
@@ -2223,6 +2238,8 @@ class TpuSpfSolver:
                         )
         if weights is not None:
             wsum = normalize_weights(wsum)
+            self.spf_kernel_stats["ucmp_prefixes"] += 1
+            self.spf_kernel_stats["ucmp_slot_visits"] += visits
         nhs = [
             NextHop(
                 address=fh_name,

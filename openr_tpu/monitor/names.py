@@ -268,6 +268,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:rib_election",          #         classes, advertiser election
     "spf:election",              #           device election (big tables)
     "spf:rib_unicast",           #         unicast RibEntries
+    "spf:unicast_general",       #           the scalar election (warm too)
     "spf:rib_mpls",              #         node-label routes
     "spf:ksp",                   #         KSP prefixes' batched paths
     "spf:ksp_solve",             #           a chunk: dispatch → costs on the host
