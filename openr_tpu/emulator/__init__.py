@@ -18,7 +18,12 @@ from openr_tpu.emulator.chaos import (  # noqa: F401
     LinkFaults,
     run_schedule,
 )
-from openr_tpu.emulator.cluster import Cluster, ClusterNodeSpec, LinkSpec  # noqa: F401
+from openr_tpu.emulator.cluster import (  # noqa: F401
+    Cluster,
+    ClusterNodeSpec,
+    LinkSpec,
+    without_anti_entropy,
+)
 from openr_tpu.emulator.convergence import measure_convergence  # noqa: F401
 from openr_tpu.emulator.invariants import (  # noqa: F401
     Violation,

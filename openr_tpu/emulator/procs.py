@@ -60,6 +60,9 @@ log = logging.getLogger(__name__)
 #: readiness-handshake patience: N interpreters starting on (possibly)
 #: one core serialize their imports; scaled by fleet size at wait time
 READY_BASE_TIMEOUT_S = 30.0
+#: how long a SIGTERMed child may take over its orderly shutdown before
+#: it is killed
+_EXIT_GRACE_S = 10.0
 
 _LOG_TAIL = 30  # lines of a dead node's log quoted in errors
 
@@ -396,15 +399,23 @@ class ProcCluster:
             if pn.alive:
                 pn.proc.send_signal(signal.SIGCONT)  # un-hang first
                 pn.proc.terminate()
-        deadline = time.monotonic() + 10.0
+        deadline = time.monotonic() + _EXIT_GRACE_S
         for pn in list(self.nodes.values()) + list(self.crashed.values()):
-            if pn.proc is None:
-                continue
-            while pn.alive and time.monotonic() < deadline:
-                await asyncio.sleep(0.05)
-            if pn.alive:
-                pn.proc.kill()
-            pn.proc.wait()
+            if pn.proc is not None:
+                await self._reap(pn, deadline)
+
+    @staticmethod
+    async def _reap(pn: ProcNode, deadline: float) -> None:
+        """Collect a signalled child. One that is still there at
+        `deadline` (a SIGTERM it never acts on) is killed. Polled, not
+        `to_thread(proc.wait)`: a thread waiting for a child that does
+        not die outlives every timeout, and `asyncio.run` waits for it
+        on the way out."""
+        while pn.alive and time.monotonic() < deadline:
+            await asyncio.sleep(0.05)
+        if pn.alive:
+            pn.proc.kill()
+        pn.proc.wait(timeout=_EXIT_GRACE_S)  # killed or gone: at once
 
     def endpoints(self) -> list[str]:
         """Live ctrl endpoints, `breeze --endpoints` format."""
@@ -551,7 +562,7 @@ class ProcCluster:
             pn.proc.send_signal(
                 signal.SIGTERM if graceful else signal.SIGKILL
             )
-            await asyncio.to_thread(pn.proc.wait)
+            await self._reap(pn, time.monotonic() + _EXIT_GRACE_S)
 
     async def restart_node(self, name: str) -> None:
         """Real re-exec from the same config: fresh interpreter, fresh

@@ -26,7 +26,7 @@ from __future__ import annotations
 import asyncio
 import gc
 import logging
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 from openr_tpu.common.tasks import guard_task, reap
 from openr_tpu.emulator.chaos import (
@@ -36,7 +36,7 @@ from openr_tpu.emulator.chaos import (
     LinkFaults,
     run_schedule,
 )
-from openr_tpu.emulator.cluster import Cluster
+from openr_tpu.emulator.cluster import Cluster, without_anti_entropy
 from openr_tpu.emulator.invariants import wait_quiescent
 from openr_tpu.monitor import work_ledger
 from openr_tpu.watchdog.watchdog import _current_rss_mb
@@ -251,17 +251,20 @@ async def run_soak(cfg: SoakConfig) -> SoakReport:
         kv_faults=cfg.kv_faults,
         fib_faults=cfg.fib_faults,
     )
-    transform = None
-    if not cfg.enforce_queue_bounds:
-        # control case: every node built with bounds OFF while the caps
-        # stay configured, so check_queue_bounds still knows the limits
-        from dataclasses import replace
 
-        def transform(ncfg):  # noqa: F811
-            return replace(
+    def transform(ncfg):
+        # an update lost in a storm must fail the round's quiesce check,
+        # not be repaired by the periodic full sync and pass a tick later
+        ncfg = without_anti_entropy(ncfg)
+        if not cfg.enforce_queue_bounds:
+            # control case: every node built with bounds OFF while the
+            # caps stay configured, so check_queue_bounds still knows
+            # the limits
+            ncfg = replace(
                 ncfg,
                 messaging=replace(ncfg.messaging, enforce_bounds=False),
             )
+        return ncfg
 
     cluster = Cluster.from_edges(
         cfg.edges, solver=cfg.solver, chaos=plan,

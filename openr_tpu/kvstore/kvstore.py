@@ -69,6 +69,10 @@ class _Peer:
         )
         self.flood_failures = 0
         self.sync_task: "asyncio.Task | None" = None
+        # a sync was asked for while sync_task was mid-exchange: that
+        # exchange's digest may predate whatever the asker dropped, so
+        # it owes one more round before it may end (_spawn_sync)
+        self.resync_owed = False
         # a completed full sync unlocks the anti-entropy noop probe:
         # later re-syncs open with a digestless store-hash compare and
         # only ship the per-key digest on mismatch (docs/Wire.md)
@@ -252,8 +256,13 @@ class KvStore(OpenrModule):
 
     def _spawn_sync(self, peer: _Peer) -> None:
         """One sync task per peer at a time (a down peer's retry loop must
-        not accumulate duplicates across anti-entropy ticks)."""
+        not accumulate duplicates across anti-entropy ticks). A request
+        that finds the task running is not dropped: the caller (a failed
+        flood, an overflowed backlog) has just thrown away updates that
+        only a sync STARTED FROM NOW ON is sure to carry, so the running
+        task is told to go round again when its exchange ends."""
         if peer.sync_task is not None and not peer.sync_task.done():
+            peer.resync_owed = True
             return
         peer.sync_task = self.spawn(
             self._sync_with_peer(peer),
@@ -321,6 +330,14 @@ class KvStore(OpenrModule):
                                 area=area,
                             )
                     self._connected_once.add(key)
+                # this attempt's own handle: a flood failing on the same
+                # session mid-exchange clears peer.session under us
+                session = peer.session
+                # everything dropped up to here is in the store the
+                # digest below is taken from; what is dropped from here
+                # on sets the flag again and is checked after the
+                # exchange
+                peer.resync_owed = False
                 own_hash = db.store_hash()
                 # delta sync (docs/Wire.md): after the first successful
                 # sync, open with a digestless store-hash probe — a
@@ -338,7 +355,7 @@ class KvStore(OpenrModule):
                         self.counters.increment("kvstore.full_syncs_legacy")
                 else:
                     digest = None if peer.probe_ok else db.digest_triples()
-                raw = await peer.session.full_sync(
+                raw = await session.full_sync(
                     area, self.node_name, digest, store_hash=own_hash
                 )
                 if isinstance(raw, dict) and raw.get("need_digest"):
@@ -353,7 +370,7 @@ class KvStore(OpenrModule):
                     # during the probe await may have moved our store,
                     # and a stale hash could spuriously match the
                     # responder's post-convergence state
-                    raw = await peer.session.full_sync(
+                    raw = await session.full_sync(
                         area, self.node_name, db.digest_triples(),
                         store_hash=db.store_hash(),
                     )
@@ -368,13 +385,27 @@ class KvStore(OpenrModule):
                         KeyDumpParams(keys=list(pub.to_be_updated_keys))
                     )
                     if want:
-                        await peer.session.flood(
+                        await session.flood(
                             Publication(
                                 area=area,
                                 key_vals=want,
                                 node_ids=[self.node_name],
                             )
                         )
+                if peer.resync_owed:
+                    # a flood to this peer failed (taking the session
+                    # and its backlog with it) or the backlog overflowed
+                    # while this exchange was in flight. Ending here as
+                    # a success would leave the peer with no session, no
+                    # sync task and floods held for a session nobody
+                    # restores, until anti-entropy: sync again, now
+                    if self.counters is not None:
+                        self.counters.flight_record(
+                            "kvstore.sync_again",
+                            peer=peer.spec.node_name,
+                            area=area,
+                        )
+                    continue
                 peer.synced = True
                 # legacy responders ignore a digestless probe's intent
                 # (None digest reads as empty → they dump their whole

@@ -121,6 +121,9 @@ class _Neighbor:
     last_heard: float = 0.0
     last_seq: int = 0
     handshake_done: bool = False
+    # when our last handshake request to it left (monotonic seconds):
+    # paces the re-send while NEGOTIATE lasts
+    handshake_sent: float = 0.0
     # RTT measurement state: the neighbor's latest hello sent-timestamp
     # (THEIR clock, echoed back verbatim) and when we received it (OUR
     # monotonic clock), so our next hello can report our turnaround lag.
@@ -420,7 +423,20 @@ class Spark(OpenrModule):
                     self._update_rtt(nb, raw_rtt)
             if nb.state == SparkNeighborState.WARM:
                 nb.state = SparkNeighborState.NEGOTIATE
+                nb.handshake_sent = now
                 self.spawn(self._send_handshake(nb, is_ack=False))
+            elif nb.state == SparkNeighborState.NEGOTIATE:
+                # still negotiating while the neighbor says it hears
+                # us: our handshake or its ack was lost (both are
+                # datagrams). Its hellos keep the hold timer from ever
+                # resetting this, and the far side, ESTABLISHED on the
+                # handshake it did get, sends no request of its own —
+                # so ask again, every handshake_time_ms, until answered
+                # (reference: Spark negotiate timer †)
+                cfg = self.config.node.spark
+                if now - nb.handshake_sent >= cfg.handshake_time_ms / 1e3:
+                    nb.handshake_sent = now
+                    self.spawn(self._send_handshake(nb, is_ack=False))
             elif (
                 nb.state == SparkNeighborState.RESTART
                 and nb.handshake_done

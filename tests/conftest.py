@@ -27,7 +27,7 @@ def pytest_report_header(config):
     return f"jax devices: {jax.devices()}"
 
 
-def pytest_configure(config):
+def _build_native(config):
     """Build `native/` once where it is not built and can be, so that a
     clean checkout and a built tree run the same tests: `native/build/`
     is not committed, and the native engine's tests skip without it
@@ -54,6 +54,149 @@ def pytest_configure(config):
 
 
 # --------------------------------------------------------------------------
+# deadline: every test runs under one, @pytest.mark.timeout(seconds) where
+# it carries the marker and DEADLINE_S otherwise, counted over its setup,
+# call and teardown together. When it passes, the stacks of all threads
+# (and of the running event loop's tasks) go to the run's stderr under the
+# test's id (the real one, past pytest's capture, so that a `-q` log names
+# what waited and where), the processes the test started are killed, and
+# the test fails from inside whatever it was waiting in; the run goes on.
+# A test that never comes back to the interpreter (blocked in C with the
+# signal held, a native deadlock) is ended GRACE_S later by faulthandler's
+# watchdog thread, which dumps the stacks and exits the process: under
+# xdist that is one worker, reported as that test's failure and replaced.
+
+import asyncio  # noqa: E402
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import time  # noqa: E402
+
+import pytest  # noqa: E402
+
+#: seconds a test without the marker may take (the slowest that passes
+#: takes under 20 s under `-n 6`, PR 35)
+DEADLINE_S = 300.0
+#: seconds between a test's deadline and the end of its process
+GRACE_S = 30.0
+#: while a deadline stands expired the signal comes again this often: the
+#: first one may land in a background asyncio task, which keeps any
+#: exception but KeyboardInterrupt and SystemExit to itself
+_AGAIN_S = 1.0
+
+_log_fd = 2  # the run's own stderr, duplicated before capture takes fd 2
+
+
+class _Deadline:
+    item = None  # the test the clock runs for
+    seconds = 0.0
+    at = 0.0  # time.monotonic() at which it passes
+    dumped = False
+
+
+def _descendants(pid: int) -> list[int]:
+    """Every live process below `pid` (Linux lists children a thread)."""
+    out, todo = [], [pid]
+    while todo:
+        parent = todo.pop()
+        try:
+            tasks = os.listdir(f"/proc/{parent}/task")
+        except OSError:
+            continue
+        for tid in tasks:
+            try:
+                with open(f"/proc/{parent}/task/{tid}/children") as f:
+                    kids = [int(k) for k in f.read().split()]
+            except OSError:
+                continue
+            out += kids
+            todo += kids
+    return out
+
+
+def _deadline_passed(signum, frame):
+    d = _Deadline
+    if not d.dumped:
+        d.dumped = True
+        os.write(
+            _log_fd,
+            f"\n+++ deadline: {d.item.nodeid} is past its {d.seconds:g} s; "
+            f"stacks of all threads:\n".encode(),
+        )
+        faulthandler.dump_traceback(file=_log_fd, all_threads=True)
+        loop = asyncio._get_running_loop()
+        if loop is not None:
+            # a thread waiting in the loop's select says nothing of what
+            # the loop waits for: its tasks do
+            with os.fdopen(os.dup(_log_fd), "w") as log:
+                for task in asyncio.all_tasks(loop):
+                    task.print_stack(file=log)
+        # a wait for a child that will not die ends with the child; their
+        # owners (Popen, asyncio's watcher) collect the exit statuses
+        for pid in _descendants(os.getpid()):
+            try:
+                os.kill(pid, signal.SIGKILL)
+            except ProcessLookupError:
+                pass
+    pytest.fail(
+        f"deadline: the test is past its {d.seconds:g} s (stacks of all "
+        f"threads are in the run's stderr under '+++ deadline: "
+        f"{d.item.nodeid}')"
+    )
+
+
+def pytest_configure(config):
+    global _log_fd
+    _log_fd = os.dup(2)  # capture is suspended while plugins configure
+    signal.signal(signal.SIGALRM, _deadline_passed)
+    _build_native(config)
+
+
+@pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item):
+    marker = item.get_closest_marker("timeout")
+    d = _Deadline
+    d.item, d.dumped = item, False
+    d.seconds = float(marker.args[0]) if marker else DEADLINE_S
+    d.at = time.monotonic() + d.seconds
+    faulthandler.dump_traceback_later(
+        d.seconds + GRACE_S, exit=True, file=_log_fd
+    )
+    yield
+    faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def _deadline_phase(item):
+    """The signal is armed only while pytest is inside setup, call or
+    teardown, where it takes an exception for the test's outcome, and
+    not again once it has failed the test: what is left of the test
+    then (its teardown) has until the watchdog."""
+    if not _Deadline.dumped:
+        left = max(_Deadline.at - time.monotonic(), 0.001)
+        signal.setitimer(signal.ITIMER_REAL, left, _AGAIN_S)
+    yield
+    signal.setitimer(signal.ITIMER_REAL, 0)
+
+
+pytest_runtest_setup = pytest_runtest_call = _deadline_phase
+pytest_runtest_teardown = _deadline_phase
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_handlecrashitem(crashitem, report, sched):
+    """xdist's `--dist loadfile` scheduler puts a dead worker's file back
+    in its queue with the test that killed the worker still to run: the
+    next worker would die of it too, and so on to the restart limit, with
+    the tests behind it never run. It has its failure: tick it off."""
+    units = [getattr(sched, "workqueue", {})]
+    units += getattr(sched, "assigned_work", {}).values()
+    for by_scope in units:
+        for unit in by_scope.values():
+            if crashitem in unit:
+                unit[crashitem] = True
+
+
+# --------------------------------------------------------------------------
 # asyncio sanitizer: every event loop a test creates (asyncio.run included)
 # runs in DEBUG mode with a recording exception handler and an
 # instrumented task factory. After each test the autouse fixture fails the
@@ -63,11 +206,8 @@ def pytest_configure(config):
 # orlint OR002/OR005 exist to prevent. Opt out for a test that provokes
 # these on purpose with @pytest.mark.asyncio_sanitizer_off.
 
-import asyncio  # noqa: E402
 import gc  # noqa: E402
 import weakref  # noqa: E402
-
-import pytest  # noqa: E402
 
 
 #: exception-handler messages that are task-hygiene failures. Everything

@@ -443,6 +443,160 @@ def test_flood_backpressure_overflow_resyncs():
     run(main())
 
 
+class _HeldSyncTransport(InProcKvTransport):
+    """In-proc transport whose full-sync replies can be held on the wire
+    and whose floods can be made to fail, one switch each."""
+
+    def __init__(self):
+        super().__init__()
+        self.release_sync = asyncio.Event()
+        self.sync_replies_held = 0
+        self.fail_floods = False
+
+    async def connect(self, peer_id, endpoint, counters=None):
+        return _HeldSyncSession(
+            await super().connect(peer_id, endpoint, counters=counters), self
+        )
+
+
+class _HeldSyncSession:
+    def __init__(self, inner, transport):
+        self._inner = inner
+        self._t = transport
+        self.codec = inner.codec
+
+    async def full_sync(self, area, sender_id, digest, store_hash=None):
+        reply = await self._inner.full_sync(
+            area, sender_id, digest, store_hash=store_hash
+        )
+        # answered from the responder's store as it is now; the answer
+        # is then in flight until the test lets it land
+        self._t.sync_replies_held += 1
+        await self._t.release_sync.wait()
+        return reply
+
+    async def flood(self, pub):
+        if self._t.fail_floods:
+            raise ConnectionError("test: flood failed")
+        return await self._inner.flood(pub)
+
+    async def close(self):
+        await self._inner.close()
+
+
+@pytest.mark.parametrize("how", ["flood_fails", "backlog_overflows"])
+def test_resync_asked_for_during_a_sync_is_not_lost(how):
+    """A sync with the peer is in flight (its digest sent, the reply on
+    its way) when the flood pump drops updates and asks for a re-sync to
+    carry them: a flood on the session fails, or the backlog overflows.
+    `_spawn_sync` keeps one task a peer, so the request finds the task
+    running — and the running exchange predates what was dropped. It
+    must go round again; ending as a success left the updates (and,
+    after a failed flood, a peer with no session, no sync task and every
+    later flood held) to the periodic anti-entropy sync, which this test
+    keeps out of reach."""
+
+    async def main():
+        t = _HeldSyncTransport()
+        ws = {n: Wrapper(t, n) for n in ("a", "b")}
+        kv = ws["a"].config.node.kvstore
+        for w in ws.values():
+            w.config.node.kvstore.sync_interval_s = 3600
+            await w.start()
+        a, b = ws["a"].store, ws["b"].store
+        a.add_peer_sync(PeerSpec(node_name="b"))
+        assert await _settle(lambda: t.sync_replies_held == 1)
+        peer = a.peers[("0", "b")]
+        assert peer.session is not None and not peer.sync_task.done()
+
+        if how == "flood_fails":
+            keys = ["k0"]
+            t.fail_floods = True
+            a.set_key("0", "k0", V(1, "a", b"x"))
+            assert await _settle(
+                lambda: ws["a"].counters.get("kvstore.flood_failures") == 1
+            )
+            assert peer.session is None and not peer.pending_keys
+            t.fail_floods = False
+        else:
+            keys = [f"k{i}" for i in range(5)]
+            kv.flood_pending_max_keys = 4
+            for k in keys:  # no await: the pump never gets to send one
+                a.set_key("0", k, V(1, "a", b"x"))
+            assert ws["a"].counters.get("kvstore.flood_backpressure_drops") == 5
+            assert not peer.pending_keys
+        assert not peer.sync_task.done()  # still the same exchange
+
+        t.release_sync.set()
+        ok = await _settle(
+            lambda: all(b.get_key("0", k) is not None for k in keys),
+            timeout=2.0,
+        )
+        assert ok, (
+            f"b never got {keys}: peer synced={peer.synced} "
+            f"session={peer.session} sync_task.done={peer.sync_task.done()}"
+        )
+        assert await _settle(lambda: peer.synced and peer.sync_task.done())
+        assert peer.session is not None
+        # and the flood path to b works again, nothing held
+        a.set_key("0", "later", V(1, "a", b"y"))
+        assert await _settle(lambda: b.get_key("0", "later") is not None)
+        assert not peer.pending_keys
+        for w in ws.values():
+            await w.stop()
+
+    run(main())
+
+
+def test_anti_entropy_repairs_a_flood_lost_in_silence():
+    """What is left for the backstop: a flood the transport reports as
+    delivered and the peer never applied. Neither end can know, so no
+    event repairs it; the periodic full sync does, a tick later."""
+
+    class SilentLossTransport(InProcKvTransport):
+        lose = False
+
+        async def connect(self, peer_id, endpoint, counters=None):
+            session = await super().connect(peer_id, endpoint, counters=counters)
+            deliver = session.flood
+
+            async def flood(pub):
+                if self.lose:
+                    return 0  # "sent", and gone
+                return await deliver(pub)
+
+            session.flood = flood
+            return session
+
+    async def main():
+        t = SilentLossTransport()
+        ws = {n: Wrapper(t, n) for n in ("a", "b")}
+        for w in ws.values():
+            w.config.node.kvstore.sync_interval_s = 1
+            await w.start()
+        a, b = ws["a"].store, ws["b"].store
+        a.add_peer_sync(PeerSpec(node_name="b"))
+        assert await _settle(
+            lambda: ("0", "b") in a.peers and a.peers[("0", "b")].synced
+        )
+        t.lose = True
+        a.set_key("0", "k", V(1, "a", b"x"))
+        assert await _settle(
+            lambda: ws["a"].counters.get("kvstore.floods_sent") == 1
+        )
+        t.lose = False
+        assert b.get_key("0", "k") is None
+        assert ws["a"].counters.get("kvstore.flood_failures") == 0
+        ok = await _settle(
+            lambda: b.get_key("0", "k") is not None, timeout=4.0
+        )
+        assert ok, "the periodic full sync did not repair the loss"
+        for w in ws.values():
+            await w.stop()
+
+    run(main())
+
+
 def test_flood_churn_1k_updates_per_sec_bounded():
     """Sustained 1k key-updates/sec against the default limiter: queue
     depth stays bounded and the peer converges to final state."""

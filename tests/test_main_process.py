@@ -62,7 +62,15 @@ async def _wait_cli(port, args, want, timeout=30.0, interval=0.5):
             *args,
             stdout=asyncio.subprocess.PIPE, stderr=asyncio.subprocess.PIPE,
         )
-        out, _err = await p.communicate()
+        try:
+            out, _err = await asyncio.wait_for(p.communicate(), 20.0)
+        except asyncio.TimeoutError:
+            # a breeze call that neither answers nor fails is one more
+            # unsatisfied poll, not a wait without end
+            p.kill()
+            await p.wait()
+            last = "<cli did not return within 20 s>"
+            continue
         last = out.decode()
         if p.returncode == 0 and want(last):
             return last

@@ -349,7 +349,7 @@ def test_flood_pending_version_dominant_merge():
 def test_flood_coalesces_to_backed_off_peer():
     """Acceptance: with a backed-off peer, N publications coalesce into
     ≪N flood messages after heal, and the stores end byte-identical."""
-    from openr_tpu.emulator import Cluster
+    from openr_tpu.emulator import Cluster, without_anti_entropy
     from openr_tpu.emulator.invariants import (
         check_kvstore_consistency,
         wait_quiescent,
@@ -358,7 +358,9 @@ def test_flood_coalesces_to_backed_off_peer():
     N = 40
 
     async def body():
-        c = Cluster.from_edges([("a", "b")])
+        c = Cluster.from_edges(
+            [("a", "b")], node_config_transform=without_anti_entropy
+        )
         await c.start()
         await c.wait_converged(timeout=20.0)
         na = c.nodes["a"]

@@ -16,7 +16,7 @@ import pytest
 
 from openr_tpu.emulator import proc_invariants
 from openr_tpu.emulator.cluster import LinkSpec
-from openr_tpu.emulator.procs import ProcCluster
+from openr_tpu.emulator.procs import ProcCluster, ProcNode
 from openr_tpu.rpc import RpcClient
 
 
@@ -97,6 +97,37 @@ async def _poll(what, predicate, timeout=90.0, interval=0.5):
             return last
         await asyncio.sleep(interval)
     raise AssertionError(f"{what} never satisfied (last={last!r})")
+
+
+def test_child_that_ignores_sigterm_is_killed_not_waited_for():
+    """crash_node(graceful=True) and stop() SIGTERM a child and collect
+    it. A child that never acts on the signal used to be waited for
+    without end (in a thread, which `asyncio.run` then waited for on its
+    way out too): it has a deadline now, and is killed at it."""
+    import subprocess
+    import time
+
+    child = subprocess.Popen(
+        [
+            sys.executable, "-c",
+            "import signal, time; "
+            "signal.signal(signal.SIGTERM, signal.SIG_IGN); "
+            "print('ready', flush=True); time.sleep(600)",
+        ],
+        stdout=subprocess.PIPE,
+    )
+    try:
+        assert child.stdout.readline() == b"ready\n"  # handler installed
+        pn = ProcNode("deaf", "", "", "", proc=child)
+        child.send_signal(signal.SIGTERM)
+        t0 = time.monotonic()
+        asyncio.run(ProcCluster._reap(pn, t0 + 0.5))
+        assert not pn.alive
+        assert child.returncode == -signal.SIGKILL
+        assert 0.5 <= time.monotonic() - t0 < 5.0
+    finally:
+        child.kill()
+        child.stdout.close()
 
 
 @pytest.mark.timeout(60)

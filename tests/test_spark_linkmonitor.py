@@ -186,6 +186,63 @@ def test_nongraceful_restart_detected_via_heard_map():
     run(main())
 
 
+def test_lost_handshake_is_asked_for_again():
+    """Handshakes are datagrams. While every handshake towards `a` is
+    lost, `b` reaches ESTABLISHED on a's request and `a` waits in
+    NEGOTIATE: b's hellos keep a's hold timer quiet and b, established,
+    sends no request of its own, so nothing but a's own re-send (every
+    handshake_time_ms while the neighbor says it hears us) can end the
+    half-open adjacency once the loss stops."""
+    from openr_tpu.spark.spark import SparkPacket
+    from openr_tpu.types.serde import from_wire_auto
+
+    class LossyHub(MockIoHub):
+        lose_handshakes_to_a = True
+        lost = 0
+
+        def _enqueue(self, lk, dst_node, dst_if, payload, inbox):
+            if self.lose_handshakes_to_a and dst_node == "a":
+                if from_wire_auto(payload, SparkPacket).handshake is not None:
+                    self.lost += 1
+                    return
+            super()._enqueue(lk, dst_node, dst_if, payload, inbox)
+
+    def state(sp, key):
+        nb = sp.neighbors.get(key)
+        return None if nb is None else nb.state
+
+    async def main():
+        hub = LossyHub()
+        sa, _ = mk_spark(hub, "a", kvstore_port=1111)
+        sb, _ = mk_spark(hub, "b", kvstore_port=2222)
+        hub.link("a", "if-ab", "b", "if-ba", latency_ms=1)
+        await sa.start()
+        await sb.start()
+        sa.add_interface("if-ab")
+        sb.add_interface("if-ba")
+        ok = await settle(
+            lambda: state(sb, ("if-ba", "a")) == SparkNeighborState.ESTABLISHED
+            and state(sa, ("if-ab", "b")) == SparkNeighborState.NEGOTIATE
+            and hub.lost >= 1
+        )
+        assert ok, "never reached the half-open state the test is about"
+        hub.lose_handshakes_to_a = False
+        ok = await settle(
+            lambda: state(sa, ("if-ab", "b")) == SparkNeighborState.ESTABLISHED,
+            timeout=2.0,
+        )
+        assert ok, (
+            "a stayed in NEGOTIATE after the loss stopped: its one "
+            "handshake was never sent again"
+        )
+        assert sa.neighbors[("if-ab", "b")].kvstore_port == 2222
+        assert state(sb, ("if-ba", "a")) == SparkNeighborState.ESTABLISHED
+        await sa.stop()
+        await sb.stop()
+
+    run(main())
+
+
 def test_three_node_star():
     """Hub node sees both leaves on separate interfaces."""
 
