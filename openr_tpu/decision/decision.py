@@ -18,6 +18,7 @@ import asyncio
 import json
 import logging
 import threading
+import time
 from dataclasses import replace
 
 import numpy as np
@@ -412,6 +413,9 @@ class Decision(OpenrModule):
         # of the rebuild that picks the batch up (both on the loop
         # thread, in different tasks: not a `with` block)
         self._debounce_span: profiling.Span | None = None
+        # perf_counter() at the loop's last line before the solver thread
+        # and at the thread's last line (decision:thread_start / _return)
+        self._handoff_t: float | None = None
         # perf_counter() of the snapshot behind the most recently
         # EMITTED RouteUpdate, and behind the most recently COMPLETED
         # rebuild (emitted or not) — benchmarks use the pair to attribute
@@ -1065,7 +1069,8 @@ class Decision(OpenrModule):
         per_area = {
             a: self._compute_area(ls, ps) for a, (ls, ps) in states.items()
         }
-        rdb = merge_area_ribs(per_area, self.node_name)
+        with profiling.annotate("decision:merge_full"):
+            rdb = merge_area_ribs(per_area, self.node_name)
         if self.rib_policy is not None:
             self.rib_policy.apply(rdb)
         return rdb
@@ -1121,6 +1126,7 @@ class Decision(OpenrModule):
         mismatch (out-of-band LSDB mutation), artifact absent (node not
         in topology at solve time).
         """
+        profiling.stamp("decision:thread_start", self._handoff_t)
         with profiling.annotate("decision:compute_rib"):
             if dirt is None:
                 dirt = {a: None for a in states}
@@ -1214,18 +1220,24 @@ class Decision(OpenrModule):
                     per_area[a] = rdb
                 if solved_any:
                     path = "full"
-                    new_rib = merge_area_ribs(per_area, self.node_name)
-                    if len(per_area) == 1:
-                        # detach the merge book from the per-area cache:
-                        # the single-area fast path returns the cached rdb
-                        # itself, and the book must never alias it (scoped
-                        # rounds patch cache rdbs in place off-loop, while
-                        # ctrl readers hold self.rib on the event loop).
-                        # Bulk C dict copy, full-rebuild rounds only.
-                        detached = RouteDatabase(this_node_name=self.node_name)
-                        detached.unicast_routes = dict(new_rib.unicast_routes)
-                        detached.mpls_routes = dict(new_rib.mpls_routes)
-                        new_rib = detached
+                    with profiling.annotate("decision:merge_full"):
+                        new_rib = merge_area_ribs(per_area, self.node_name)
+                        if len(per_area) == 1:
+                            # detach the merge book from the per-area
+                            # cache: the single-area fast path returns the
+                            # cached rdb itself, and the book must never
+                            # alias it (scoped rounds patch cache rdbs in
+                            # place off-loop, while ctrl readers hold
+                            # self.rib on the event loop). Bulk C dict
+                            # copy, full-rebuild rounds only.
+                            detached = RouteDatabase(
+                                this_node_name=self.node_name
+                            )
+                            detached.unicast_routes = dict(
+                                new_rib.unicast_routes
+                            )
+                            detached.mpls_routes = dict(new_rib.mpls_routes)
+                            new_rib = detached
                 else:
                     path = "topo_delta" if warm_areas else "prefix_only"
                     scope = prefix_scope
@@ -1236,9 +1248,10 @@ class Decision(OpenrModule):
                     # read-only in this worker thread; _rebuild_routes
                     # applies the update in place on the event loop. No
                     # base-table copy — the round is O(delta × areas).
-                    update = merge_scope_delta(
-                        per_area, self.rib, scope, lscope
-                    )
+                    with profiling.annotate("decision:merge_scope"):
+                        update = merge_scope_delta(
+                            per_area, self.rib, scope, lscope
+                        )
                     new_rib = self.rib
         with profiling.annotate("decision:diff"):
             self._merge_mode = "scoped" if scope is not None else "full"
@@ -1266,6 +1279,7 @@ class Decision(OpenrModule):
             self._rebuild_cached_areas = cached_areas
             self._rebuild_warm_areas = warm_areas
             self._rebuild_warm_region = warm_region
+        self._handoff_t = time.perf_counter()
         return new_rib, update
 
     async def _rebuild_routes(self) -> None:
@@ -1364,9 +1378,17 @@ class Decision(OpenrModule):
                 ps_bumps, self._dirty_ps_bumps = self._dirty_ps_bumps, {}
                 ls_bumps, self._dirty_ls_bumps = self._dirty_ls_bumps, {}
             with profiling.annotate("decision:compute_diff"):
+                # the two hand-offs, loop → solver thread → loop, begin in
+                # one thread and end in the other, where no TraceAnnotation
+                # can go: spans of the record alone (profiling.stamp), the
+                # clock read passed through _handoff_t (one rebuild runs
+                # at a time)
+                self._handoff_t = time.perf_counter()
                 new_rib, update = await asyncio.to_thread(
                     self._compute_and_diff, states, dirt, ps_bumps, ls_bumps
                 )
+                profiling.stamp("decision:thread_return", self._handoff_t)
+                self._handoff_t = None
         except asyncio.CancelledError:
             raise  # node shutdown mid-rebuild must propagate (OR005)
         except Exception as exc:  # noqa: BLE001 — keep serving the old RIB
@@ -1486,6 +1508,10 @@ class Decision(OpenrModule):
             # TPU-branch-gated like the compile/device ledgers: every
             # engine walks the same dataflow stages
             work_ledger.export_to(self.counters)
+            # the garbage collector's process totals (runtime.gc.*): what
+            # of a convergence tail is the collector's; its pauses inside
+            # this rebuild are the record's spf:gc / decision:gc spans
+            profiling.export_gc_to(self.counters)
             with self._decode_stats_lock:
                 for tier, n in self.decode_stats.items():
                     self.counters.set(f"decision.decode.{tier}", n)

@@ -189,6 +189,13 @@ COUNTERS: frozenset[str] = frozenset(
         "jax.compiles.total",
         "jax.transfers.host_reads",
         "jax.transfers.host_bytes",
+        # the cyclic garbage collector's process totals (monitor/
+        # profiling.py export_gc_to; docs/Monitor.md "Runtime: the
+        # garbage collector"): cumulative gauges set at a rebuild's edge
+        "runtime.gc.collections",
+        "runtime.gc.pause_ms",
+        "runtime.gc.full_collections",
+        "runtime.gc.full_pause_ms",
     }
 )
 
@@ -255,6 +262,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "decision:apply_snapshot",   #   LSDB apply, dirt, snapshot (loop)
     "decision:snapshot",         #     the LSDB view the solver works on
     "decision:compute_diff",     #   the solver thread, as the loop waits
+    "decision:thread_start",     #     to_thread → the thread runs (record)
     "decision:compute_rib",      #     per-area compute + merge
     "spf:to_csr",                #       LinkState → CSR snapshot
     "spf:prepare",               #       cold: to_csr + pads + dispatch
@@ -270,6 +278,7 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:rib_unicast",           #         unicast RibEntries
     "spf:unicast_general",       #           the scalar election (warm too)
     "spf:rib_mpls",              #         node-label routes
+    "spf:ksp_prepare",           #         KSP: dests, masks, k clamp, dist0
     "spf:ksp",                   #         KSP prefixes' batched paths
     "spf:ksp_solve",             #           a chunk: dispatch → costs on the host
     "spf:ksp_fetch",             #           a chunk: paths → host
@@ -280,7 +289,16 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:warm_solve",            #       warm kernel + packed fetch
     "spf:warm_unpack",           #       warm: unpack + change mask
     "spf:warm_reassemble",       #       warm: scoped routes + MPLS
+    "spf:warm_scope",            #         change mask, view, `touched` set
+    "spf:general_items",         #         scoped items sorted and copied
+    "spf:warm_table_copy",       #         cached RIB copied, `touched` put in
+    "spf:warm_labels",           #         label routes of the changed nodes
+    "spf:gc",                    #       a collection (gen ≥ 1) under spf:
+    "decision:merge_full",       #       cross-area fold, all of it
+    "decision:merge_scope",      #       cross-area fold, the scoped keys
     "decision:diff",             #     RIB delta / work-ledger commit
+    "decision:thread_return",    #     thread done → the loop resumes (record)
+    "decision:gc",               #   a collection (gen ≥ 1) under decision:
     "decision:export_counters",  #   markers, trim policy, counters
     "decision:publish",          #   merge book + route_updates.push
     "spf:prewarm",               # after a full rebuild: the flap's programs
@@ -288,7 +306,7 @@ REBUILD_SPANS: tuple[str, ...] = (
 
 #: every span name the program opens (tests/test_profiling.py checks the
 #: call sites against it; docs/Monitor.md lists them)
-SPANS: frozenset[str] = frozenset(REBUILD_SPANS) | {"fib:program"}
+SPANS: frozenset[str] = frozenset(REBUILD_SPANS) | {"fib:program", "fib:gc"}
 
 #: the queue counter FIELD vocabulary the messaging seams may emit —
 #: OR007 statically cross-checks messaging/__init__.py's emit sites
@@ -316,6 +334,7 @@ DOCUMENTED: frozenset[str] = frozenset(
     | {n for n in COUNTERS if n.startswith("jax.")}
     | {n for n in COUNTERS if n.startswith("persist.")}
     | {n for n in COUNTERS if n.startswith("wire.")}
+    | {n for n in COUNTERS if n.startswith("runtime.")}
 )
 
 #: source files exempt from the per-callsite check: the registry's own

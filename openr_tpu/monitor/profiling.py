@@ -36,13 +36,19 @@ One span, three sinks:
 With no collector open and no ``counters`` a span writes to the trace
 alone. Leaving a span never calls the device (the HBM gauges are sampled
 at rebuild edges, decision.py).
+
+The cyclic garbage collector's pauses are spans of the same record
+("The collector's pauses" below): the first ``collect()``,
+``annotate()`` or ``start()`` of a process hooks ``gc.callbacks`` once.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import logging
+import threading
 import time
 
 log = logging.getLogger(__name__)
@@ -127,6 +133,8 @@ class collect:
         self._tokens = None
 
     def __enter__(self) -> SpanRecord:
+        if not _gc_hooked:
+            install_gc_hook()
         self._tokens = (_RECORD.set(self.record), _PARENT.set(None))
         return self.record
 
@@ -184,6 +192,17 @@ def start(name: str) -> "Span":
     return Span(name)._open()
 
 
+def stamp(name: str, start: float | None) -> None:
+    """A span of the record alone, from `start` (a `perf_counter()`
+    reading taken earlier, possibly in another thread) to now: for a
+    hand-off that begins in one thread and ends in another, where a
+    `TraceAnnotation` (entered and left by one thread) cannot go. Child
+    of the span open here; nothing without an open record or a start."""
+    record = _RECORD.get()
+    if record is not None and start is not None:
+        record.spans.append((name, _PARENT.get(), start, time.perf_counter()))
+
+
 class Span:
     """The timed span: the (possibly absent) jax annotation, a
     `perf_counter` pair, and on the way out the Counters stat and the
@@ -202,6 +221,8 @@ class Span:
         self._token = None
 
     def _open(self) -> "Span":
+        if not _gc_hooked:
+            install_gc_hook()
         self._inner = _annotation(self.name)
         if self._inner is not None:
             try:
@@ -236,3 +257,107 @@ class Span:
     def stop(self, record: SpanRecord | None = None) -> None:
         """Leave a span opened by `start`, into `record` at its top."""
         self._close(record, None)
+
+
+# ------------------------------------------------ the collector's pauses
+
+
+class GcTotals:
+    """The cyclic garbage collector over the life of the process, by
+    generation (index 0, 1, 2; 2 is a full collection): collections,
+    seconds the interpreter stood still in them (start callback to stop
+    callback, the collection's span inside), objects they freed.
+    Written by `_on_gc` alone; a collection runs with the interpreter's
+    `collecting` flag up, its callbacks included, so there is one writer
+    at a time."""
+
+    __slots__ = ("collections", "pause_s", "collected")
+
+    def __init__(self):
+        self.collections = [0, 0, 0]
+        self.pause_s = [0.0, 0.0, 0.0]
+        self.collected = [0, 0, 0]
+
+
+_GC = GcTotals()
+_GC_LOCK = threading.Lock()
+_gc_hooked = False
+#: the collection in flight: its start and, where it became one, its span
+_gc_t0: float | None = None
+_gc_span: Span | None = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The `gc.callbacks` hook. Any generation: two clock reads and the
+    totals (a fabric event sets off on the order of a hundred young
+    collections). A collection of generation 1 or 2 that starts while a
+    span is open in this thread's context is a span too, child of the
+    one it interrupted and named by that span's module prefix
+    (`spf:gc`, `decision:gc`, `fib:gc`: perfbench/trace_reduce.py keeps
+    the row like any span of the program): opened here at "start" and
+    closed at "stop" by the same thread, into the trace, the open
+    record and the totals.
+    With no span open (the loop asleep, a caller outside the program)
+    it lands in the totals alone."""
+    global _gc_t0, _gc_span
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        if info["generation"]:
+            parent = _PARENT.get()
+            if parent is not None:
+                _gc_span = Span(parent.partition(":")[0] + ":gc")._open()
+        return
+    t0, span = _gc_t0, _gc_span
+    if t0 is None:
+        return  # hooked between this collection's start and its stop
+    _gc_t0 = _gc_span = None
+    if span is not None:
+        span._close(_RECORD.get(), _PARENT.get())
+    gen = info["generation"]
+    _GC.collections[gen] += 1
+    _GC.pause_s[gen] += time.perf_counter() - t0
+    _GC.collected[gen] += info["collected"]
+
+
+def install_gc_hook() -> None:
+    """Hook the collector, once a process however often it is asked."""
+    global _gc_hooked
+    with _GC_LOCK:
+        if not _gc_hooked:
+            gc.callbacks.append(_on_gc)
+            _gc_hooked = True
+
+
+def remove_gc_hook() -> None:
+    """Take the hook off again (tests); the totals stay. The next
+    `collect()`, `annotate()` or `start()` puts it back."""
+    global _gc_hooked, _gc_t0, _gc_span
+    with _GC_LOCK:
+        if _gc_hooked:
+            gc.callbacks.remove(_on_gc)
+            _gc_hooked = False
+            _gc_t0 = _gc_span = None
+
+
+def gc_totals() -> dict[str, float]:
+    """The process totals as the `runtime.gc.*` gauges carry them
+    (docs/Monitor.md "Runtime: the garbage collector"): cumulative, so
+    a reader takes the difference of two readings."""
+    return {
+        "collections": sum(_GC.collections),
+        "pause_ms": sum(_GC.pause_s) * 1e3,
+        "full_collections": _GC.collections[2],
+        "full_pause_ms": _GC.pause_s[2] * 1e3,
+        "collected": sum(_GC.collected),
+    }
+
+
+def export_gc_to(counters) -> None:
+    """Stamp the totals into a Counters registry, as `compile_ledger`
+    and `work_ledger` hand theirs over at a rebuild's edge. Values are
+    the process's: one collector serves every in-process node."""
+    totals = gc_totals()
+    counters.set("runtime.gc.collections", totals["collections"])
+    counters.set("runtime.gc.pause_ms", totals["pause_ms"])
+    counters.set("runtime.gc.full_collections", totals["full_collections"])
+    counters.set("runtime.gc.full_pause_ms", totals["full_pause_ms"])

@@ -29,6 +29,12 @@ Design (all shapes static, no host round-trips inside):
 The k rounds run under ``lax.scan`` — k is static, banned is the carry.
 Distances strictly decrease along a back-walk (metrics ≥ 1), so the walk
 needs no visited-set and terminates in ≤ max_hops steps.
+
+The phases carry ``jax.named_scope`` names, as ops/spf_split.py's do, so
+a device trace of the kernel reads by phase: ``first_solve`` (round 1's
+shared, ban-free distances), ``round/ban_mask`` + ``round/fixpoint`` (a
+later round's masked SSSP), ``round/walk`` (the back-walk and its bans),
+``round/emit`` (the round's rows of the outputs). Metadata only.
 """
 
 from __future__ import annotations
@@ -86,11 +92,12 @@ def _ksp_edge_disjoint_dense_jit(
     bidx = jnp.arange(b)
 
     def sssp(banned):
-        dist = jnp.full((num_nodes, b), INF_DIST, DIST_DTYPE)
-        dist = dist.at[root, :].set(0)
-        usable = (~blocked[:, :, None]) & (~banned) & (
-            wgt[:, :, None] < INF_DIST
-        )
+        with jax.named_scope("round/ban_mask"):
+            dist = jnp.full((num_nodes, b), INF_DIST, DIST_DTYPE)
+            dist = dist.at[root, :].set(0)
+            usable = (~blocked[:, :, None]) & (~banned) & (
+                wgt[:, :, None] < INF_DIST
+            )
         width = nbr.shape[1]
 
         def relax(state):
@@ -129,9 +136,10 @@ def _ksp_edge_disjoint_dense_jit(
             _dist, changed, it = state
             return changed & (it < num_nodes)
 
-        dist, _, _ = jax.lax.while_loop(
-            cond, relax, (dist, jnp.bool_(True), 0)
-        )
+        with jax.named_scope("round/fixpoint"):
+            dist, _, _ = jax.lax.while_loop(
+                cond, relax, (dist, jnp.bool_(True), 0)
+            )
         return dist
 
     def walk(dist, banned):
@@ -220,20 +228,22 @@ def _ksp_edge_disjoint_dense_jit(
         if dist0 is not None:
             # round 1 is ban-free and shared: broadcast the caller's
             # precomputed distances instead of running the fixpoint
-            dist = jax.lax.cond(
-                i == 0,
-                lambda: jnp.broadcast_to(
-                    dist0[:, None], (num_nodes, b)
-                ).astype(DIST_DTYPE),
-                lambda: sssp(banned),
-            )
+            def first_solve():
+                with jax.named_scope("first_solve"):
+                    return jnp.broadcast_to(
+                        dist0[:, None], (num_nodes, b)
+                    ).astype(DIST_DTYPE)
+
+            dist = jax.lax.cond(i == 0, first_solve, lambda: sssp(banned))
         else:
             dist = sssp(banned)
-        cost, path, hop, banned, ok = walk(dist, banned)
-        path = jnp.where(ok[:, None], path, -1)
-        costs = costs.at[i].set(cost)
-        paths = paths.at[i].set(path)
-        hops = hops.at[i].set(hop)
+        with jax.named_scope("round/walk"):
+            cost, path, hop, banned, ok = walk(dist, banned)
+        with jax.named_scope("round/emit"):
+            path = jnp.where(ok[:, None], path, -1)
+            costs = costs.at[i].set(cost)
+            paths = paths.at[i].set(path)
+            hops = hops.at[i].set(hop)
         return banned, costs, paths, hops, i + 1, jnp.any(ok)
 
     _, costs, paths, hops, _, _ = jax.lax.while_loop(

@@ -324,3 +324,30 @@ def test_ksp_relax_branches_agree(monkeypatch):
     )
     for a, b in zip(ref, wide):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("shared_first_solve", [True, False])
+def test_the_kernel_s_phases_carry_named_scopes(shared_first_solve):
+    """A device trace of the KSP kernel reads by phase, as the split
+    kernels' does (docs/Monitor.md "Spans"): the scopes are in the
+    lowered program's op metadata. Lowering only: nothing runs."""
+    import jax.numpy as jnp
+
+    from openr_tpu.ops.ksp import _ksp_edge_disjoint_dense_jit
+
+    v, d, b = 16, 4, 8
+    lowered = _ksp_edge_disjoint_dense_jit.lower(
+        jnp.zeros((v, d), jnp.int32),
+        jnp.full((v, d), int(INF_DIST), jnp.int32),
+        jnp.zeros((v, d), bool),
+        jnp.int32(0),
+        jnp.zeros((b,), jnp.int32),
+        k=4,
+        max_hops=v - 1,
+        dist0=jnp.zeros((v,), jnp.int32) if shared_first_solve else None,
+    )
+    text = lowered.as_text(debug_info=True)
+    scopes = ["round/ban_mask", "round/fixpoint", "round/walk", "round/emit"]
+    for scope in scopes:
+        assert scope in text, scope
+    assert ("first_solve" in text) is shared_first_solve

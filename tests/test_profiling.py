@@ -6,6 +6,7 @@ are views of."""
 
 import ast
 import asyncio
+import gc
 import pathlib
 import re
 import sys
@@ -23,6 +24,38 @@ def no_jax(monkeypatch):
     not latched an answer yet (and forgets this one afterwards)."""
     monkeypatch.setitem(sys.modules, "jax", None)
     monkeypatch.setattr(profiling, "_ANNOTATION_CLS", None)
+
+
+@pytest.fixture(autouse=True)
+def only_forced_collections():
+    """The collector runs where a test calls it and nowhere else, so
+    that a record holds the spans the test opened and no `<prefix>:gc`
+    of a collection that happened to fall into it."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+@pytest.fixture
+def trace_rows(monkeypatch):
+    """In place of jax's TraceAnnotation: the rows opened and closed,
+    `("enter" | "exit", name)` in order."""
+    rows = []
+
+    class Row:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            rows.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            rows.append(("exit", self.name))
+
+    monkeypatch.setattr(profiling, "_ANNOTATION_CLS", Row)
+    return rows
 
 
 class _NoJax:
@@ -204,21 +237,9 @@ def test_span_exit_makes_no_device_call(monkeypatch):
 
 
 def test_no_collector_and_no_counters_writes_to_the_trace_alone(
-    monkeypatch,
+    trace_rows,
 ):
-    rows = []
-
-    class Row:
-        def __init__(self, name):
-            self.name = name
-
-        def __enter__(self):
-            rows.append(("enter", self.name))
-
-        def __exit__(self, *exc):
-            rows.append(("exit", self.name))
-
-    monkeypatch.setattr(profiling, "_ANNOTATION_CLS", Row)
+    rows = trace_rows
     with profiling.collect() as rec:
         pass
     with profiling.annotate("spf:bare") as span:
@@ -272,9 +293,198 @@ def test_a_span_closes_into_the_record_when_its_block_raises():
     assert rec2.spans[0][1] is None
 
 
+# ------------------------------------------------ the collector's pauses
+
+
+def _grown(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after}
+
+
+def test_a_collection_inside_an_open_span_is_its_gc_child(trace_rows):
+    """Generation 1 or 2, started under an open span: a `<prefix>:gc`
+    span with the three sinks every span has (trace row, record,
+    totals), child of the span it interrupted."""
+    rows = trace_rows
+    before = profiling.gc_totals()
+    with profiling.collect() as rec:
+        with profiling.annotate("spf:warm_reassemble"):
+            gc.collect()
+        with profiling.annotate("decision:rebuild"):
+            with profiling.annotate("decision:publish"):
+                gc.collect(1)
+        with profiling.annotate("fib:program"):
+            gc.collect(2)
+    assert [(n, p) for n, p, _s, _e in rec.spans] == [
+        ("spf:gc", "spf:warm_reassemble"), ("spf:warm_reassemble", None),
+        ("decision:gc", "decision:publish"),
+        ("decision:publish", "decision:rebuild"), ("decision:rebuild", None),
+        ("fib:gc", "fib:program"), ("fib:program", None),
+    ]
+    at = {(n, p): (s, e) for n, p, s, e in rec.spans}
+    for child, parent in (
+        (("spf:gc", "spf:warm_reassemble"), ("spf:warm_reassemble", None)),
+        (("decision:gc", "decision:publish"),
+         ("decision:publish", "decision:rebuild")),
+        (("fib:gc", "fib:program"), ("fib:program", None)),
+    ):
+        assert at[parent][0] <= at[child][0] <= at[child][1] <= at[parent][1]
+    # the row opens inside its parent's and closes before it
+    assert rows[:4] == [
+        ("enter", "spf:warm_reassemble"), ("enter", "spf:gc"),
+        ("exit", "spf:gc"), ("exit", "spf:warm_reassemble"),
+    ]
+    assert [name for kind, name in rows if kind == "enter"].count(
+        "decision:gc") == 1
+    grew = _grown(before, profiling.gc_totals())
+    assert grew["collections"] == 3 and grew["full_collections"] == 2
+    assert grew["pause_ms"] >= grew["full_pause_ms"] > 0.0
+    # the totals hold the spans' time and the hook's own two reads
+    assert grew["pause_ms"] >= sum(
+        ms for name, ms in rec.ms.items() if name.endswith(":gc"))
+    assert set(rec.ms) >= {"spf:gc", "decision:gc", "fib:gc"}
+
+
+def test_a_collection_under_no_span_lands_in_the_totals_alone(trace_rows):
+    before = profiling.gc_totals()
+    with profiling.collect() as rec:  # a record, but no span open
+        gc.collect()
+    gc.collect()  # no record either
+    held = profiling.start("decision:debounce_wait")  # nobody's parent
+    gc.collect()
+    held.stop()
+    assert rec.spans == [] and trace_rows == [
+        ("enter", "decision:debounce_wait"), ("exit", "decision:debounce_wait"),
+    ]
+    grew = _grown(before, profiling.gc_totals())
+    assert grew["collections"] == grew["full_collections"] == 3
+    assert grew["full_pause_ms"] > 0.0
+
+
+def test_a_young_collection_opens_nothing():
+    before = profiling.gc_totals()
+    with profiling.collect() as rec:
+        with profiling.annotate("spf:rib_unicast"):
+            gc.collect(0)
+    assert [(n, p) for n, p, _s, _e in rec.spans] == [
+        ("spf:rib_unicast", None)]
+    grew = _grown(before, profiling.gc_totals())
+    assert grew["collections"] == 1 and grew["full_collections"] == 0
+    assert grew["pause_ms"] > 0.0 and grew["full_pause_ms"] == 0.0
+
+
+def test_what_a_collection_freed_is_counted():
+    class Node:
+        pass
+
+    before = profiling.gc_totals()
+    with profiling.annotate("spf:x"):
+        for _ in range(50):
+            a, b = Node(), Node()
+            a.other, b.other = b, a  # a cycle only the collector frees
+        del a, b
+        gc.collect()
+    assert profiling.gc_totals()["collected"] - before["collected"] >= 100
+
+
+def test_one_hook_a_process_and_it_comes_off_cleanly():
+    for _ in range(3):
+        with profiling.collect():
+            with profiling.annotate("spf:x"):
+                pass
+        profiling.start("decision:debounce_wait").stop()
+    assert gc.callbacks.count(profiling._on_gc) == 1
+    profiling.remove_gc_hook()
+    profiling.remove_gc_hook()  # and again: nothing left to take off
+    assert profiling._on_gc not in gc.callbacks
+    before = profiling.gc_totals()
+    gc.collect()
+    assert profiling.gc_totals() == before  # unhooked: it counts nothing
+    # the next span of the process puts it back, once
+    with profiling.annotate("spf:x"):
+        gc.collect()
+    with profiling.collect():
+        pass
+    assert gc.callbacks.count(profiling._on_gc) == 1
+    assert profiling.gc_totals()["full_collections"] == (
+        before["full_collections"] + 1)
+
+
+def test_the_hook_counts_and_records_without_jax(no_jax):
+    """JAX_PLATFORMS=cpu and no profiler, or no jax at all: the span is
+    a clock pair, as every span is there."""
+    before = profiling.gc_totals()
+    with profiling.collect() as rec:
+        with profiling.annotate("spf:solve"):
+            gc.collect()
+    assert [n for n, *_ in rec.spans] == ["spf:gc", "spf:solve"]
+    assert profiling.gc_totals()["full_collections"] == (
+        before["full_collections"] + 1)
+
+
+def test_export_gc_to_sets_the_four_cumulative_gauges():
+    c = Counters()
+    profiling.export_gc_to(c)
+    first = {k: v for k, v in c.snapshot().items() if k.startswith("runtime.")}
+    assert set(first) == {
+        "runtime.gc.collections", "runtime.gc.pause_ms",
+        "runtime.gc.full_collections", "runtime.gc.full_pause_ms",
+    }
+    assert set(first) <= names.COUNTERS and set(first) <= names.DOCUMENTED
+    with profiling.annotate("spf:x"):
+        gc.collect()
+        gc.collect(0)
+    profiling.export_gc_to(c)
+    second = c.snapshot()
+    grew = {k.rpartition(".")[2]: second[k] - v for k, v in first.items()}
+    assert grew["collections"] == 2 and grew["full_collections"] == 1
+    assert grew["pause_ms"] > grew["full_pause_ms"] > 0.0
+
+
+# -------------------------------------------- spans of the record alone
+
+
+def test_a_stamp_spans_two_threads_in_the_record_alone(monkeypatch):
+    """`stamp`: a hand-off that begins in one thread and ends in
+    another is a span of the record and no row of the trace."""
+    monkeypatch.setattr(
+        profiling, "_ANNOTATION_CLS",
+        lambda name: pytest.fail(f"a trace row was opened: {name}")
+        if "thread" in name else None,
+    )
+
+    def worker(called_at):
+        profiling.stamp("decision:thread_start", called_at)
+        time.sleep(0.002)
+        return time.perf_counter()
+
+    async def body():
+        with profiling.collect() as rec:
+            with profiling.annotate("decision:compute_diff"):
+                done_at = await asyncio.to_thread(worker, time.perf_counter())
+                profiling.stamp("decision:thread_return", done_at)
+        return rec
+
+    rec = asyncio.run(body())
+    assert [(n, p) for n, p, _s, _e in rec.spans] == [
+        ("decision:thread_start", "decision:compute_diff"),
+        ("decision:thread_return", "decision:compute_diff"),
+        ("decision:compute_diff", None),
+    ]
+    (_n, _p, s0, e0), (_n, _p, s1, e1), (_n, _p, s, e) = rec.spans
+    assert s <= s0 <= e0 <= s1 <= e1 <= e
+    assert e0 + 0.0015 <= s1  # the worker's own time lies between the two
+    # no record open, or no start handed over: nothing, and no error
+    profiling.stamp("decision:thread_start", time.perf_counter())
+    with profiling.collect() as rec2:
+        profiling.stamp("decision:thread_return", None)
+    assert rec2.spans == []
+
+
 # ------------------------------------------------------ the vocabulary
 
-_SPAN_CALLS = {"annotate", "start"}
+#: `stamp` makes a span of the record alone; a collection's span is made
+#: in profiling.py itself, from the prefix of the span it interrupted
+_SPAN_CALLS = {"annotate", "start", "stamp"}
 
 
 def _span_literals():
@@ -308,7 +518,19 @@ def test_every_span_name_is_registered_prefixed_and_documented():
         used.setdefault(name, f"{path}:{line}")
     unknown = {n: at for n, at in used.items() if n not in names.SPANS}
     assert not unknown, unknown
-    assert set(used) == set(names.SPANS)
+    # a collection takes the module prefix of the span it falls into:
+    # every prefix that opens a span has its `<prefix>:gc` registered
+    gc_spans = {n.partition(":")[0] + ":gc" for n in used}
+    assert gc_spans == {"spf:gc", "decision:gc", "fib:gc"}
+    assert not gc_spans & set(used)
+    assert set(used) | gc_spans == set(names.SPANS)
+    new = {
+        "spf:gc", "decision:gc", "decision:merge_scope", "decision:merge_full",
+        "spf:warm_scope", "spf:general_items", "spf:warm_table_copy",
+        "spf:warm_labels", "decision:thread_start", "decision:thread_return",
+        "spf:ksp_prepare",
+    }
+    assert new <= set(names.REBUILD_SPANS)
     prefix = re.compile(r"^(spf|decision|fib|kvstore):[a-z_]+$")
     assert all(prefix.match(n) for n in names.SPANS)
     assert set(names.REBUILD_SPANS) < names.SPANS

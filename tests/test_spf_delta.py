@@ -561,6 +561,113 @@ def test_last_breakdown_ms_is_a_view_of_the_rebuilds_span_record(backend):
         assert all(warm[n] == 0.0 for n in warm_spans)
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_a_rebuild_names_fold_reassembly_hand_off_and_collector(backend):
+    """PR 36: `decision:merge_full` / `decision:merge_scope`, the
+    children of `spf:warm_reassemble`, the two hand-offs to and from the
+    solver thread (record only) and the collector's `spf:gc` /
+    `decision:gc` are keys of every rebuild's breakdown; the
+    `runtime.gc.*` gauges are exported at the rebuild's edge and only
+    grow."""
+    import gc
+
+    from openr_tpu.monitor import names, profiling
+
+    new = {
+        "decision:merge_full", "decision:merge_scope",
+        "decision:thread_start", "decision:thread_return",
+        "spf:warm_scope", "spf:general_items", "spf:warm_table_copy",
+        "spf:warm_labels", "spf:gc", "decision:gc",
+    }
+    gauges = (
+        "runtime.gc.collections", "runtime.gc.pause_ms",
+        "runtime.gc.full_collections", "runtime.gc.full_pause_ms",
+    )
+
+    async def body():
+        d = mk_decision(backend)
+        adj_dbs, prefix_dbs = topogen.grid(5, 5, metric=10)
+        d.process_publication(adj_pub(adj_dbs))
+        d.process_publication(prefix_pub(prefix_dbs))
+        with profiling.collect() as rec_full:
+            await d._rebuild_routes()
+        full = dict(d.last_breakdown_ms)
+        seen = [{k: d.counters.snapshot()[k] for k in gauges}]
+        adj_cur = {db.this_node_name: db for db in adj_dbs}
+        k = [a.other_node_name for a in adj_cur["node-1"].adjacencies].index(
+            "node-2"
+        )
+        d.process_publication(flap_pub(adj_cur, "node-1", k, 30, 2))
+        gc.collect()  # under no span: the totals alone
+        with profiling.collect() as rec_warm:
+            await d._rebuild_routes()
+        warm = dict(d.last_breakdown_ms)
+        seen.append({k: d.counters.snapshot()[k] for k in gauges})
+        return d, full, warm, rec_full, rec_warm, seen
+
+    d, full, warm, rec_full, rec_warm, seen = run(body())
+    assert new <= set(names.REBUILD_SPANS)
+    for bd in (full, warm):
+        assert new <= set(bd) and all(bd[k] >= 0.0 for k in new)
+    # the first RIB folds in full; the flap, a scoped round, by the book
+    assert full["decision:merge_full"] > 0.0 == full["decision:merge_scope"]
+    assert warm["decision:merge_scope"] > 0.0 == warm["decision:merge_full"]
+    assert d.counters.get("decision.merge.scoped") == 1
+    for bd in (full, warm):
+        assert (
+            bd["decision:merge_full"] + bd["decision:merge_scope"]
+            <= bd["compute_rib"]
+        )
+        # the hand-offs and the thread's work lie inside the loop's wait
+        assert bd["decision:thread_start"] > 0.0
+        assert bd["decision:thread_return"] > 0.0
+        assert (
+            bd["decision:thread_start"] + bd["compute_rib"] + bd["diff"]
+            + bd["decision:thread_return"]
+            <= bd["compute_diff"] + 1e-6
+        )
+    for rec in (rec_full, rec_warm):
+        at = {n: (p, s, e) for n, p, s, e in rec.spans}
+        _p, lo, hi = at["decision:compute_diff"]
+        _p, rib_s, _e = at["decision:compute_rib"]
+        _p, _s, diff_e = at["decision:diff"]
+        for name in ("decision:thread_start", "decision:thread_return"):
+            parent, s, e = at[name]
+            assert parent == "decision:compute_diff"
+            assert lo <= s <= e <= hi
+        assert at["decision:thread_start"][2] <= rib_s
+        assert diff_e <= at["decision:thread_return"][1]
+    parents = {n: p for n, p, _s, _e in rec_warm.spans}
+    assert parents["decision:merge_scope"] == "decision:compute_rib"
+    assert (
+        {n: p for n, p, _s, _e in rec_full.spans}["decision:merge_full"]
+        == "decision:compute_rib"
+    )
+    if backend == "tpu":
+        parts = ("spf:warm_scope", "spf:general_items", "spf:warm_table_copy",
+                 "spf:warm_labels")
+        assert all(parents[n] == "spf:warm_reassemble" for n in parts)
+        assert parents["spf:unicast_general"] == "spf:warm_reassemble"
+        assert all(warm[n] > 0.0 for n in parts)
+        assert all(full[n] == 0.0 for n in parts)
+        assert (
+            sum(warm[n] for n in parts) + warm["spf:unicast_general"]
+            + warm["spf:ksp"] <= warm["spf:warm_reassemble"]
+        )
+        # the solver's copy of the totals, exported by the loop over
+        # every key of spf_kernel_stats
+        st = d._tpu.spf_kernel_stats
+        assert st["gc_full_collections"] >= 1 and st["gc_pause_ms"] > 0.0
+        assert d.counters.get("decision.spf.gc_full_collections") >= 1
+    # cumulative and process-wide: both readings there, the later no
+    # smaller, and the forced collection between them counted
+    first, second = seen
+    assert all(second[k] >= first[k] >= 0 for k in gauges)
+    for k in ("runtime.gc.full_collections", "runtime.gc.full_pause_ms"):
+        assert second[k] > first[k]
+    assert second["runtime.gc.pause_ms"] >= second["runtime.gc.full_pause_ms"]
+
+
 # ------------------------------------------------- the compiled patch scatter
 
 
