@@ -41,6 +41,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from openr_tpu.common.constants import DIST_INF
+from openr_tpu.types.topology import ForwardingAlgorithm
 
 INF64 = np.int64(DIST_INF)
 
@@ -67,6 +68,21 @@ class MultiTable:
 
 
 @dataclass
+class ComplexTable:
+    """Columnar advertisers of `ElectView.complex_items`, the shape
+    `MultiTable` has: what a warm start scopes the scalar fallback by.
+    Only KNOWN advertisers get a slot: an unknown one cannot become
+    reachable without a structural change, which is a full solve."""
+
+    adv: np.ndarray  # int64 [S] advertiser node id
+    seg: np.ndarray  # int64 [S] owning index into complex_items
+    # items with a KSP2_ED_ECMP entry on ANY advertiser, reachable or
+    # not (conservative, as the oracle's warm path is): k-disjoint
+    # paths depend on the whole graph, not on advertiser classes
+    whole_graph: np.ndarray  # int64 [K] indices into complex_items
+
+
+@dataclass
 class ElectView:
     """One PrefixState revision's election-ready classification."""
 
@@ -76,6 +92,7 @@ class ElectView:
     orig: np.ndarray  # int64 [P] advertiser node id
     multi: MultiTable | None
     complex_items: list  # [(prefix, {node: entry})] scalar fallback
+    complex_table: ComplexTable  # complex_items' advertisers, columnar
     gen: tuple  # generation token (lineage, rev, base_version)
 
 
@@ -93,8 +110,6 @@ class MultiElection:
 def _entry_plain(e) -> bool:
     """Advertiser shape the vectorized election covers: shortest-path
     ECMP with no route-shape constraints."""
-    from openr_tpu.types.topology import ForwardingAlgorithm
-
     return (
         e.forwarding_algorithm == ForwardingAlgorithm.SP_ECMP
         and not e.min_nexthop
@@ -120,6 +135,24 @@ def build_elect_view(entries: dict, name_to_id: dict, gen) -> ElectView:
     m_entries: list = []
     m_names: list = []
     complex_items: list = []
+    c_adv: list = []
+    c_seg: list = []
+    c_whole: list = []
+
+    def add_complex(prefix, per_node) -> None:
+        idx = len(complex_items)
+        # copy: the live object mutates per_node dicts in place, and
+        # this view may outlive its instance via the shared cell
+        complex_items.append((prefix, dict(per_node)))
+        known = [name_to_id[n] for n in per_node if n in name_to_id]
+        c_adv.extend(known)
+        c_seg.extend([idx] * len(known))
+        if any(
+            e.forwarding_algorithm == ForwardingAlgorithm.KSP2_ED_ECMP
+            for e in per_node.values()
+        ):
+            c_whole.append(idx)
+
     for prefix, per_node in sorted(entries.items()):
         if len(per_node) == 1:
             (node, entry), = per_node.items()
@@ -132,7 +165,7 @@ def build_elect_view(entries: dict, name_to_id: dict, gen) -> ElectView:
                 continue
             # single UNKNOWN advertiser stays scalar (rare, and the
             # scalar path's reachable={} / local handling covers it)
-            complex_items.append((prefix, dict(per_node)))
+            add_complex(prefix, per_node)
             continue
         if all(_entry_plain(e) for e in per_node.values()):
             # known advertisers first, in NAME order — `best_nodes` /
@@ -169,9 +202,7 @@ def build_elect_view(entries: dict, name_to_id: dict, gen) -> ElectView:
                 m_entries.append(e)
                 m_names.append(n)
             continue
-        # copy: the live object mutates per_node dicts in place, and
-        # this view may outlive its instance via the shared cell
-        complex_items.append((prefix, dict(per_node)))
+        add_complex(prefix, per_node)
 
     multi: MultiTable | None = None
     if m_prefixes:
@@ -200,6 +231,11 @@ def build_elect_view(entries: dict, name_to_id: dict, gen) -> ElectView:
         orig=np.asarray(orig, dtype=np.int64),
         multi=multi,
         complex_items=complex_items,
+        complex_table=ComplexTable(
+            adv=np.asarray(c_adv, dtype=np.int64),
+            seg=np.asarray(c_seg, dtype=np.int64),
+            whole_graph=np.asarray(c_whole, dtype=np.int64),
+        ),
         gen=gen,
     )
 

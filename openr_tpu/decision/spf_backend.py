@@ -326,9 +326,12 @@ class TpuSpfSolver:
         # _mk_nexthops built with weights, and the (chosen advertiser,
         # first-hop slot) steps of its loop, the weighted election's
         # host work; multi_scoped: anycast prefixes a warm start's
-        # advertiser matrix named for re-election. gc_pause_ms /
-        # gc_full_collections: the garbage collector's process totals as
-        # they stood when the last solver call ended (_note_gc).
+        # advertiser matrix named for re-election; complex_scoped: the
+        # same for the complex (scalar fallback) items and their
+        # advertiser table, KSP items (always in) not counted.
+        # gc_pause_ms / gc_full_collections: the garbage collector's
+        # process totals as they stood when the last solver call ended
+        # (_note_gc).
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
@@ -342,6 +345,7 @@ class TpuSpfSolver:
             "ksp_path_nodes": 0,
             "general_prefixes": 0, "ucmp_prefixes": 0,
             "ucmp_slot_visits": 0, "multi_scoped": 0,
+            "complex_scoped": 0,
             "gc_pause_ms": 0.0, "gc_full_collections": 0,
         }
         # what prewarm_flap_programs has run its programs for: one key
@@ -1362,11 +1366,19 @@ class TpuSpfSolver:
                     self.spf_kernel_stats["multi_scoped"] += len(scoped)
                     for i in scoped:
                         touched.add(t.prefixes[i])
-                for p, _per in view.complex_items:
-                    # UCMP/KSP/constrained prefixes: KSP depends on the
-                    # whole graph and the rest are cheap — always
-                    # re-assemble (exact)
-                    touched.add(p)
+                # UCMP/constrained/mixed prefixes: the scalar election
+                # reads its advertisers' (dist, first-hop) classes and
+                # nothing else of the solve, so the advertiser table
+                # scopes them as the matrix above scopes anycast. Only
+                # KSP depends on the whole graph: those items are always
+                # re-assembled (exact)
+                ct = view.complex_table
+                scoped = np.setdiff1d(
+                    ct.seg[changed_mask[ct.adv]], ct.whole_graph
+                )  # sorted, unique
+                self.spf_kernel_stats["complex_scoped"] += len(scoped)
+                for i in np.concatenate((scoped, ct.whole_graph)).tolist():
+                    touched.add(view.complex_items[i][0])
             entries = self.assemble_prefix_routes(art2, ps, touched)
             with profiling.annotate("spf:warm_table_copy"):
                 rdb = RouteDatabase(this_node_name=my_node)

@@ -11,7 +11,9 @@ for byte. What the deployment added to the program:
     path (inside `spf:rib_unicast`), the warm path (inside
     `spf:warm_reassemble`) and the prefix-only path;
   * the counters `decision.spf.general_prefixes`, `.ucmp_prefixes`,
-    `.ucmp_slot_visits` and `.multi_scoped`.
+    `.ucmp_slot_visits` and `.multi_scoped`; since PR 37 `.complex_scoped`:
+    a warm start re-elects the weighted VIPs that a changed node
+    advertises, and no longer all of them.
 
 One story (module fixture): the first RIB; THE LINK raised (a warm start);
 ToR 16 changes the weight it advertises VIP 1 with (prefix-only); THE LINK
@@ -37,6 +39,7 @@ from openr_tpu.fib.fib import CLIENT_ID_OPENR
 from openr_tpu.messaging import ReplicateQueue
 from openr_tpu.monitor import Counters, names, perf, profiling
 from openr_tpu.types.kvstore import Publication, Value
+from openr_tpu.types.network import IpPrefix
 from openr_tpu.types.serde import to_wire
 from perfbench import compare, topo
 from perfbench.drivers.decision_fib import AREA
@@ -46,7 +49,10 @@ from perfbench.references import fabric_vips as reference
 sys.path.insert(0, str(Path(__file__).resolve().parent / "perfbench"))
 import vips_hand_case as hand  # noqa: E402
 
-COUNTED = ("general_prefixes", "ucmp_prefixes", "ucmp_slot_visits", "multi_scoped")
+COUNTED = ("general_prefixes", "ucmp_prefixes", "ucmp_slot_visits", "multi_scoped",
+           "complex_scoped")
+#: VIP 2 (advertisers 13 and 15): weighted, and untouched by THE LINK
+VIP_2 = IpPrefix.make(hand.vip_prefix(2))
 REBUILDS = ("decision.rebuild.full", "decision.rebuild.topo_delta",
             "decision.rebuild.prefix_only", "decision.spf.warm_starts")
 
@@ -124,6 +130,9 @@ async def run_story() -> dict:
                 and dec.rib.mpls_routes == oracle.mpls_routes),
             "vip_routes_in_rib": sum(
                 str(p.prefix).startswith("10.200.") for p in dec.rib.unicast_routes),
+            # VIP 2's route objects: the area cache's and the merge book's
+            "vip_2": (dec._area_cache[AREA]["rdb"].unicast_routes[VIP_2],
+                      dec.rib.unicast_routes[VIP_2]),
         }
 
     async def event(label: str, key_vals: dict) -> dict:
@@ -241,24 +250,42 @@ def test_the_counters_read_the_hand_count(story):
     # election sees the four VIPs that state a weight (1, 2, 3, 5)
     assert story["cold"]["grew"] == {
         "general_prefixes": 4, "ucmp_prefixes": 4,
-        "ucmp_slot_visits": hand.SLOT_VISITS_ALL_AT_1, "multi_scoped": 0}
+        "ucmp_slot_visits": hand.SLOT_VISITS_ALL_AT_1, "multi_scoped": 0,
+        "complex_scoped": 0}
     # warm: ToR 14 changed: its loopback, the two anycast VIPs it
-    # advertises (0 and 6: named by the advertiser matrix), and all four
-    # weighted VIPs whether the event touched them or not (VIP 2 it did not)
+    # advertises (0 and 6: named by the advertiser matrix), and the three
+    # weighted VIPs it advertises (1, 3 and 5: named by the advertiser
+    # table). Not VIP 2 (advertisers 13 and 15): its two planes stay out
+    # of the slot visits
+    vip_2_visits = 2
     assert story["raised"]["grew"] == {
-        "general_prefixes": 1 + 2 + 4, "ucmp_prefixes": 4,
-        "ucmp_slot_visits": hand.SLOT_VISITS_LINK_RAISED, "multi_scoped": 2}
+        "general_prefixes": 1 + 2 + 3, "ucmp_prefixes": 3,
+        "ucmp_slot_visits": hand.SLOT_VISITS_LINK_RAISED - vip_2_visits,
+        "multi_scoped": 2, "complex_scoped": 3}
+    assert hand.SLOT_VISITS_LINK_RAISED - vip_2_visits == 3 + 1 + 3
     # prefix-only: VIP 1 alone; advertisers 14 (plane 0) and 16 (both)
     assert story["weight"]["grew"] == {
         "general_prefixes": 1, "ucmp_prefixes": 1, "ucmp_slot_visits": 1 + 2,
-        "multi_scoped": 0}
+        "multi_scoped": 0, "complex_scoped": 0}
     assert story["restored"]["grew"] == {
-        "general_prefixes": 1 + 2 + 4, "ucmp_prefixes": 4,
-        "ucmp_slot_visits": hand.SLOT_VISITS_ALL_AT_1, "multi_scoped": 2}
+        "general_prefixes": 1 + 2 + 3, "ucmp_prefixes": 3,
+        "ucmp_slot_visits": hand.SLOT_VISITS_ALL_AT_1 - vip_2_visits,
+        "multi_scoped": 2, "complex_scoped": 3}
+    assert hand.SLOT_VISITS_ALL_AT_1 - vip_2_visits == 4 + 2 + 4
+
+
+def test_a_weighted_vip_the_event_did_not_touch_keeps_its_route_object(story):
+    """VIP 2's `RibEntry` is elected once, cold; every later RIB holds
+    that object, in the area's cache and in the merge book."""
+    cold = story["cold"]["vip_2"]
+    assert cold[0].nexthops[0].weight == 1 and len(cold[0].nexthops) == 2
+    for step in ("raised", "weight", "restored"):
+        for got, first in zip(story[step]["vip_2"], cold):
+            assert got is first, step
 
 
 @pytest.mark.parametrize("step", STEPS)
-def test_decision_exports_the_four_counters(story, step):
+def test_decision_exports_the_five_counters(story, step):
     # the export runs inside the rebuild, before its routes are pushed
     assert story[step]["exported"] == story[step]["held"]
 
@@ -326,11 +353,25 @@ def test_on_seeded_weights_a_warm_start_is_the_scalar_oracle_byte_for_byte(
         warm0 = solver.warm_solves
         res = solver.warm_compute_routes(art, ls, ps, me, pairs, set(), rdb, 0.25)
         assert res is not None and solver.warm_solves == warm0 + 1
+        art0, prev = art, rdb
         rdb, art, touched, _labels, _region = res
         oracle = oracle_compute_routes(ls, ps, me, vectorize=False)
         assert rdb.unicast_routes == oracle.unicast_routes, metric
         assert rdb.mpls_routes == oracle.mpls_routes, metric
-        # every weighted VIP is re-elected, whatever the link
+        # the link's ToR is the one node whose (distance, first hops)
+        # moved, read off the two solves
+        names = ls.to_csr().node_names
+        n = len(names)
+        moved = (art0.solved[1][:n, 0] != art.solved[1][:n, 0]) | (
+            art0.solved[2][:, :n] != art.solved[2][:, :n]).any(axis=0)
+        assert [names[i] for i in moved.nonzero()[0]] == [topo.node_name(t)]
+        # of the weighted VIPs exactly those that ToR advertises are
+        # re-elected; the others keep the cached route object
         weighted = {p for p in ps.prefixes if any(
             e.weight for e in ps.prefixes[p].values())}
-        assert len(weighted) == 8 and weighted <= touched
+        its_own = {p for p in weighted if topo.node_name(t) in ps.prefixes[p]}
+        assert len(weighted) == 8 and weighted & touched == its_own
+        # ToR (1, 0) advertises no weighted VIP, the other two cases' do
+        assert len(its_own) < 8 and bool(its_own) == ((pod, tor) != (1, 0))
+        for p in weighted - its_own:
+            assert rdb.unicast_routes.get(p) is prev.unicast_routes.get(p)
