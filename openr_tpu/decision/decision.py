@@ -463,6 +463,10 @@ class Decision(OpenrModule):
         self._area_solves = 0  # _compute_area invocations (SPF solves)
         self._rebuild_path = "full"  # path the last rebuild took
         self._rebuild_cached_areas = 0
+        # the last rebuild's dirt held a structural topology change
+        # (`_note_dirt`'s None: adjacency set, overload bit, label,
+        # weight, expiry), the first RIB's included: every key is new
+        self._rebuild_structural = False
         # ---- delta merge book -----------------------------------------
         # self.rib IS the merge book: a persistent merged RIB that
         # scoped rebuilds patch in place with the RouteUpdate produced
@@ -1375,6 +1379,7 @@ class Decision(OpenrModule):
                 # anything arriving later stays pending for the rebuild
                 # that will actually contain it
                 dirt, self._dirty = self._dirty, {}
+                self._rebuild_structural = None in dirt.values()
                 ps_bumps, self._dirty_ps_bumps = self._dirty_ps_bumps, {}
                 ls_bumps, self._dirty_ls_bumps = self._dirty_ls_bumps, {}
             with profiling.annotate("decision:compute_diff"):
@@ -1467,6 +1472,8 @@ class Decision(OpenrModule):
                 self.counters.increment("decision.rebuild.topo_delta")
             else:
                 self.counters.increment("decision.rebuild.full")
+            if self._rebuild_structural:
+                self.counters.increment("decision.rebuild.structural")
             if self._rebuild_cached_areas:
                 self.counters.increment(
                     "decision.rebuild.cached_areas",

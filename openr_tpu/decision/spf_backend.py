@@ -292,8 +292,10 @@ class TpuSpfSolver:
         # scatter_calls: _scatter_set programs dispatched (table
         # patches + the warm start's INF scatters), each a host→device
         # round of its own
+        # upload_bytes: host bytes `_upload` placed on the device
         self.dev_cache_stats = {
             "uploads": 0, "patches": 0, "hits": 0, "scatter_calls": 0,
+            "upload_bytes": 0,
         }
         # observability for the split kernel's regime picks (round-3
         # verdict weak 5: GS chunking must never disable SILENTLY):
@@ -437,24 +439,29 @@ class TpuSpfSolver:
             self.dev_cache_stats["hits"] += 1
             return got
         self.dev_cache_stats["uploads"] += 1
-        # build the wanted set from the (already journal-complete) csr
+        # build the wanted set from the (already journal-complete) csr:
+        # the host's table build and the transfer named apart, so that
+        # a structural event's time on either can be read
+        # (docs/Monitor.md "Spans")
         if want == "split":
-            t = build_split_tables(
-                csr.edge_src, csr.edge_dst, csr.edge_metric, csr.num_nodes
-            )
-            vp2 = t["vp"]
-            over2 = np.zeros(vp2, dtype=bool)
-            m = min(vp2, csr.padded_nodes)
-            over2[:m] = csr.node_overloaded[:m]
+            with profiling.annotate("spf:table_build"):
+                t = build_split_tables(
+                    csr.edge_src, csr.edge_dst, csr.edge_metric,
+                    csr.num_nodes,
+                )
+                vp2 = t["vp"]
+                over2 = np.zeros(vp2, dtype=bool)
+                m = min(vp2, csr.padded_nodes)
+                over2[:m] = csr.node_overloaded[:m]
             dset = {
                 "vp": vp2,
-                "base_nbr": jnp.asarray(t["base_nbr"]),
-                "base_wgt": jnp.asarray(t["base_wgt"]),
-                "ov_ids": jnp.asarray(t["ov_ids"]),
-                "ov_nbr": jnp.asarray(t["ov_nbr"]),
-                "ov_wgt": jnp.asarray(t["ov_wgt"]),
-                "out_nbr": jnp.asarray(t["out_nbr"]),
-                "over": jnp.asarray(over2),
+                **self._upload({
+                    **{k: t[k] for k in (
+                        "base_nbr", "base_wgt", "ov_ids", "ov_nbr",
+                        "ov_wgt", "out_nbr",
+                    )},
+                    "over": over2,
+                }),
                 # host int: hop-count regime marker (0 = mixed metrics);
                 # cleared by _apply_patch_suffix when churn breaks it
                 "uniform_metric": t["uniform_metric"],
@@ -464,14 +471,29 @@ class TpuSpfSolver:
                 "ov_pos": t["ov_pos"],
             }
         else:
-            nbr, wgt = csr.dense_tables()
-            dset = {
-                "nbr": jnp.asarray(nbr),
-                "wgt": jnp.asarray(wgt),
-                "over": jnp.asarray(csr.node_overloaded),
-            }
+            with profiling.annotate("spf:table_build"):
+                nbr, wgt = csr.dense_tables()
+            dset = self._upload(
+                {"nbr": nbr, "wgt": wgt, "over": csr.node_overloaded}
+            )
         cache["sets"][want] = dset
         return dset
+
+    def _upload(self, tables: dict[str, np.ndarray]) -> dict:
+        """The host `tables` placed on the device and waited for, under
+        `spf:upload`; their bytes land in dev_cache_stats["upload_bytes"].
+        The wait is what makes the span the transfer's: without it the
+        span would time a dispatch and the transfer would be charged to
+        the wall of the kernel that runs next. It costs the event
+        nothing: that kernel waits for its tables either way, and a
+        base is uploaded once."""
+        with profiling.annotate("spf:upload"):
+            placed = {k: jnp.asarray(v) for k, v in tables.items()}
+            jax.block_until_ready(placed)  # orlint: disable=OR009 — once a topology base, off the steady path; the span has to end with the transfer
+        self.dev_cache_stats["upload_bytes"] += sum(
+            int(v.nbytes) for v in tables.values()
+        )
+        return placed
 
     def _apply_patch_suffix(self, cache, csr) -> None:
         """Scatter the unapplied journal suffix into each set the cache

@@ -50,6 +50,8 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.rebuild.cached_areas",
         "decision.rebuild.area_solves",
         "decision.rebuild.failed",
+        # rebuilds whose dirt held a structural topology change
+        "decision.rebuild.structural",
         # rebuilds published with an empty update: Fib is told nothing
         "decision.rebuild.no_change",
         # a rebuild's PrefixState snapshot: handed out again (no prefix
@@ -268,6 +270,8 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:prepare",               #       cold: to_csr + pads + dispatch
     "spf:dispatch",              #       device tables: hit, patch, build
     "spf:patch_scatter",         #         journal suffix → compiled scatters
+    "spf:table_build",           #         new base: the host's table build
+    "spf:upload",                #         new base: tables → device, waited for
     "spf:batched_solve",         #       cold fused kernel + packed fetch
     "spf:sharded_solve",         #       mesh kernel, dispatch only
     "spf:native_solve",          #       C++ host engine

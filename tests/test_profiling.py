@@ -480,6 +480,62 @@ def test_a_stamp_spans_two_threads_in_the_record_alone(monkeypatch):
     assert rec2.spans == []
 
 
+# ------------------------------- the spans of a topology base's tables
+
+
+def test_a_structural_rebuild_opens_table_build_and_upload_once_under_dispatch():
+    """`spf:table_build` and `spf:upload` (PR 38): a topology base the
+    device has no tables of opens each once, as children of
+    `spf:dispatch` whose sum it holds, and `upload_bytes` moves by the
+    tables' bytes; a metric-only change on that base opens neither."""
+    import dataclasses
+
+    from openr_tpu.decision.linkstate import LinkState, PrefixState
+    from openr_tpu.decision.spf_backend import TpuSpfSolver
+    from openr_tpu.utils.topogen import fat_tree
+
+    ls, ps = LinkState(), PrefixState()
+    adj_dbs, prefix_dbs = fat_tree(4)
+    for db in adj_dbs:
+        ls.update_adjacency_db(db)
+    for db in prefix_dbs:
+        ps.update_prefix_db(db)
+    me = adj_dbs[-1].this_node_name
+    solver = TpuSpfSolver(native_rib="off")
+    solver.compute_routes(ls, ps, me)
+
+    def rebuild():
+        bytes0 = solver.dev_cache_stats["upload_bytes"]
+        with profiling.collect() as rec:
+            solver.compute_routes(ls, ps, me)
+        return rec, solver.dev_cache_stats["upload_bytes"] - bytes0
+
+    # an overload bit: structural, so the CSR and its tables are built anew
+    drained = dataclasses.replace(adj_dbs[0], is_overloaded=True)
+    assert ls.update_adjacency_db_delta(drained) == (True, None)
+    rec, uploaded = rebuild()
+    opened = [(n, p) for n, p, _s, _e in rec.spans]
+    assert opened.count(("spf:table_build", "spf:dispatch")) == 1
+    assert opened.count(("spf:upload", "spf:dispatch")) == 1
+    assert [n for n, _p in opened].count("spf:dispatch") == 1
+    ms = rec.ms
+    assert 0 < ms["spf:table_build"] and 0 < ms["spf:upload"]
+    assert ms["spf:table_build"] + ms["spf:upload"] <= ms["spf:dispatch"]
+    split = solver._dev[ls.to_csr().base_version]["sets"]["split"]
+    tables = [v for v in split.values() if hasattr(v, "nbytes")]
+    assert len(tables) == 7 and uploaded == sum(int(v.nbytes) for v in tables) > 0
+    assert bool(split["over"].any())
+    # a metric alone: the base stays, its tables are patched where they lie
+    adjs = drained.adjacencies
+    raised = dataclasses.replace(drained, adjacencies=(
+        dataclasses.replace(adjs[0], metric=7), *adjs[1:]))
+    changed, pairs = ls.update_adjacency_db_delta(raised)
+    assert changed and pairs
+    rec, uploaded = rebuild()
+    assert not {"spf:table_build", "spf:upload"} & {n for n, *_ in rec.spans}
+    assert "spf:patch_scatter" in rec.ms and uploaded == 0
+
+
 # ------------------------------------------------------ the vocabulary
 
 #: `stamp` makes a span of the record alone; a collection's span is made
@@ -528,7 +584,7 @@ def test_every_span_name_is_registered_prefixed_and_documented():
         "spf:gc", "decision:gc", "decision:merge_scope", "decision:merge_full",
         "spf:warm_scope", "spf:general_items", "spf:warm_table_copy",
         "spf:warm_labels", "decision:thread_start", "decision:thread_return",
-        "spf:ksp_prepare",
+        "spf:ksp_prepare", "spf:table_build", "spf:upload",
     }
     assert new <= set(names.REBUILD_SPANS)
     prefix = re.compile(r"^(spf|decision|fib|kvstore):[a-z_]+$")
