@@ -201,6 +201,32 @@ def _metric_only_delta(
     return delta
 
 
+def _overload_only(old: AdjacencyDatabase, new: AdjacencyDatabase) -> bool:
+    """Whether `new` differs from `old` in the node-overload bit alone:
+    the one structural change that moves no edge, id, detail or table
+    shape of the CSR, only `node_overloaded` (Open/R's drain)."""
+    return (
+        old.is_overloaded != new.is_overloaded
+        and old.this_node_name == new.this_node_name
+        and old.node_label == new.node_label
+        and old.adjacencies == new.adjacencies
+    )
+
+
+def _cell_edges(cell: list) -> int | None:
+    """Edge count of the base a CSR cell holds or will derive (see
+    `LinkState._rebase`), None where it has neither."""
+    while True:
+        # the derivation is read before the base: `_rebase` sets the
+        # base first and drops the derivation after
+        derive = cell[3]
+        if cell[0] is not None:
+            return cell[0].num_edges
+        if derive is None:
+            return None
+        cell = derive[0]
+
+
 class LinkState:
     """The per-area adjacency graph (reference: openr/decision/LinkState †).
 
@@ -240,8 +266,10 @@ class LinkState:
         # replaces the cell instead of clearing it, so snapshots taken
         # before a structural change keep their own still-valid cache;
         # within one cell only the (serialized) rebuild thread writes
-        # slots 1-2.
-        self._csr_cell: list = [None, None, 0]
+        # slots 1-2. Slot 3 is the derivation of a cell installed by an
+        # overload-only change, (parent cell, parent's pending, node,
+        # bit): to_csr turns it into a base without a build (_rebase).
+        self._csr_cell: list = [None, None, 0, None]
         # metric-only changes since the base CSR in the cell: applied
         # copy-on-write at to_csr() time (one array copy per solve, not
         # per flap), so churn never pays the O(E) python rebuild.
@@ -250,6 +278,10 @@ class LinkState:
         # rebinding append preserves the prefix, so an index into one
         # snapshot's list addresses the same flaps in every later one.
         self._pending: list[tuple[str, Adjacency]] = []
+        # how to_csr served (decision.csr.*): `builds` ran _build_csr,
+        # `overload_rebases` derived a base from an overload-only
+        # change. Shared with snapshots, which do the rebuilds' work.
+        self.csr_stats = {"builds": 0, "overload_rebases": 0}
 
     # ---- mutation ---------------------------------------------------------
 
@@ -271,7 +303,11 @@ class LinkState:
         change (adjacency set, overload bits, labels, weights, first
         insert). Decision's topology-delta rebuild classifier consumes
         the pairs; everything else keeps the plain bool contract via
-        `update_adjacency_db`."""
+        `update_adjacency_db`.
+
+        A change of the node-overload bit alone is structural to the
+        classifier, but the CSR keeps it as data: the cached view is
+        re-based with the bit flipped (`_rebase`) instead of dropped."""
         old = self._adj_dbs.get(db.this_node_name)
         if old == db:
             return False, []
@@ -286,18 +322,28 @@ class LinkState:
             if delta is not None
             else None
         )
-        base = self._csr_cell[0]
-        if base is not None and delta is not None:
+        cell = self._csr_cell
+        edges = _cell_edges(cell)
+        if edges is not None and delta is not None:
             if (
                 len(self._pending) + len(delta)
-                <= max(64, base.num_edges // 8)  # compaction cap
+                <= max(64, edges // 8)  # compaction cap
             ):
                 self._pending = self._pending + [
                     (db.this_node_name, a) for a in delta
                 ]
                 # cell's patched view stays: to_csr applies the suffix
                 return True, pairs
-        self._csr_cell = [None, None, 0]
+        elif edges is not None and old is not None and _overload_only(old, db):
+            # the next base is this cell's view with one bit flipped; a
+            # fresh cell, so snapshots of this one keep the old mask
+            self._csr_cell = [
+                None, None, 0,
+                (cell, self._pending, db.this_node_name, db.is_overloaded),
+            ]
+            self._pending = []
+            return True, pairs
+        self._csr_cell = [None, None, 0, None]
         self._pending = []
         return True, pairs
 
@@ -305,7 +351,7 @@ class LinkState:
         if node in self._adj_dbs:
             del self._adj_dbs[node]
             self.rev += 1
-            self._csr_cell = [None, None, 0]
+            self._csr_cell = [None, None, 0, None]
             self._pending = []
             return True
         return False
@@ -323,6 +369,7 @@ class LinkState:
         # the current reference is race-free; the patched view travels
         # in the shared cell
         snap._pending = self._pending
+        snap.csr_stats = self.csr_stats
         return snap
 
     # ---- queries ----------------------------------------------------------
@@ -390,15 +437,53 @@ class LinkState:
         With metric-only churn pending, returns a copy-on-write patched
         view of the cached base — O(E) numpy copies + O(patches) fixups
         instead of the O(E) python rebuild — carrying the cumulative
-        patch journal for the solver's device-array cache.
+        patch journal for the solver's device-array cache. After an
+        overload-only change the base is derived from the previous view
+        by one bit (`_rebase`), O(V) instead of the build.
         """
         cell = self._csr_cell
         if cell[0] is None:
-            cell[0] = self._build_csr()
-            cell[1], cell[2] = None, 0
-            self._pending = []
+            if cell[3] is not None:
+                self._rebase(cell)
+                self.csr_stats["overload_rebases"] += 1
+            else:
+                cell[0] = self._build_csr()
+                cell[1], cell[2] = None, 0
+                self._pending = []
+                self.csr_stats["builds"] += 1
+        # rebound-on-append: stable view
+        return self._cell_view(cell, self._pending)
+
+    def _rebase(self, cell: list) -> None:
+        """Give a cell installed by overload-only changes its base: the
+        parent cell's view (its base plus the metric patches pending
+        when the bit changed) with the node's bit set or cleared, under
+        a fresh `base_version` and an empty journal. Every other array
+        and map is the parent's, shared by reference: the bit moves no
+        edge, id or detail. Cells chain (several flips before one
+        to_csr): each link is one more O(V) copy of the mask."""
+        chain = []
+        while True:
+            derive = cell[3]  # before the base: see _cell_edges
+            if cell[0] is not None:
+                break
+            chain.append((cell, derive))
+            cell = derive[0]
+        for child, (parent, pending, node, bit) in reversed(chain):
+            view = self._cell_view(parent, pending)
+            over = view.node_overloaded.copy()
+            over[view.name_to_id[node]] = bit
+            ver = next(_csr_version)
+            child[0] = replace(
+                view, node_overloaded=over,
+                version=ver, base_version=ver, patches=(),
+            )
+            child[3] = None
+
+    def _cell_view(self, cell: list, pending: list) -> CsrGraph:
+        """`cell`'s base with `pending` applied, the progress published
+        through the cell."""
         base = cell[0]
-        pending = self._pending  # rebound-on-append: stable view
         if not pending:
             return base
         patched, upto = cell[1], cell[2]

@@ -52,6 +52,10 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.rebuild.failed",
         # rebuilds whose dirt held a structural topology change
         "decision.rebuild.structural",
+        # how LinkState.to_csr served: a build (_build_csr) / a base
+        # derived from an overload-only change without one
+        "decision.csr.builds",
+        "decision.csr.overload_rebases",
         # rebuilds published with an empty update: Fib is told nothing
         "decision.rebuild.no_change",
         # a rebuild's PrefixState snapshot: handed out again (no prefix

@@ -8,7 +8,8 @@ to the program:
   * the spans `spf:table_build` and `spf:upload`, children of
     `spf:dispatch` in `TpuSpfSolver._device_arrays`;
   * the counters `decision.dev_cache.upload_bytes` and
-    `decision.rebuild.structural`.
+    `decision.rebuild.structural`; since PR 39 `decision.csr.builds` and
+    `decision.csr.overload_rebases` (an overload bit re-bases the CSR).
 
 One story (module fixture): the first RIB with one aggregation switch and
 one spine drained; an aggregation switch of another pod drained; a ToR
@@ -42,7 +43,8 @@ K = 6
 COUNTED = ("decision.rebuild.full", "decision.rebuild.structural",
            "decision.rebuild.topo_delta", "decision.spf.warm_starts",
            "decision.dev_cache.uploads", "decision.dev_cache.upload_bytes",
-           "decision.spf.engine_native")
+           "decision.spf.engine_native", "decision.csr.builds",
+           "decision.csr.overload_rebases")
 
 
 async def run_story() -> dict:
@@ -252,20 +254,27 @@ def test_a_drained_neighbour_of_the_root_is_a_next_hop_toward_itself_only(story)
 def test_each_step_took_the_path_it_is_named_for(story):
     # the first RIB's dirt is structural too: every key is new
     assert story["cold"]["grew"]["decision.rebuild.structural"] == 1
+    assert story["cold"]["grew"]["decision.csr.builds"] == 1
     for step in STRUCTURAL:
         grew = story[step]["grew"]
         assert grew["decision.rebuild.full"] == 1, step
         assert grew["decision.rebuild.structural"] == 1, step
         assert grew["decision.dev_cache.uploads"] == 1, step
         assert grew["decision.spf.warm_starts"] == 0, step
+        # an overload bit re-bases the CSR: no walk over the adjacencies
+        assert grew["decision.csr.overload_rebases"] == 1, step
+        assert grew["decision.csr.builds"] == 0, step
     raised = story["raised"]["grew"]
     assert raised["decision.rebuild.topo_delta"] == 1
     assert raised["decision.spf.warm_starts"] == 1
     assert raised["decision.rebuild.structural"] == 0
     assert raised["decision.dev_cache.uploads"] == 0
     assert raised["decision.dev_cache.upload_bytes"] == 0
+    assert raised["decision.csr.builds"] == 0
+    assert raised["decision.csr.overload_rebases"] == 0
     assert all(story[s]["grew"]["decision.spf.engine_native"] == 0 for s in STEPS)
-    assert "decision.rebuild.structural" in names.COUNTERS
+    assert {"decision.rebuild.structural", "decision.csr.builds",
+            "decision.csr.overload_rebases"} <= names.COUNTERS
 
 
 def test_every_new_base_uploads_its_tables_bytes_and_both_spans_say_so(story):
