@@ -35,7 +35,8 @@ Traffic parameters (perfbench/traffic/<mix>.json):
 Every event of the pool changes routes (a plane toward one pod dies or
 returns), so an event ends at its trace back from Fib at FIB_PROGRAMMED
 and at nothing else. Series: `latency_ms`, `marker.<NAME>_ms`,
-`decision.<span>_ms`, `fib.program_ms`, one entry an event each.
+`decision.<span>_ms`, `fib.program_ms`, one entry an event each; `work`:
+the least work of an event's cold solve, as `solver_rebuild` gives it.
 """
 
 from __future__ import annotations
@@ -94,6 +95,7 @@ async def _run(ctx) -> dict:
         coalesce_route_updates,
     )
     from openr_tpu.monitor import Counters, perf
+    from openr_tpu.ops.spf import pad_batch
     from openr_tpu.types.kvstore import Publication, Value
     from openr_tpu.types.serde import to_wire
 
@@ -308,10 +310,19 @@ async def _run(ctx) -> dict:
             )
         )
     )
+    # the least work of an event's cold solve (perfbench/work.py), for
+    # the roofline's reader: as `solver_rebuild` counts it
+    n_nbrs = int((g.src == root).sum())
+    work = {
+        "nodes": g.n, "edges": g.num_edges,
+        "batch": int(pad_batch(1 + n_nbrs)),
+        "out_bytes": since["fetched_bytes"] / max(window.events, 1),
+    }
     return {
         "attempted": attempted,
         "failed": failed,
         "series": series,
         "counters": delta,
         "checks": checks,
+        "work": work,
     }

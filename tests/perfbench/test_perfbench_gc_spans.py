@@ -2,11 +2,18 @@
 merge fold, the parts of the warm reassembly and the solver thread's
 hand-off. Every twin's run gives each of its cell's new metrics a number;
 a program without the spans and counters (the parent) gives none, not 0;
-a full collection planted inside a rebuild shows in all three readings."""
+a full collection planted inside a rebuild shows in all three readings.
+
+The entries are held by what they mean, not by where they sit: a later PR
+appends entries after them and cells to their `workloads` (PR 40). The
+checks of the listing take the benchmark as a dict, so that
+test_perfbench_contract.py can run them on a benchmark with one entry and
+one cell more."""
 
 import gc
 import json
 import time
+from pathlib import Path
 
 import pytest
 from perfbench_util import REPO, TINY_CELLS, load_benchmark, tiny_checkout
@@ -19,20 +26,64 @@ NEW = {
     "merge_fold_ms", "warm_scope_ms", "warm_table_copy_ms", "warm_labels_ms",
     "warm_reassemble_unattributed_ms", "thread_start_ms", "thread_return_ms",
 }
-EVENT_CELLS = {
+ER = {"gc_pause_ms_per_rebuild", "gc_full_collections.er100k"}
+#: the event cells of PR 36's time, in the order its entries list them
+EVENT_CELLS = [
     "fabric10k.metric_flap", "lsdb100k.cost_out_in",
     "backbone_ksp.circuit_cost_out", "fabric_vips.tor_uplink_flap",
-}
+]
 
 
-def spec_of(name: str) -> dict:
+def spec_of(name: str, root: Path = REPO) -> dict:
     return json.loads(
-        (REPO / "perfbench" / "layer_metrics" / f"{name}.json").read_text())
+        (root / "perfbench" / "layer_metrics" / f"{name}.json").read_text())
 
 
-def new_metrics_of(real_cell: str) -> list[dict]:
-    return [m for m in load_benchmark()["per_layer"]
+def new_metrics_of(bench: dict, real_cell: str) -> list[dict]:
+    return [m for m in bench["per_layer"]
             if m["name"] in NEW and real_cell in m["workloads"]]
+
+
+def driver_of_cell(bench: dict, cell: str, root: Path = REPO) -> str:
+    mix = next(w["traffic"] for w in bench["workloads"] if w["name"] == cell)
+    return json.loads(
+        (root / "perfbench" / "traffic" / f"{mix}.json").read_text())["driver"]
+
+
+def check_the_twelve_entries(bench: dict, root: Path = REPO) -> None:
+    """PR 36's entries, wherever they sit in `per_layer`: the ten event
+    metrics list PR 36's four event cells first, in order, and after them
+    only cells of the `decision_fib` family of drivers (their series and
+    counters); the er100k pair lists er100k alone."""
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    assert NEW <= set(per_layer)
+    for name in NEW:
+        m = per_layer[name]
+        assert m["better"] == "lower"
+        if name in ER:
+            assert set(m["workloads"]) == {"er100k.full_rib"}
+        else:
+            assert m["workloads"][:4] == EVENT_CELLS, name
+            for later in m["workloads"][4:]:
+                assert driver_of_cell(bench, later, root).startswith(
+                    "decision_fib"), (name, later)
+        assert m["moves"] == ("full_rib_ms" if name in ER else "event_to_fib_p50_ms")
+        spec = spec_of(name, root)
+        assert spec["reader"] in {
+            "series_stat", "series_residual", "counter_per_event", "counter_sum"}
+    assert {per_layer[n]["layer"] for n in NEW} == {
+        "runtime (python gc)", "Decision publish", "election + assembly",
+        "Decision queue + debounce",
+    }
+
+
+def check_every_cell_is_measured_for_the_collector(bench: dict) -> None:
+    """What the twins' count of ten (two on er100k) stood for: every cell
+    is listed under a `gc_pause_ms_per_*` entry."""
+    for w in bench["workloads"]:
+        assert any(m["name"].startswith("gc_pause_ms_per_")
+                   and w["name"] in m.get("workloads", [w["name"]])
+                   for m in bench["per_layer"]), w["name"]
 
 
 def read(name: str, obs: dict):
@@ -64,23 +115,7 @@ def observed(checkout, cell, monkeypatch, seconds=1.0) -> dict:
 
 
 def test_the_twelve_entries_are_what_the_issue_lists():
-    per_layer = {m["name"]: m for m in load_benchmark()["per_layer"]}
-    assert NEW <= set(per_layer)
-    assert list(per_layer)[-12:] == [
-        m for m in per_layer if m in NEW], "appended, in one block, at the end"
-    for name in NEW:
-        m = per_layer[name]
-        assert m["better"] == "lower"
-        er = name in ("gc_pause_ms_per_rebuild", "gc_full_collections.er100k")
-        assert set(m["workloads"]) == ({"er100k.full_rib"} if er else EVENT_CELLS)
-        assert m["moves"] == ("full_rib_ms" if er else "event_to_fib_p50_ms")
-        spec = spec_of(name)
-        assert spec["reader"] in {
-            "series_stat", "series_residual", "counter_per_event", "counter_sum"}
-    assert {per_layer[n]["layer"] for n in NEW} == {
-        "runtime (python gc)", "Decision publish", "election + assembly",
-        "Decision queue + debounce",
-    }
+    check_the_twelve_entries(load_benchmark())
 
 
 @pytest.mark.parametrize("cell", sorted(TINY_CELLS))
@@ -88,8 +123,10 @@ def test_every_twin_gives_each_new_metric_of_its_cell_a_number(
     checkout, cell, monkeypatch,
 ):
     obs = observed(checkout, cell, monkeypatch)
-    mine = new_metrics_of(TINY_CELLS[cell])
-    assert len(mine) == (2 if TINY_CELLS[cell] == "er100k.full_rib" else 10)
+    bench = load_benchmark()
+    check_every_cell_is_measured_for_the_collector(bench)
+    mine = new_metrics_of(bench, TINY_CELLS[cell])
+    assert any(m["name"].startswith("gc_pause_ms_per_") for m in mine)
     got = {m["name"]: read(m["name"], obs) for m in mine}
     assert all(type(v) is float for v in got.values()), got
     for name, value in got.items():
