@@ -43,6 +43,7 @@ from openr_tpu.monitor import (
 )
 from openr_tpu.monitor import device as device_telemetry
 from openr_tpu.types.kvstore import Publication, Value
+from openr_tpu.types.network import sorted_nexthops
 from openr_tpu.types.routes import (
     RouteDatabase,
     RouteUpdateType,
@@ -121,16 +122,40 @@ class _TopoDelta:
 
 def _fold_unicast(cur, entry):
     """One cross-area selection step for a unicast prefix: `entry` (from
-    a later-sorted area) folded into the current winner `cur`."""
+    a later-sorted area) folded into the current winner `cur`. The
+    winner keeps its own LFA backups; at a tie the backups are unioned
+    too (`_union_backups`; docs/Decision.md "Multi-area")."""
     ek = metric_key(entry.best_entry) if entry.best_entry else (0, 0, 0)
     ck = metric_key(cur.best_entry) if cur.best_entry else (0, 0, 0)
     if ek > ck or (ek == ck and entry.igp_cost < cur.igp_cost):
         return entry
     if ek == ck and entry.igp_cost == cur.igp_cost:
+        nexthops = _union_nexthops(cur.nexthops, entry.nexthops)
+        if not (cur.backup_nexthops or entry.backup_nexthops):
+            return replace(cur, nexthops=nexthops)
         return replace(
-            cur, nexthops=_union_nexthops(cur.nexthops, entry.nexthops)
+            cur, nexthops=nexthops, backup_nexthops=_union_backups(
+                cur.backup_nexthops, entry.backup_nexthops, nexthops
+            ),
         )
     return cur
+
+
+def _union_backups(a, b, primaries):
+    """Equal-cost multi-area backup union: both areas' LFA backups less
+    any (neighbor, interface) slot that is a primary of the merged route;
+    a slot in both keeps the least metric (the earlier area's at equal
+    metric)."""
+    taken = {(nh.neighbor_node, nh.if_name) for nh in primaries}
+    best: dict[tuple, object] = {}
+    for nh in (*a, *b):
+        key = (nh.neighbor_node, nh.if_name)
+        if key in taken:
+            continue
+        held = best.get(key)
+        if held is None or nh.metric < held.metric:
+            best[key] = nh
+    return sorted_nexthops(best.values())
 
 
 def _fold_mpls(cur, mentry):

@@ -22,8 +22,8 @@ Two advertiser shapes are vectorized:
     chosen set for the nexthop union.
 
 Everything else — KSP, UCMP weights, min_nexthop, mixed advertiser
-algorithms, LFA, installed policy — falls back to the engines' existing
-scalar paths (the fallback matrix in docs/Decision.md). Both engines
+algorithms, LFA in the oracle, installed policy — falls back to the
+engines' existing scalar paths (the fallback matrix in docs/Decision.md). Both engines
 (oracle NumPy, TPU backend NumPy-or-device) consume the same table and
 the same election algebra, so vectorized/scalar and engine/engine
 byte-parity hold by shared construction and are gated by tests.

@@ -295,7 +295,7 @@ def _lfa_backups(
     targets: list[str],
 ) -> tuple[NextHop, ...]:
     """RFC 5286 loop-free alternates toward `targets` — the oracle mirror
-    of TpuSpfSolver._mk_backup_nexthops / ops.spf.lfa_matrix:
+    of TpuSpfSolver._lfa_backups / ops.spf.lfa_matrix:
     dist_n(t) < dist_n(root) + dist_root(t), neighbor not already a
     primary first hop for any target, overloaded neighbors excluded
     unless they ARE the target."""

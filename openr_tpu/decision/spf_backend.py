@@ -19,6 +19,7 @@ path and the test suite asserts RouteDatabase equality between the two.
 from __future__ import annotations
 
 import logging
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,6 @@ from openr_tpu.common.constants import MPLS_LABEL_MIN
 from openr_tpu.decision.election import (
     elect_multi_np,
     iter_multi_winners,
-    multi_items,
 )
 from openr_tpu.decision.ksp import (
     normalize_weights,
@@ -331,6 +331,10 @@ class TpuSpfSolver:
         # advertiser matrix named for re-election; complex_scoped: the
         # same for the complex (scalar fallback) items and their
         # advertiser table, KSP items (always in) not counted.
+        # lfa_routes / lfa_nexthops / lfa_unprotected: with enable_lfa,
+        # routes _lfa_backups gave one or more backups, the backup next
+        # hops it built, and routes it left with none (RFC 6571's
+        # coverage is routes / (routes + unprotected)).
         # gc_pause_ms / gc_full_collections: the garbage collector's
         # process totals as they stood when the last solver call ended
         # (_note_gc).
@@ -348,6 +352,7 @@ class TpuSpfSolver:
             "general_prefixes": 0, "ucmp_prefixes": 0,
             "ucmp_slot_visits": 0, "multi_scoped": 0,
             "complex_scoped": 0,
+            "lfa_routes": 0, "lfa_nexthops": 0, "lfa_unprotected": 0,
             "gc_pause_ms": 0.0, "gc_full_collections": 0,
         }
         # what prewarm_flap_programs has run its programs for: one key
@@ -377,6 +382,11 @@ class TpuSpfSolver:
         self._uni_cache: dict = {}
         # class-level {label: RibMplsEntry} sub-dicts (MPLS section)
         self._mpls_cls_cache: dict = {}
+        # cross-rebuild LFA backups (_lfa_backups), same fingerprint
+        # scheme: {"rows": {backup metric row bytes: next hops},
+        # "routes": {prefix: (route, next hops, route with them)}}, so
+        # an unchanged route keeps its backups and its identity
+        self._lfa_cache: dict = {}
         self._mpls_fingerprint_cap = 8
         # nexthop-group intern table (types/routes.NexthopIntern): one
         # shared NexthopGroup object per distinct ECMP set across every
@@ -579,6 +589,8 @@ class TpuSpfSolver:
             self._uni_cache.pop(next(iter(self._uni_cache)))
         while len(self._mpls_cls_cache) > fingerprint_cap:
             self._mpls_cls_cache.pop(next(iter(self._mpls_cls_cache)))
+        while len(self._lfa_cache) > fingerprint_cap:
+            self._lfa_cache.pop(next(iter(self._lfa_cache)))
         # warm-start host index: cheap to rebuild (one argsort per
         # topology base), so a trim drops it entirely
         self._warm_out.clear()
@@ -974,10 +986,16 @@ class TpuSpfSolver:
             len(prefixes),
         )
         out: dict = {}
+        lfa_jobs = [] if lfa is not None else None
         with profiling.annotate("spf:unicast_general"):
             ksp_jobs = self._unicast_general(
-                csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids, lfa,
-                dist, slot_cache, mk_nexthops_cached, items, out,
+                csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids,
+                slot_cache, mk_nexthops_cached, items, out, lfa_jobs,
+            )
+        if lfa_jobs:
+            self._lfa_backups(
+                csr, my_id, nbr_ids, fh, lfa, dist, slot_cache, ls.area,
+                lfa_jobs, out,
             )
         if ksp_jobs:
             self._ksp_batch(csr, ls, my_node, my_id, d_root, ksp_jobs, out)
@@ -1473,27 +1491,13 @@ class TpuSpfSolver:
             # NextHop construction deduplicated by unique (first-hop-column,
             # igp) classes — in a fat-tree thousands of prefixes collapse to
             # a handful of classes. The general per-prefix loop below keeps
-            # every other case (anycast, UCMP, KSP, min_nexthop, LFA).
+            # every other case (anycast, UCMP, KSP, min_nexthop); LFA backups
+            # are one pass over the routes of all three (`_lfa_backups`).
             view = ps.election_view(csr.name_to_id, csr.base_version)
             plain_p, plain_n = view.plain_p, view.plain_n
             plain_e, orig = view.plain_e, view.orig
             complex_items, view_gen = view.complex_items, view.gen
             multi = view.multi
-            if lfa is not None:
-                # LFA backups are per-target, not per-class — every prefix
-                # takes the general scalar loop when LFA is enabled (the
-                # fallback matrix in docs/Decision.md)
-                merged = list(complex_items)
-                if len(plain_p):
-                    merged += [
-                        (p, {plain_n[i]: plain_e[i]})
-                        for i, p in enumerate(plain_p)
-                    ]
-                if multi is not None:
-                    merged += multi_items(multi)
-                complex_items = sorted(merged)
-                multi = None
-                plain_p = []
             self.elect_stats["plain"] = len(plain_p)
             self.elect_stats["multi"] = (
                 len(multi.prefixes) if multi is not None else 0
@@ -1539,6 +1543,9 @@ class TpuSpfSolver:
                         slot_cache, fh[:, orig[i]], int(igp[i]), ls.area
                     )
         with profiling.annotate("spf:rib_unicast"):
+            # with LFA: (prefix, chosen target ids) of every route written,
+            # for the one backups pass after the three sections
+            lfa_jobs = [] if lfa is not None else None
             cell = None
             if len(plain_p) or mel is not None:
                 # cross-rebuild RibEntry caches (same shape as the MPLS
@@ -1627,6 +1634,12 @@ class TpuSpfSolver:
                         plain_dict.update(sub)
                     cell["plain"] = (sig, plain_dict)
                     unicast.update(plain_dict)
+                if lfa_jobs is not None:
+                    lfa_jobs.extend(
+                        (plain_p[i], orig[i : i + 1])
+                        for i, c in zip(idxs.tolist(), cls.tolist())
+                        if class_nhs[c]
+                    )
 
             # ---- unicast: elected multi-advertiser (anycast ECMP) --------
             # entry construction per surviving prefix; the nexthop union is
@@ -1649,10 +1662,9 @@ class TpuSpfSolver:
                     fh[:, multi.adv].tobytes(),
                 )
                 cached_m = cell.get("multi")
-                if cached_m is not None and cached_m[0] == sig_m:
-                    rdb.unicast_routes.update(cached_m[1])
-                else:
+                if cached_m is None or cached_m[0] != sig_m:
                     mdict: dict = {}
+                    targets: list = []
                     for (
                         p, best_names, chosen_ids, chosen_names, igp_c, best_e
                     ) in iter_multi_winners(multi, mel):
@@ -1667,15 +1679,23 @@ class TpuSpfSolver:
                             best_entry=best_e,
                             igp_cost=igp_c,
                         )
-                    cell["multi"] = (sig_m, mdict)
-                    rdb.unicast_routes.update(mdict)
+                        targets.append((p, chosen_ids))
+                    cached_m = cell["multi"] = (sig_m, mdict, targets)
+                rdb.unicast_routes.update(cached_m[1])
+                if lfa_jobs is not None:
+                    lfa_jobs.extend(cached_m[2])
 
             # ---- unicast: general path -----------------------------------
             with profiling.annotate("spf:unicast_general"):
                 ksp_jobs = self._unicast_general(
                     csr, ls, my_node, my_id, d_root, fh, fh_any, nbr_ids,
-                    lfa, dist, slot_cache, mk_nexthops_cached, complex_items,
-                    rdb.unicast_routes,
+                    slot_cache, mk_nexthops_cached, complex_items,
+                    rdb.unicast_routes, lfa_jobs,
+                )
+            if lfa_jobs:
+                self._lfa_backups(
+                    csr, my_id, nbr_ids, fh, lfa, dist, slot_cache, ls.area,
+                    lfa_jobs, rdb.unicast_routes,
                 )
             if ksp_jobs:
                 self._ksp_batch(
@@ -1906,17 +1926,19 @@ class TpuSpfSolver:
         fh: np.ndarray,
         fh_any: np.ndarray,
         nbr_ids: list[int],
-        lfa,
-        dist,
         slot_cache: list[list[tuple[str, str]]],
         mk_nexthops_cached,
         items,
         out: dict,
+        lfa_jobs: list | None = None,
     ) -> list[tuple]:
         """The general per-prefix unicast path (anycast, UCMP, KSP,
         min_nexthop, LFA — and, on the scoped-reassembly path, plain
         prefixes too). Writes routes into `out`; returns the KSP jobs
-        for the caller's single batched `_ksp_batch` device call."""
+        for the caller's single batched `_ksp_batch` device call. With
+        `lfa_jobs` (a list), every route written is also appended to it
+        as (prefix, chosen target ids), for the caller's one
+        `_lfa_backups` pass."""
         ksp_jobs: list[tuple] = []  # (prefix, reachable, best_nodes)
         self.spf_kernel_stats["general_prefixes"] += len(items)
         for prefix, per_node in items:
@@ -1970,12 +1992,6 @@ class TpuSpfSolver:
             best_entry = reachable[chosen_names[0]]
             if best_entry.min_nexthop and len(nexthops) < best_entry.min_nexthop:
                 continue
-            backups: tuple[NextHop, ...] = ()
-            if lfa is not None:
-                backups = self._mk_backup_nexthops(
-                    csr, my_id, nbr_ids, fh, lfa, dist, chosen, ls.area,
-                    slot_cache,
-                )
             out[prefix] = RibEntry(
                 prefix=prefix,
                 nexthops=nexthops,
@@ -1983,8 +1999,9 @@ class TpuSpfSolver:
                 best_nodes=tuple(best_nodes),
                 best_entry=best_entry,
                 igp_cost=min_igp,
-                backup_nexthops=backups,
             )
+            if lfa_jobs is not None:
+                lfa_jobs.append((prefix, chosen))
         return ksp_jobs
 
     def _ksp_batch(
@@ -2173,52 +2190,104 @@ class TpuSpfSolver:
         self.spf_kernel_stats["ksp_jobs"] += len(jobs)
         self.spf_kernel_stats["ksp_path_nodes"] += path_nodes
 
-    @staticmethod
-    def _mk_backup_nexthops(
+    def _lfa_backups(
+        self,
         csr: CsrGraph,
         my_id: int,
         nbr_ids: list[int],
         fh: np.ndarray,
         lfa: np.ndarray,
-        dist: np.ndarray,
-        targets: np.ndarray,
-        area: str,
+        dist,
         slot_cache: list[list[tuple[str, str]]],
-    ) -> tuple[NextHop, ...]:
-        """LFA backups toward `targets`: loop-free neighbors that are not
-        already primary first hops for any target. Metric = best
-        via-neighbor path cost: metric(root→n) + min over targets of
-        dist_n(target)."""
-        n_real = len(nbr_ids)
-        is_primary = fh[:n_real, targets].any(axis=1)
-        is_lfa = lfa[:n_real, targets].any(axis=1)
-        out: dict[tuple[str, str], int] = {}
-        for n_idx in np.nonzero(is_lfa & ~is_primary)[0]:
-            col = 1 + int(n_idx)
-            # metric over the targets this neighbor is actually
-            # loop-free for (a shorter non-loop-free path must not win)
-            via = min(
-                int(dist[int(t), col])
-                for t in targets
-                if lfa[int(n_idx), int(t)]
+        area: str,
+        jobs: list[tuple],
+        out: dict,
+    ) -> None:
+        """RFC 5286 link-protecting backups of the routes the general
+        pass built (`jobs`: (prefix, chosen target ids)), one pass a
+        rebuild under `spf:lfa_backups`: a neighbor backs a route up
+        where it is loop-free toward one of its targets (`lfa`, the
+        kernel's `ops.spf.lfa_matrix`) and a primary first hop of none;
+        one next hop a min-metric link to it, metric = metric(root→n) +
+        the least dist_n(t) over the targets it is loop-free for (a
+        shorter path that is not loop-free must not win). The [vp, B]
+        distance matrix comes to the host once, under
+        `spf:lfa_dist_fetch`. A route given a backup is replaced in
+        `out`; spf_kernel_stats counts lfa_routes (given one or more),
+        lfa_nexthops (built) and lfa_unprotected (given none)."""
+        with profiling.annotate("spf:lfa_backups"):
+            with profiling.annotate("spf:lfa_dist_fetch"):
+                mat = np.asarray(dist)
+            n = len(nbr_ids)
+            link = np.array(
+                [min(d[1] for d in csr.details(my_id, v)) for v in nbr_ids],
+                np.int64,
             )
-            link = min(
-                d[1] for d in csr.details(my_id, nbr_ids[int(n_idx)])
-            )
-            m = link + via
-            for key in slot_cache[int(n_idx)]:
-                if key not in out or m < out[key]:
-                    out[key] = m
-        return sorted_nexthops(
-            NextHop(
-                address=fh_name,
-                if_name=if_name,
-                metric=m,
-                neighbor_node=fh_name,
-                area=area,
-            )
-            for (fh_name, if_name), m in out.items()
-        )
+            # a route's row over the neighbors: its backup metric through
+            # each, -1 where that neighbor is no backup. One target (a
+            # loopback) is the common shape: those rows in one go
+            rows: list = [None] * len(jobs)
+            single = [j for j, (_p, t) in enumerate(jobs) if len(t) == 1]
+            if single:
+                t = np.array([int(jobs[j][1][0]) for j in single], np.int64)
+                backs = (lfa[:n, t] & ~fh[:n, t]).T
+                for j, row in zip(single, np.where(
+                    backs, link[None, :] + mat[t, 1 : 1 + n], -1
+                )):
+                    rows[j] = row
+            for j, (_p, t) in enumerate(jobs):
+                if rows[j] is None:
+                    ok = lfa[:n, t]
+                    via = np.where(ok, mat[t, 1 : 1 + n].T, INF_DIST)
+                    backs = ok.any(axis=1) & ~fh[:n, t].any(axis=1)
+                    rows[j] = np.where(backs, link + via.min(axis=1), -1)
+            # a row's next hops depend on the row and the root's slots
+            # alone, so they are kept across rebuilds under the slots'
+            # fingerprint, and a route whose entry and backups are the
+            # objects of the last rebuild gets its last entry back: an
+            # event that moves few backups builds few objects
+            slot_gen = (area, tuple(tuple(sl) for sl in slot_cache))
+            cell = self._lfa_cache.pop(slot_gen, None) or {
+                "rows": {}, "routes": {},
+            }
+            self._lfa_cache[slot_gen] = cell
+            while len(self._lfa_cache) > self._mpls_fingerprint_cap:
+                self._lfa_cache.pop(next(iter(self._lfa_cache)))
+            memo, held = cell["rows"], cell["routes"]
+            for d in (memo, held):
+                if len(d) > 4 * max(len(jobs), 1024):
+                    d.clear()
+            given = built = 0
+            for (prefix, _t), row in zip(jobs, rows):
+                key = row.tobytes()
+                nhs = memo.get(key)
+                if nhs is None:
+                    nhs = memo[key] = sorted_nexthops(
+                        NextHop(
+                            address=name,
+                            if_name=if_name,
+                            metric=int(row[i]),
+                            neighbor_node=name,
+                            area=area,
+                        )
+                        for i in np.flatnonzero(row >= 0).tolist()
+                        for name, if_name in slot_cache[i]
+                    )
+                if not nhs:
+                    continue
+                route = out[prefix]
+                last = held.get(prefix)
+                if last is None or last[0] is not route or last[1] is not nhs:
+                    last = held[prefix] = (
+                        route, nhs, replace(route, backup_nexthops=nhs)
+                    )
+                out[prefix] = last[2]
+                given += 1
+                built += len(nhs)
+        st = self.spf_kernel_stats
+        st["lfa_routes"] += given
+        st["lfa_nexthops"] += built
+        st["lfa_unprotected"] += len(jobs) - given
 
     @staticmethod
     def _nbr_slot_cache(

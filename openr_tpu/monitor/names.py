@@ -72,6 +72,12 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.spf.warm_starts",
         "decision.spf.warm_fallbacks",
         "decision.spf.warm_region_nodes",
+        # LFA backups (TpuSpfSolver._lfa_backups, set from
+        # spf_kernel_stats): routes given one or more, next hops built,
+        # routes given none
+        "decision.spf.lfa_routes",
+        "decision.spf.lfa_nexthops",
+        "decision.spf.lfa_unprotected",
         "decision.spf_ms",
         "decision.spf_runs",
         "decision.spf_solve_ms",
@@ -285,6 +291,8 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:election",              #           device election (big tables)
     "spf:rib_unicast",           #         unicast RibEntries
     "spf:unicast_general",       #           the scalar election (warm too)
+    "spf:lfa_backups",           #           LFA backups, one pass a rebuild
+    "spf:lfa_dist_fetch",        #             [vp, B] matrix → host for them
     "spf:rib_mpls",              #         node-label routes
     "spf:ksp_prepare",           #         KSP: dests, masks, k clamp, dist0
     "spf:ksp",                   #         KSP prefixes' batched paths
