@@ -101,6 +101,16 @@ class CsrGraph:
     version: int = 0
     base_version: int = 0
     patches: tuple["MetricPatch", ...] = ()
+    # the base_version of the build whose edges, ids and details this
+    # view has: a constructor that builds a base sets it to that base's
+    # version, and a base `LinkState._rebase` derived keeps its
+    # parent's, so caches of structure alone survive an overload bit
+    structure_version: int = 0
+    # on a base `_rebase` derived: (parent base_version, parent view's
+    # version, its journal), what brings the parent's device tables to
+    # the view this base was derived from. Numbers, never the parent
+    # view: a chain of drains must not hold every earlier view alive
+    rebased_from: tuple[int, int, tuple["MetricPatch", ...]] | None = None
 
     def details(self, u: int, v: int):
         """Adjacency details for edge (u, v), override-aware."""
@@ -460,8 +470,10 @@ class LinkState:
         when the bit changed) with the node's bit set or cleared, under
         a fresh `base_version` and an empty journal. Every other array
         and map is the parent's, shared by reference: the bit moves no
-        edge, id or detail. Cells chain (several flips before one
-        to_csr): each link is one more O(V) copy of the mask."""
+        edge, id or detail, so the base keeps the parent's
+        `structure_version` and records in `rebased_from` the view it
+        came from. Cells chain (several flips before one to_csr): each
+        link is one more O(V) copy of the mask."""
         chain = []
         while True:
             derive = cell[3]  # before the base: see _cell_edges
@@ -477,6 +489,7 @@ class LinkState:
             child[0] = replace(
                 view, node_overloaded=over,
                 version=ver, base_version=ver, patches=(),
+                rebased_from=(view.base_version, view.version, view.patches),
             )
             child[3] = None
 
@@ -645,6 +658,7 @@ class LinkState:
             edge_index=edge_index,
             version=ver,
             base_version=ver,
+            structure_version=ver,
         )
 
 

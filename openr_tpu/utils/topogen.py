@@ -448,6 +448,7 @@ def erdos_renyi_lsdb(
         name_to_id=name_to_id,
         version=ver,
         base_version=ver,
+        structure_version=ver,
     )
     ps = PrefixState()
     for i, s in enumerate(names):

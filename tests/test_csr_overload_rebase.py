@@ -7,16 +7,24 @@ on small fat trees with parallel links and a drained link mix node
 overload flips (one node twice, two nodes before one `to_csr`), metric
 changes left pending across a flip, and the changes that must fall back
 to a build (a link's overload bit, an adjacency or a node added or
-removed); snapshots are taken before and after flips."""
+removed); snapshots are taken before and after flips.
+
+The solver keys on the same lineage: a re-based base carries its
+parent's device tables (`TpuSpfSolver._carry`) with its own overload mask
+placed anew, and equal routes hold whether it carries or builds."""
 
 import dataclasses
+import gc
 import random
+import weakref
 
 import numpy as np
 import pytest
 
-from openr_tpu.decision.linkstate import LinkState
-from openr_tpu.types.topology import Adjacency
+from openr_tpu.decision.linkstate import LinkState, PrefixState
+from openr_tpu.decision.oracle import compute_routes as oracle_compute_routes
+from openr_tpu.decision.spf_backend import TpuSpfSolver
+from openr_tpu.types.topology import Adjacency, ForwardingAlgorithm
 from openr_tpu.utils import topogen
 
 ARRAYS = ("edge_src", "edge_dst", "edge_metric", "node_overloaded",
@@ -79,6 +87,7 @@ class Story:
     seeded random sequence of changes."""
 
     def __init__(self, k: int, seed: int):
+        self.k = k
         self.rng = random.Random(seed)
         self.dbs = fabric(k, self.rng)
         self.ls = LinkState()
@@ -138,6 +147,7 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
     assert_same_csr(ls.to_csr(), built(st.dbs))
     assert stats == {"builds": 1, "overload_rebases": 0}
     bases = {ls.to_csr().base_version}
+    structure = ls.to_csr().structure_version
     kinds = set()
     # <= 2 metric entries a step over 24 steps: under the journal's
     # compaction cap (64), so only a fallback step builds
@@ -181,17 +191,27 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
         grew = {key: stats[key] - before[key] for key in stats}
         base = ls._csr_cell[0]
         assert got.base_version == base.base_version
+        assert got.structure_version == base.structure_version
         if kind in ("fallback", "fallback_flip"):
             assert grew == {"builds": 1, "overload_rebases": 0}, kind
+            # a build is a structure of its own
+            assert base.structure_version == base.base_version
+            assert base.rebased_from is None
         elif kind == "metric":
             assert grew == {"builds": 0, "overload_rebases": 0}, kind
         else:
-            # one re-base however many flips: a new base, an empty journal
+            # one re-base however many flips: a new base, an empty journal,
+            # the structure of the base before it
             assert grew == {"builds": 0, "overload_rebases": 1}, kind
             assert base.version == base.base_version
             assert base.patches == ()
             assert base.base_version not in bases
+            assert base.structure_version == structure
+            # derived from the last base, or from the middle of a chain
+            assert (base.rebased_from[0] in bases) == (
+                kind not in ("two_flips", "same_twice")), kind
         bases.add(base.base_version)
+        structure = base.structure_version
         # the snapshot taken before keeps its own cell, so its old mask
         assert_same_csr(snap.to_csr(), built(snap_dbs))
     assert {"flip", "fallback"} <= kinds
@@ -217,3 +237,159 @@ def test_a_snapshot_before_a_flip_keeps_the_old_mask():
     assert last.edge_metric is base.edge_metric
     assert last.adj_details is base.adj_details
     assert ls.csr_stats == {"builds": 1, "overload_rebases": 2}
+    # through the solver: each view solves with its own mask, the first
+    # by a build, the two re-based ones carried down the chain, each
+    # placing its own mask
+    solver, ps = device_solver(), prefixes(st)
+    for view in (before, between, ls, before):
+        assert_routes(solver, view, ps, ROOT)
+    assert solver.dev_cache_stats["uploads"] == 3
+    assert solver.dev_cache_stats["carried"] == 2
+
+
+ROOT = "node-0"
+TABLES = {
+    "split": ("base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt",
+              "out_nbr", "over"),
+    "dense": ("nbr", "wgt", "over"),
+}
+
+
+def device_solver() -> TpuSpfSolver:
+    return TpuSpfSolver(native_rib="off")
+
+
+def prefixes(st: Story, ksp: tuple = ()) -> PrefixState:
+    """One loopback a node of the story's fabric, KSP2_ED_ECMP on those
+    of `ksp`."""
+    ps = PrefixState()
+    for pdb in topogen.fat_tree(st.k)[1]:
+        if pdb.this_node_name in ksp:
+            pdb = dataclasses.replace(pdb, prefix_entries=tuple(
+                dataclasses.replace(
+                    e, forwarding_algorithm=ForwardingAlgorithm.KSP2_ED_ECMP)
+                for e in pdb.prefix_entries))
+        ps.update_prefix_db(pdb)
+    return ps
+
+
+def assert_routes(solver, ls, ps, me: str):
+    """The device solver's RIB of `ls` is the scalar oracle's."""
+    got = solver.compute_routes(ls, ps, me)
+    want = oracle_compute_routes(ls, ps, me, vectorize=False)
+    assert got.unicast_routes == want.unicast_routes
+    assert got.mpls_routes == want.mpls_routes
+    return got
+
+
+def assert_fresh_tables(solver, csr) -> dict:
+    """Each table set the solver holds for `csr`'s base is what a new
+    solver builds for `csr`, array for array; returns them as host
+    arrays."""
+    sets = solver._dev[csr.base_version]["sets"]
+    held = {}
+    for name, dset in sets.items():
+        ref = device_solver()._device_arrays(csr, name)
+        assert set(dset) == set(ref), name
+        for key in TABLES[name]:
+            np.testing.assert_array_equal(
+                np.asarray(dset[key]), np.asarray(ref[key]),
+                err_msg=f"{name}.{key}")
+        held[name] = {key: np.asarray(dset[key]) for key in TABLES[name]}
+    return held
+
+
+def patched_metric(st: Story) -> None:
+    """One metric change that patches the CSR (not one on a drained
+    link, which no edge carries)."""
+    for _ in range(20):
+        st.metric()
+        if st.ls.to_csr().patches:
+            return
+    raise AssertionError("no metric change reached an edge")
+
+
+def undrained(st: Story) -> str:
+    return next(n for n in sorted(st.dbs)
+                if n != ROOT and not st.dbs[n].is_overloaded)
+
+
+@pytest.mark.parametrize("ksp", [(), ("node-19",)], ids=["spf", "ksp"])
+def test_a_carried_base_is_a_fresh_build_and_a_patch_leaves_its_parent_alone(ksp):
+    st = Story(4, 11)
+    ls, ps = st.ls, prefixes(st, ksp)
+    solver = device_solver()
+    assert_routes(solver, ls, ps, ROOT)
+    # the view the flip derives from is patched and never solved: the
+    # carry first brings the parent's entry forward to it
+    patched_metric(st)
+    parent = ls.to_csr()
+    stats = solver.dev_cache_stats
+    uploads = stats["uploads"]
+    st.flip(undrained(st))
+    assert_routes(solver, ls, ps, ROOT)
+    child = ls.to_csr()
+    assert child.rebased_from == (
+        parent.base_version, parent.version, parent.patches)
+    assert child.structure_version == parent.structure_version
+    assert child.base_version != parent.base_version
+    # each set carried, its mask alone placed
+    sets = 2 if ksp else 1
+    assert (stats["uploads"], stats["carried"]) == (uploads + sets, sets)
+    assert_fresh_tables(solver, child)
+    before = assert_fresh_tables(solver, parent)
+    # a metric patch of the carried base scatters into its own tables
+    patched_metric(st)
+    assert_routes(solver, ls, ps, ROOT)
+    view = ls.to_csr()
+    assert view.base_version == child.base_version and view.patches
+    assert view.structure_version == parent.structure_version
+    assert (stats["uploads"], stats["carried"]) == (uploads + sets, sets)
+    assert_fresh_tables(solver, view)
+    entry = solver._dev[parent.base_version]
+    for name, arrays in before.items():
+        for key, arr in arrays.items():
+            np.testing.assert_array_equal(
+                np.asarray(entry["sets"][name][key]), arr,
+                err_msg=f"{name}.{key}")
+
+
+@pytest.mark.parametrize("flips", ["two_nodes", "one_node_twice"])
+def test_two_flips_with_no_solve_between_build_to_the_same_routes(flips):
+    """The middle base never reached the device, so the last one has no
+    parent entry to carry: it is built, to the routes a new solver
+    gives."""
+    st = Story(4, 5)
+    ls, ps = st.ls, prefixes(st)
+    solver = device_solver()
+    assert_routes(solver, ls, ps, ROOT)
+    a = undrained(st)
+    st.flip(a)
+    st.flip(a if flips == "one_node_twice" else undrained(st))
+    got = assert_routes(solver, ls, ps, ROOT)
+    assert ls.csr_stats["overload_rebases"] == 1
+    stats = solver.dev_cache_stats
+    assert (stats["uploads"], stats["carried"]) == (2, 0)
+    assert_fresh_tables(solver, ls.to_csr())
+    fresh = device_solver().compute_routes(ls, ps, ROOT)
+    assert got.unicast_routes == fresh.unicast_routes
+    assert got.mpls_routes == fresh.mpls_routes
+
+
+def test_a_rebased_base_keeps_no_view_of_its_parent_alive():
+    """A drain records numbers of its parent, not the parent: a chain of
+    drains holds one view, not every earlier one."""
+    st = Story(4, 3)
+    ls, ps = st.ls, prefixes(st)
+    solver = device_solver()
+    patched_metric(st)
+    assert_routes(solver, ls, ps, ROOT)
+    parent = weakref.ref(ls.to_csr())
+    assert parent() is not None
+    st.flip(undrained(st))
+    assert_routes(solver, ls, ps, ROOT)
+    child = ls.to_csr()
+    assert solver.dev_cache_stats["carried"] == 1
+    gc.collect()
+    assert parent() is None
+    assert child.rebased_from[0] != child.base_version

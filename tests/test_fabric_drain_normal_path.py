@@ -9,7 +9,11 @@ to the program:
     `spf:dispatch` in `TpuSpfSolver._device_arrays`;
   * the counters `decision.dev_cache.upload_bytes` and
     `decision.rebuild.structural`; since PR 39 `decision.csr.builds` and
-    `decision.csr.overload_rebases` (an overload bit re-bases the CSR).
+    `decision.csr.overload_rebases` (an overload bit re-bases the CSR);
+    `decision.dev_cache.carried`, the table sets a re-based base took over
+    from its parent's device entry instead of building them: only the
+    first RIB builds and uploads every table, a drain or an undrain
+    builds and uploads its overload mask alone.
 
 One story (module fixture): the first RIB with one aggregation switch and
 one spine drained; an aggregation switch of another pod drained; a ToR
@@ -22,6 +26,7 @@ import asyncio
 import dataclasses
 import time
 
+import numpy as np
 import pytest
 
 from openr_tpu.common import constants as C
@@ -32,6 +37,7 @@ from openr_tpu.fib import Fib, MockFibHandler
 from openr_tpu.fib.fib import CLIENT_ID_OPENR
 from openr_tpu.messaging import ReplicateQueue
 from openr_tpu.monitor import Counters, compile_ledger, names, perf
+from openr_tpu.ops.spf_split import build_split_tables
 from openr_tpu.types.kvstore import Publication, Value
 from openr_tpu.types.serde import to_wire
 from perfbench import compare, topo
@@ -44,7 +50,10 @@ COUNTED = ("decision.rebuild.full", "decision.rebuild.structural",
            "decision.rebuild.topo_delta", "decision.spf.warm_starts",
            "decision.dev_cache.uploads", "decision.dev_cache.upload_bytes",
            "decision.spf.engine_native", "decision.csr.builds",
-           "decision.csr.overload_rebases")
+           "decision.csr.overload_rebases", "decision.dev_cache.carried")
+#: the split set's device arrays, each against a fresh build of the base
+SPLIT = ("base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt", "out_nbr",
+         "over")
 
 
 async def run_story() -> dict:
@@ -93,6 +102,12 @@ async def run_story() -> dict:
         ps, = dec.prefix_states.values()
         oracle = oracle_compute_routes(ls, ps, me, vectorize=False)
         split = dec._tpu._dev[next(reversed(dec._tpu._dev))]["sets"]["split"]
+        csr = ls.to_csr()
+        fresh = build_split_tables(
+            csr.edge_src, csr.edge_dst, csr.edge_metric, csr.num_nodes)
+        m = min(fresh["vp"], csr.padded_nodes)
+        fresh["over"] = np.zeros(fresh["vp"], dtype=bool)
+        fresh["over"][:m] = csr.node_overloaded[:m]
         return {
             "label": label,
             "grew": {k: after[k] - before[k] for k in COUNTED},
@@ -110,6 +125,10 @@ async def run_story() -> dict:
                 and dec.rib.mpls_routes == oracle.mpls_routes),
             "table_bytes": sum(
                 int(v.nbytes) for v in split.values() if hasattr(v, "nbytes")),
+            "mask_bytes": int(split["over"].nbytes),
+            "split_is_a_fresh_build": {
+                k: bool(np.array_equal(np.asarray(split[k]), fresh[k]))
+                for k in SPLIT},
             "bases_on_the_device": len(dec._tpu._dev),
             # the solver's ids follow the sorted names: back to the graph's
             "over_on_the_device": sorted(
@@ -255,11 +274,15 @@ def test_each_step_took_the_path_it_is_named_for(story):
     # the first RIB's dirt is structural too: every key is new
     assert story["cold"]["grew"]["decision.rebuild.structural"] == 1
     assert story["cold"]["grew"]["decision.csr.builds"] == 1
+    assert story["cold"]["grew"]["decision.dev_cache.uploads"] == 1
+    assert story["cold"]["grew"]["decision.dev_cache.carried"] == 0
     for step in STRUCTURAL:
         grew = story[step]["grew"]
         assert grew["decision.rebuild.full"] == 1, step
         assert grew["decision.rebuild.structural"] == 1, step
+        # the parent base's tables carried over, the mask alone placed
         assert grew["decision.dev_cache.uploads"] == 1, step
+        assert grew["decision.dev_cache.carried"] == 1, step
         assert grew["decision.spf.warm_starts"] == 0, step
         # an overload bit re-bases the CSR: no walk over the adjacencies
         assert grew["decision.csr.overload_rebases"] == 1, step
@@ -270,22 +293,32 @@ def test_each_step_took_the_path_it_is_named_for(story):
     assert raised["decision.rebuild.structural"] == 0
     assert raised["decision.dev_cache.uploads"] == 0
     assert raised["decision.dev_cache.upload_bytes"] == 0
+    assert raised["decision.dev_cache.carried"] == 0
     assert raised["decision.csr.builds"] == 0
     assert raised["decision.csr.overload_rebases"] == 0
     assert all(story[s]["grew"]["decision.spf.engine_native"] == 0 for s in STEPS)
     assert {"decision.rebuild.structural", "decision.csr.builds",
             "decision.csr.overload_rebases"} <= names.COUNTERS
+    assert names.is_registered("decision.dev_cache.carried")
 
 
 def test_every_new_base_uploads_its_tables_bytes_and_both_spans_say_so(story):
+    """The first RIB builds and uploads all its tables; a re-based base
+    builds and uploads its overload mask alone, under the same spans,
+    and those bytes alone are counted."""
     assert {"spf:table_build", "spf:upload"} < set(names.REBUILD_SPANS)
+    grew = story["cold"]["grew"]
+    assert grew["decision.dev_cache.upload_bytes"] == story["cold"]["table_bytes"]
     for step in ("cold", *STRUCTURAL):
         bd, grew = story[step]["breakdown"], story[step]["grew"]
-        assert grew["decision.dev_cache.upload_bytes"] == story[step]["table_bytes"]
         assert bd["spf:table_build"] > 0 and bd["spf:upload"] > 0, step
         assert bd["spf:table_build"] + bd["spf:upload"] <= bd["spf:dispatch"], step
         assert bd["spf:dispatch"] <= bd["spf:prepare"] <= bd["compute_rib"], step
         assert bd["spf:warm_solve"] == 0 and bd["spf:batched_solve"] > 0, step
+    for step in STRUCTURAL:
+        mask = story[step]["mask_bytes"]
+        assert story[step]["grew"]["decision.dev_cache.upload_bytes"] == mask, step
+        assert 0 < mask < story[step]["table_bytes"] // 10, step
     # the same shapes for every base: an overload bit moves no table
     assert len({story[s]["table_bytes"] for s in ("cold", *STRUCTURAL)}) == 1
     bd = story["raised"]["breakdown"]
@@ -298,6 +331,14 @@ def test_the_mask_on_the_device_is_the_drained_set_of_its_base(story):
     assert story["drained"]["over_on_the_device"] == sorted(
         [*story["drained_at_first"], story["agg"]])
     assert story["undrained"]["over_on_the_device"] == story["drained_at_first"]
+
+
+@pytest.mark.parametrize("step", STEPS)
+def test_the_tables_on_the_device_are_a_fresh_build_of_the_base(story, step):
+    """Carried across re-bases and patched by the raise, the split set
+    on the device is what building the base's tables anew would place,
+    array for array."""
+    assert story[step]["split_is_a_fresh_build"] == dict.fromkeys(SPLIT, True)
 
 
 def test_no_event_after_the_first_rib_compiles_and_old_bases_leave_the_device(story):

@@ -487,7 +487,10 @@ def test_a_structural_rebuild_opens_table_build_and_upload_once_under_dispatch()
     """`spf:table_build` and `spf:upload` (PR 38): a topology base the
     device has no tables of opens each once, as children of
     `spf:dispatch` whose sum it holds, and `upload_bytes` moves by the
-    tables' bytes; a metric-only change on that base opens neither."""
+    tables' bytes; a node's overload bit re-bases the CSR, whose base
+    carries the last one's tables and opens each once for its overload
+    mask alone, and `upload_bytes` moves by the mask's bytes; a
+    metric-only change on that base opens neither."""
     import dataclasses
 
     from openr_tpu.decision.linkstate import LinkState, PrefixState
@@ -510,9 +513,12 @@ def test_a_structural_rebuild_opens_table_build_and_upload_once_under_dispatch()
             solver.compute_routes(ls, ps, me)
         return rec, solver.dev_cache_stats["upload_bytes"] - bytes0
 
-    # an overload bit: structural, so the CSR and its tables are built anew
-    drained = dataclasses.replace(adj_dbs[0], is_overloaded=True)
-    assert ls.update_adjacency_db_delta(drained) == (True, None)
+    # a link's overload bit: structural, so the CSR and its tables are
+    # built anew
+    adjs = adj_dbs[0].adjacencies
+    link_drained = dataclasses.replace(adj_dbs[0], adjacencies=(
+        dataclasses.replace(adjs[0], is_overloaded=True), *adjs[1:]))
+    assert ls.update_adjacency_db_delta(link_drained) == (True, None)
     rec, uploaded = rebuild()
     opened = [(n, p) for n, p, _s, _e in rec.spans]
     assert opened.count(("spf:table_build", "spf:dispatch")) == 1
@@ -524,11 +530,25 @@ def test_a_structural_rebuild_opens_table_build_and_upload_once_under_dispatch()
     split = solver._dev[ls.to_csr().base_version]["sets"]["split"]
     tables = [v for v in split.values() if hasattr(v, "nbytes")]
     assert len(tables) == 7 and uploaded == sum(int(v.nbytes) for v in tables) > 0
+    assert not bool(split["over"].any())
+    # a node's overload bit: the tables carried, the mask placed alone
+    drained = dataclasses.replace(link_drained, is_overloaded=True)
+    assert ls.update_adjacency_db_delta(drained) == (True, None)
+    rec, uploaded = rebuild()
+    opened = [(n, p) for n, p, _s, _e in rec.spans]
+    assert opened.count(("spf:table_build", "spf:dispatch")) == 1
+    assert opened.count(("spf:upload", "spf:dispatch")) == 1
+    ms = rec.ms
+    assert ms["spf:table_build"] + ms["spf:upload"] <= ms["spf:dispatch"]
+    split = solver._dev[ls.to_csr().base_version]["sets"]["split"]
+    assert uploaded == int(split["over"].nbytes)
     assert bool(split["over"].any())
-    # a metric alone: the base stays, its tables are patched where they lie
+    assert solver.dev_cache_stats["carried"] == 1
+    # a metric alone: the base stays, its tables are patched where they
+    # lie (on a link the drain left up)
     adjs = drained.adjacencies
     raised = dataclasses.replace(drained, adjacencies=(
-        dataclasses.replace(adjs[0], metric=7), *adjs[1:]))
+        adjs[0], dataclasses.replace(adjs[1], metric=7), *adjs[2:]))
     changed, pairs = ls.update_adjacency_db_delta(raised)
     assert changed and pairs
     rec, uploaded = rebuild()
