@@ -124,6 +124,14 @@ class CsrGraph:
         return self.adj_details.get((u, v), default)
 
     @property
+    def structure_key(self) -> int:
+        """What caches of the ids, names and details alone key on:
+        `structure_version`, or `base_version` where the constructor
+        left it at 0 (no build is numbered 0), so a re-based base keys
+        as its parent and a hand-built graph as its own base."""
+        return self.structure_version or self.base_version
+
+    @property
     def padded_nodes(self) -> int:
         return len(self.node_mask)
 
@@ -738,7 +746,7 @@ class PrefixState:
         (nothing changed since) rather than build one."""
         return self._snap is not None and self._snap._rev == self._rev
 
-    def election_view(self, name_to_id: dict, base_version: int):
+    def election_view(self, name_to_id: dict, structure_key: int):
         """Cached columnar election classification for RIB assembly
         (:class:`openr_tpu.decision.election.ElectView`).
 
@@ -747,17 +755,19 @@ class PrefixState:
         originator-id arrays, and "multi" (anycast ECMP: 2+ advertisers,
         all plain-shaped) as the prefix→advertiser matrix the batched
         election consumes — and everything else, which keeps the scalar
-        general path. Cached on (prefix rev, topology base): under
-        metric-only churn neither changes, so steady-state rebuilds
-        skip the O(P) classification entirely.
+        general path. Cached on (prefix rev, topology structure), the
+        structure being `CsrGraph.structure_key`, which pins
+        `name_to_id`: under metric-only churn and an overload bit
+        neither changes, so those rebuilds skip the O(P)
+        classification entirely.
 
         ``gen`` is a generation token unique to (instance lineage,
-        prefix rev, topology base): within one PrefixState lineage it
-        changes iff the view could, and it can never collide across
+        prefix rev, topology structure): within one PrefixState lineage
+        it changes iff the view could, and it can never collide across
         independent instances (the lineage id), so cross-rebuild caches
         may key row indices into the view arrays on it.
         """
-        key = (self._lineage, self._rev, base_version)
+        key = (self._lineage, self._rev, structure_key)
         cached = self._view_cell[0]
         if cached is not None and cached[0] == key:
             return cached[1]
@@ -767,14 +777,14 @@ class PrefixState:
         self._view_cell[0] = (key, view)
         return view
 
-    def solver_view(self, name_to_id: dict, base_version: int):
+    def solver_view(self, name_to_id: dict, structure_key: int):
         """Legacy tuple facade over :meth:`election_view`: returns
         (plain_prefixes, plain_nodes, plain_entries, orig_ids [P]
         int64, complex_items, gen) with the multi-advertiser electable
         prefixes folded back into complex_items — the pre-election
         contract, kept for callers that only understand the plain/
         complex split."""
-        v = self.election_view(name_to_id, base_version)
+        v = self.election_view(name_to_id, structure_key)
         complex_items = v.complex_items
         if v.multi is not None:
             from openr_tpu.decision.election import multi_items

@@ -576,7 +576,7 @@ def compute_routes(
     # ---- unicast ----------------------------------------------------------
     if use_elect:
         csr = ls.to_csr()
-        view = ps.election_view(csr.name_to_id, csr.base_version)
+        view = ps.election_view(csr.name_to_id, csr.structure_key)
         # full-solve election: delta = electable prefixes, touched =
         # candidate advertiser slots (same accounting as the TPU
         # backend's elect site — parity extends to the work ledger)
@@ -971,7 +971,7 @@ def warm_compute_routes(
     # solve it scopes.
     touched: set = set(prefix_dirt)
     csr = ls.to_csr()
-    view = ps.election_view(csr.name_to_id, csr.base_version)
+    view = ps.election_view(csr.name_to_id, csr.structure_key)
     changed_ids = np.fromiter(
         (
             csr.name_to_id[n]

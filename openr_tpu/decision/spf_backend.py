@@ -264,8 +264,8 @@ class TpuSpfSolver:
         # latency shape, and the fused packed-output path wins there.
         self.mesh = mesh
         self._mesh_fallback_warned = False
-        # (structure_version, node id) → sorted neighbor ids. The CSR's
-        # edge STRUCTURE is pinned by structure_version (metric churn
+        # (structure_key, node id) → sorted neighbor ids. The CSR's
+        # edge STRUCTURE is pinned by structure_key (metric churn
         # arrives as overrides, a structural change builds a new base,
         # and a base re-based by an overload bit shares its parent's
         # adj_details and keeps its structure_version), so the O(E)
@@ -286,8 +286,8 @@ class TpuSpfSolver:
         # KSP k clamp (_ksp_batch); structural, so metric churn never
         # invalidates it
         self._ksp_nbr_counts: dict[int, tuple] = {}
-        # (area, base_version) → int64 node-label vector (MPLS section;
-        # labels are structural, see _assemble_routes)
+        # (area, csr.structure_key) → int64 node-label vector (MPLS
+        # section; labels are structural, see _node_labels)
         self._labels_cache: dict[tuple, np.ndarray] = {}
         # device-resident LSDB arrays keyed by the CSR's base version
         # (one entry per area's topology; small LRU): metric-only churn
@@ -356,6 +356,10 @@ class TpuSpfSolver:
         # gc_pause_ms / gc_full_collections: the garbage collector's
         # process totals as they stood when the last solver call ended
         # (_note_gc).
+        # assembly_carried: _assemble_routes calls on a base an overload
+        # bit re-based (structure_key != base_version) that kept the
+        # last RIB's unicast cell: the election view, keyed on the
+        # structure, survived the new base, and so did its RibEntrys.
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
@@ -372,6 +376,7 @@ class TpuSpfSolver:
             "complex_scoped": 0,
             "lfa_routes": 0, "lfa_nexthops": 0, "lfa_unprotected": 0,
             "gc_pause_ms": 0.0, "gc_full_collections": 0,
+            "assembly_carried": 0,
         }
         # what prewarm_flap_programs has run its programs for: one key
         # per (table shapes, batch, has_overloads, gs_chunks), i.e. per
@@ -855,7 +860,7 @@ class TpuSpfSolver:
             if my_id is None:
                 return None
             self.solve_count += 1
-            nbr_key = (csr.structure_version, my_id)
+            nbr_key = (csr.structure_key, my_id)
             nbr_ids = self._nbr_cache.get(nbr_key)
             if nbr_ids is None:
                 nbr_ids = sorted(
@@ -1460,7 +1465,7 @@ class TpuSpfSolver:
                 n_live = len(csr.node_names)
                 changed_mask = np.zeros(csr.padded_nodes, bool)
                 changed_mask[changed_ids] = True
-                view = ps.election_view(csr.name_to_id, csr.base_version)
+                view = ps.election_view(csr.name_to_id, csr.structure_key)
                 touched = set(prefix_dirt)
                 if len(view.plain_p):
                     for i in np.nonzero(changed_mask[view.orig])[0]:
@@ -1562,7 +1567,7 @@ class TpuSpfSolver:
             # a handful of classes. The general per-prefix loop below keeps
             # every other case (anycast, UCMP, KSP, min_nexthop); LFA backups
             # are one pass over the routes of all three (`_lfa_backups`).
-            view = ps.election_view(csr.name_to_id, csr.base_version)
+            view = ps.election_view(csr.name_to_id, csr.structure_key)
             plain_p, plain_n = view.plain_p, view.plain_n
             plain_e, orig = view.plain_e, view.orig
             complex_items, view_gen = view.complex_items, view.gen
@@ -1634,6 +1639,8 @@ class TpuSpfSolver:
                 cell = self._uni_cache.pop(slot_gen, None)
                 if cell is None or cell.get("gen") != view_gen:
                     cell = {"gen": view_gen, "entries": {}, "classdicts": {}}
+                elif csr.structure_key != csr.base_version:
+                    self.spf_kernel_stats["assembly_carried"] += 1
                 self._uni_cache[slot_gen] = cell
                 while len(self._uni_cache) > self._mpls_fingerprint_cap:
                     self._uni_cache.pop(next(iter(self._uni_cache)))
@@ -1809,8 +1816,9 @@ class TpuSpfSolver:
             # class-level sub-dict reuse, mirroring the unicast path: a
             # destination class whose membership, labels, and (fh, igp)
             # token are unchanged since a previous rebuild is ONE dict
-            # update. base_version is in the key because rows are node IDS
-            # (the name↔id interning changes with the topology base).
+            # update. The structure key is in the key because rows are node
+            # IDS: the name↔id interning changes with a built base, not
+            # with a base an overload bit re-based (CsrGraph.structure_key).
             mcell = self._mpls_cls_cache.pop(slot_gen, None) or {
                 "groups": {}, "total": 0
             }
@@ -1824,7 +1832,9 @@ class TpuSpfSolver:
                 token = dest_tokens[int(cls_sel[g[0]])]
                 lab = labels_v[rows]
                 # bytes, not hashes, for the same reason as the unicast path
-                gkey = (csr.base_version, token, rows.tobytes(), lab.tobytes())
+                gkey = (
+                    csr.structure_key, token, rows.tobytes(), lab.tobytes()
+                )
                 sub = mcls.get(gkey)
                 if sub is None:
                     sub = {}
@@ -1932,19 +1942,21 @@ class TpuSpfSolver:
         )
 
     def _node_labels(self, ls: LinkState, csr, n_live: int) -> np.ndarray:
-        """Per-node MPLS label vector, cached per topology base: a
+        """Per-node MPLS label vector, cached per topology structure: a
         node_label change is structural in _metric_only_delta (full CSR
-        rebuild → new base_version), so the O(V) python label scan —
-        measured 57 ms of a warm 100k rebuild (r5 profile) — runs once
-        per base. Shared by the full assembly and the topology-delta
-        scoped MPLS reassembly."""
-        labels_v = self._labels_cache.get((ls.area, csr.base_version))
+        build → new structure_key; an overload bit re-bases only where
+        the label stayed, `_overload_only`), so the O(V) python label
+        scan — measured 57 ms of a warm 100k rebuild (r5 profile) — runs
+        once per built base. Shared by the full assembly and the
+        topology-delta scoped MPLS reassembly."""
+        key = (ls.area, csr.structure_key)
+        labels_v = self._labels_cache.get(key)
         if labels_v is None:
             labels_v = np.fromiter(
                 (ls.node_label(nm) for nm in csr.node_names), np.int64,
                 count=n_live,
             )
-            self._labels_cache[(ls.area, csr.base_version)] = labels_v
+            self._labels_cache[key] = labels_v
             while len(self._labels_cache) > self._dev_lru_cap:
                 self._labels_cache.pop(next(iter(self._labels_cache)))
         return labels_v

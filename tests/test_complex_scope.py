@@ -173,7 +173,7 @@ class Fabric:
     def complex_prefixes(self) -> dict:
         """Every complex item of the view -> its advertisers' names."""
         csr = self.ls.to_csr()
-        view = self.ps.election_view(csr.name_to_id, csr.base_version)
+        view = self.ps.election_view(csr.name_to_id, csr.structure_key)
         return {p: set(per_node) for p, per_node in view.complex_items}
 
 

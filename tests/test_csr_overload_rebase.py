@@ -11,7 +11,9 @@ removed); snapshots are taken before and after flips.
 
 The solver keys on the same lineage: a re-based base carries its
 parent's device tables (`TpuSpfSolver._carry`) with its own overload mask
-placed anew, and equal routes hold whether it carries or builds."""
+placed anew, and equal routes hold whether it carries or builds. The
+assembly's caches key on `CsrGraph.structure_key`, which falls back to the
+base where a graph was built by hand with no `structure_version`."""
 
 import dataclasses
 import gc
@@ -393,3 +395,45 @@ def test_a_rebased_base_keeps_no_view_of_its_parent_alive():
     gc.collect()
     assert parent() is None
     assert child.rebased_from[0] != child.base_version
+
+
+def test_graphs_built_with_no_structure_version_key_on_their_base():
+    """A hand-built CsrGraph (the benchmark's er100k cell builds one)
+    leaves `structure_version` at 0, so `structure_key` is its
+    `base_version`: two such topologies under one PrefixState, the same
+    names under other ids, share no election view, MPLS class group or
+    label vector, and a solver that alternates between them gives each
+    the routes a fresh solver gives."""
+    def lsdb(seed: int):
+        return topogen.erdos_renyi_lsdb(
+            48, avg_degree=4, seed=seed, max_metric=16)
+
+    graphs = []
+    for seed, reverse in ((5, False), (6, True)):
+        csr = lsdb(seed)[2]
+        names = csr.node_names[::-1] if reverse else csr.node_names
+        csr = dataclasses.replace(
+            csr, node_names=names, structure_version=0,
+            name_to_id={n: i for i, n in enumerate(names)})
+        graphs.append((topogen.LsdbView(csr), csr, names[0]))
+    (_, a, _), (_, b, _) = graphs
+    assert a.base_version != b.base_version
+    assert a.structure_key == a.base_version
+    assert b.structure_key == b.base_version
+    ps = lsdb(5)[1]  # one loopback a name, the same names in both graphs
+    solver = device_solver()
+    for ls, csr, me in graphs * 2:
+        got = solver.compute_routes(ls, ps, me)
+        # a fresh PrefixState too: the view cell lives in the PrefixState
+        want = device_solver().compute_routes(ls, lsdb(5)[1], me)
+        assert got.unicast_routes == want.unicast_routes
+        assert got.mpls_routes == want.mpls_routes
+        assert len(got.unicast_routes) > 0 and len(got.mpls_routes) > 0
+        view = ps.election_view(csr.name_to_id, csr.structure_key)
+        assert view.gen[2] == csr.base_version
+    bases = {a.base_version, b.base_version}
+    assert {key[1] for key in solver._labels_cache} == bases
+    groups = {
+        gkey[0] for cell in solver._mpls_cls_cache.values()
+        for gkey in cell["groups"]}
+    assert groups == bases

@@ -13,7 +13,11 @@ to the program:
     `decision.dev_cache.carried`, the table sets a re-based base took over
     from its parent's device entry instead of building them: only the
     first RIB builds and uploads every table, a drain or an undrain
-    builds and uploads its overload mask alone.
+    builds and uploads its overload mask alone;
+  * `decision.spf.assembly_carried`: the assembly's caches key on the
+    CSR's structure (`CsrGraph.structure_key`), so a re-based base keeps
+    the last RIB's election view and route objects, and a drain re-mints
+    the routes it changed and no other.
 
 One story (module fixture): the first RIB with one aggregation switch and
 one spine drained; an aggregation switch of another pod drained; a ToR
@@ -50,7 +54,8 @@ COUNTED = ("decision.rebuild.full", "decision.rebuild.structural",
            "decision.rebuild.topo_delta", "decision.spf.warm_starts",
            "decision.dev_cache.uploads", "decision.dev_cache.upload_bytes",
            "decision.spf.engine_native", "decision.csr.builds",
-           "decision.csr.overload_rebases", "decision.dev_cache.carried")
+           "decision.csr.overload_rebases", "decision.dev_cache.carried",
+           "decision.spf.assembly_carried")
 #: the split set's device arrays, each against a fresh build of the base
 SPLIT = ("base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt", "out_nbr",
          "over")
@@ -134,6 +139,8 @@ async def run_story() -> dict:
             "over_on_the_device": sorted(
                 int(ls.to_csr().node_names[int(i)].rpartition("-")[2])
                 for i in split["over"].nonzero()[0]),
+            # the route objects themselves, for identity across steps
+            "rib": (dict(dec.rib.unicast_routes), dict(dec.rib.mpls_routes)),
         }
 
     async def event(label: str, dbs: list) -> dict:
@@ -224,6 +231,9 @@ STRUCTURAL = ("drained", "undrained", "own_drained", "own_undrained",
               *(f"more_{i}" for i in range(6)))
 STEPS = ("cold", "drained", "raised", "undrained", "own_drained",
          "own_undrained", "more_5")
+#: every step in the order the story takes them
+ORDER = ("cold", "drained", "raised", "undrained", "own_drained",
+         "own_undrained", *(f"more_{i}" for i in range(6)))
 
 
 @pytest.mark.parametrize("step", STEPS)
@@ -352,3 +362,30 @@ def test_no_event_after_the_first_rib_compiles_and_old_bases_leave_the_device(st
         assert story[step]["compiled"] == 0, step
     assert story["more_5"]["bases_on_the_device"] == 4
     assert max(story[s]["bases_on_the_device"] for s in STRUCTURAL) == 4
+
+
+@pytest.mark.parametrize("step", ORDER[1:])
+def test_a_route_whose_value_stayed_is_the_object_of_the_last_rib(story, step):
+    """Keyed on the structure, the assembly's caches outlive a re-based
+    base: a route the step left as it was is the previous RIB's object,
+    and the routes minted anew are exactly the ones that changed."""
+    prev = story[ORDER[ORDER.index(step) - 1]]["rib"]
+    changed = 0
+    for before, now in zip(prev, story[step]["rib"]):
+        both = before.keys() & now.keys()
+        kept = {k for k in both if now[k] is before[k]}
+        assert kept == {k for k in both if now[k] == before[k]}, step
+        assert kept, step
+        changed += len(both) - len(kept)
+    assert changed > 0, step
+
+
+def test_a_re_based_rebuild_carries_the_assembly_a_built_one_not(story):
+    """One `decision.spf.assembly_carried` a drain or an undrain (a cold
+    assembly on a re-based base that found the last RIB's unicast cell),
+    none for the first RIB's built base nor for the warm start."""
+    assert story["cold"]["grew"]["decision.spf.assembly_carried"] == 0
+    assert story["raised"]["grew"]["decision.spf.assembly_carried"] == 0
+    for step in STRUCTURAL:
+        assert story[step]["grew"]["decision.spf.assembly_carried"] == 1, step
+    assert names.is_registered("decision.spf.assembly_carried")

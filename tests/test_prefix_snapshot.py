@@ -151,7 +151,7 @@ def test_the_election_view_built_on_a_snapshot_serves_live_and_next():
     ps = seeded()
     ids = {"a": 0, "b": 1, "c": 2}
     snap = ps.snapshot()
-    view = snap.election_view(ids, base_version=3)
+    view = snap.election_view(ids, structure_key=3)
     assert ps.election_view(ids, 3) is view
     assert ps.snapshot().election_view(ids, 3) is view
     ps.update_prefix_db(pdb("c", entry(P3)))
