@@ -7,10 +7,10 @@ telemetry").
 Exercises each canonical entry point the way its production consumer
 does — the split RIB solve via ``TpuSpfSolver.compute_routes``, the
 batched kernel via ``_solve_dist``, the sharded kernel via a 2x2 mesh
-solver, and the election / KSP wrappers with production-shaped small
-inputs — then warms the compile ledger and
-re-runs everything: any post-warmup XLA compile (including one caused
-by the telemetry captures themselves) exits 1.
+solver, and the KSP wrapper with production-shaped small inputs —
+then warms the compile ledger and re-runs everything: any post-warmup
+XLA compile (including one caused by the telemetry captures
+themselves) exits 1.
 """
 
 from __future__ import annotations
@@ -38,7 +38,6 @@ EXPECTED_KERNELS = (
     "batched_sssp_split_rib",   # fused split RIB solve (production path)
     "batched_sssp_split",       # batched split kernel (_solve_dist)
     "sharded_sssp_split",       # mesh-sharded split kernel
-    "_elect_seg",               # device election segmented reductions
     "_ksp_edge_disjoint_dense_jit",  # k-shortest-paths kernel
 )
 
@@ -75,28 +74,6 @@ def _run_kernels() -> None:
     sharded = TpuSpfSolver(native_rib="off", mesh=mesh)
     b16 = np.arange(16, dtype=np.int32) % csr.num_nodes
     sharded._solve_dist(csr, b16)
-
-    # device election (segmented reductions) on a tiny 2-advertiser
-    # anycast matrix — the dispatch-threshold route is covered by
-    # tests; the smoke wants the kernel row
-    from openr_tpu.decision.election import MultiTable
-    from openr_tpu.types.network import IpPrefix
-
-    t = MultiTable(
-        prefixes=[IpPrefix.make("10.9.0.0/32")],
-        indptr=np.array([0, 2], np.int64),
-        seg=np.zeros(2, np.int64),
-        adv=np.array([1, 2], np.int64),
-        known=np.ones(2, bool),
-        rank=np.array([0, 1], np.int64),
-        entries=[None, None],
-        names=["node-1", "node-2"],
-    )
-    from openr_tpu.ops.election import elect_multi_device
-
-    d_vec = np.arange(csr.padded_nodes, dtype=np.int64) + 1
-    reach = np.ones(csr.padded_nodes, bool)
-    elect_multi_device(t, d_vec, reach, 0, dev_cache={}, gen=0)
 
     # KSP kernel through its canonicalizing wrapper
     nbr, wgt = csr.dense_tables()

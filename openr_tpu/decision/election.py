@@ -23,9 +23,9 @@ Two advertiser shapes are vectorized:
 
 Everything else — KSP, UCMP weights, min_nexthop, mixed advertiser
 algorithms, LFA in the oracle, installed policy — falls back to the
-engines' existing scalar paths (the fallback matrix in docs/Decision.md). Both engines
-(oracle NumPy, TPU backend NumPy-or-device) consume the same table and
-the same election algebra, so vectorized/scalar and engine/engine
+engines' existing scalar paths (the fallback matrix in docs/Decision.md).
+Both engines (the oracle and the TPU backend) consume the same table
+and the same NumPy election, so vectorized/scalar and engine/engine
 byte-parity hold by shared construction and are gated by tests.
 
 The classification is conservative: a prefix is only vectorized when
@@ -238,21 +238,6 @@ def build_elect_view(entries: dict, name_to_id: dict, gen) -> ElectView:
         ),
         gen=gen,
     )
-
-
-def multi_items(t: MultiTable) -> list:
-    """The multi table in scalar-path form — ``(prefix, {node: entry})``
-    per row — for the fallback seams (LFA, legacy solver_view)."""
-    return [
-        (
-            t.prefixes[i],
-            {
-                t.names[s]: t.entries[s]
-                for s in range(int(t.indptr[i]), int(t.indptr[i + 1]))
-            },
-        )
-        for i in range(len(t.prefixes))
-    ]
 
 
 def elect_multi_np(

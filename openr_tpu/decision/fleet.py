@@ -85,15 +85,13 @@ def compute_fleet_ribs(
     cols.append(np.asarray(pending))
     dist_all = np.concatenate(cols, axis=1)[:, : len(root_list)]
 
-    # The MPLS entry cache is keyed per root fingerprint; raise the cap
-    # DURABLY so repeated fleet passes keep their entries (cross-pass
+    # The assembly caches are kept per root slot fingerprint; raise the
+    # cap DURABLY so repeated fleet passes keep their entries (cross-pass
     # reuse is why a caller shares a solver at all). The memory cost is
     # the caller's explicit choice: the default (solver=None) footprint
     # dies with this call, and a shared solver can reclaim it any time
     # via TpuSpfSolver.trim_caches().
-    solver._mpls_fingerprint_cap = max(
-        solver._mpls_fingerprint_cap, len(targets) + 1
-    )
+    solver._slots.cap = max(solver._slots.cap, len(targets) + 1)
     return _assemble_all(
         solver, ls, ps, csr, targets, nbrs_of, col_of, dist_all
     )

@@ -691,7 +691,7 @@ class PrefixState:
         # lineage id: distinguishes independent PrefixState instances
         # whose per-instance _rev counters could coincide. Snapshots
         # (copy-style constructors) inherit it, so within one lineage
-        # the solver_view gen is content-stable; across instances it
+        # the election view's gen is content-stable; across instances it
         # can never collide.
         self._lineage = next(_PS_LINEAGE)
         # the frozen copy snapshot() handed out last: while _rev stands
@@ -776,21 +776,6 @@ class PrefixState:
         view = build_elect_view(self._entries, name_to_id, key)
         self._view_cell[0] = (key, view)
         return view
-
-    def solver_view(self, name_to_id: dict, structure_key: int):
-        """Legacy tuple facade over :meth:`election_view`: returns
-        (plain_prefixes, plain_nodes, plain_entries, orig_ids [P]
-        int64, complex_items, gen) with the multi-advertiser electable
-        prefixes folded back into complex_items — the pre-election
-        contract, kept for callers that only understand the plain/
-        complex split."""
-        v = self.election_view(name_to_id, structure_key)
-        complex_items = v.complex_items
-        if v.multi is not None:
-            from openr_tpu.decision.election import multi_items
-
-            complex_items = sorted(complex_items + multi_items(v.multi))
-        return (v.plain_p, v.plain_n, v.plain_e, v.orig, complex_items, v.gen)
 
     def withdraw(self, node: str, prefix: IpPrefix) -> bool:
         per_node = self._entries.get(prefix)

@@ -19,6 +19,7 @@ path and the test suite asserts RouteDatabase equality between the two.
 from __future__ import annotations
 
 import logging
+from collections import OrderedDict
 from dataclasses import replace
 
 import jax
@@ -112,12 +113,44 @@ _PHASE_SPANS = {
 }
 
 
+#: the split table set's device arrays in the kernels' argument order
+_SPLIT_TABLES = (
+    "base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt", "out_nbr", "over",
+)
+
+
 def _warm_scatter_pad(n: int) -> int:
     for t in _WARM_SCATTER_TIERS:
         if n <= t:
             return t
     top = _WARM_SCATTER_TIERS[-1]
     return -(-n // top) * top
+
+
+class _Lru(OrderedDict):
+    """A mapping of at most `cap` entries: `get` makes a hit the most
+    recent, `put` adds an entry as the most recent and drops the least
+    recent past the cap (`trim`, also after the cap is lowered)."""
+
+    def __init__(self, cap: int):
+        super().__init__()
+        self.cap = cap
+
+    def get(self, key, default=None):
+        if key not in self:
+            return default
+        self.move_to_end(key)
+        return self[key]
+
+    def put(self, key, value):
+        self[key] = value
+        self.move_to_end(key)
+        self.trim()
+        return value
+
+    def trim(self) -> None:
+        while len(self) > self.cap:
+            self.popitem(last=False)
 
 
 def _split_over(csr, vp: int) -> np.ndarray:
@@ -264,43 +297,45 @@ class TpuSpfSolver:
         # latency shape, and the fused packed-output path wins there.
         self.mesh = mesh
         self._mesh_fallback_warned = False
-        # (structure_key, node id) → sorted neighbor ids. The CSR's
-        # edge STRUCTURE is pinned by structure_key (metric churn
-        # arrives as overrides, a structural change builds a new base,
-        # and a base re-based by an overload bit shares its parent's
-        # adj_details and keeps its structure_version), so the O(E)
-        # adj_details scan runs once per built topology instead of per
-        # rebuild or drain; per-solve metrics still read the
-        # override-aware csr.details. Small FIFO bound at 4× the
-        # device-cache cap (entries are tiny; a steady-state node
-        # touches one key).
-        self._nbr_cache: dict[tuple[int, int], list[int]] = {}
         # "auto" | "on" | "off": the native C++ radix-heap solver for the
         # single-root RIB path (ops/native_spf.py). auto = use when the
         # shared library is built and LFA is off (LFA needs the batched
         # distance matrix). The batched kernel keeps: LFA, KSP, and
         # all-sources shapes.
         self.native_rib = native_rib
-        self._native_cache: dict[int, dict] = {}
-        # per-topology-base (out, in) distinct-neighbor counts for the
-        # KSP k clamp (_ksp_batch); structural, so metric churn never
-        # invalidates it
-        self._ksp_nbr_counts: dict[int, tuple] = {}
-        # (area, csr.structure_key) → int64 node-label vector (MPLS
-        # section; labels are structural, see _node_labels)
-        self._labels_cache: dict[tuple, np.ndarray] = {}
-        # device-resident LSDB arrays keyed by the CSR's base version
-        # (one entry per area's topology; small LRU): metric-only churn
-        # arrives as a patch journal (linkstate.py MetricPatch) and is
-        # applied by scatter on device instead of re-uploading O(E)
-        # arrays per rebuild (SURVEY §7 step 5: "device-resident LSDB
-        # updated by scatter"). A base version pins the tables but the
-        # overload mask, and a base re-based by an overload bit
-        # (`CsrGraph.rebased_from`) has its parent's tables: its entry
-        # is carried from the parent's with the mask placed anew
-        # (`_carry`), not built
-        self._dev: dict[int, dict] = {}
-        self._dev_lru_cap = 4
+        # The solver's cached state has three homes, one per key; each
+        # holds what its key pins and nothing else.
+        #
+        # Per topology base (csr.base_version; one entry per area's
+        # topology): the device-resident LSDB tables, metric-only churn
+        # scattered into them from the patch journal (linkstate.py
+        # MetricPatch) instead of re-uploading O(E) arrays per rebuild
+        # (SURVEY §7 step 5), and under "host" what the base's edges
+        # alone decide: the split layout, the warm start's cone index
+        # ("warm_out", _warm_out_index), KSP's neighbor counts
+        # ("ksp_counts") and its last batch sizes ("ksp"). A base
+        # re-based by an overload bit (`CsrGraph.rebased_from`) has its
+        # parent's edges: its entry is carried from the parent's with
+        # the mask placed anew (`_carry`), not built
+        self._dev = _Lru(4)
+        # the native engine's source-sorted CSR per base: it holds the
+        # overload mask inside its OutCsr, so it is never carried
+        self._native_cache = _Lru(4)
+        # Per topology structure (csr.structure_key, which pins the ids,
+        # names and details, and which a re-based base shares with its
+        # parent): {"nbr": {node id: sorted neighbor ids}, "labels":
+        # {area: int64 node-label vector}}, so the O(E) adj_details scan
+        # and the O(V) label scan run once per built topology
+        self._structure = _Lru(4)
+        # Per slot fingerprint (`_slot_cell`: the area and the root's
+        # adjacency slots, which the first-hop columns alone can't see):
+        # the cross-rebuild assembly caches, {"uni": unicast RibEntry
+        # cell, "mpls": RibMplsEntry by (label, node, class token, igp),
+        # "mpls_cls": class-level {label: RibMplsEntry} groups, "lfa":
+        # LFA backups}. The cap covers one root by default;
+        # compute_fleet_ribs raises it durably to its root count
+        # (reclaim via trim_caches())
+        self._slots = _Lru(8)
         # observability: sets placed by `_upload` (a built set's tables,
         # a carried set's mask) vs in-place patch scatters vs pure hits
         # — under metric-only churn, `uploads` must stay flat after
@@ -390,49 +425,18 @@ class TpuSpfSolver:
         # of which: topology-delta warm starts (bounded-region kernel
         # seeded from the cached artifact instead of a cold solve)
         self.warm_solves = 0
-        # per-topology-base src-sorted edge index (order, row_start) for
-        # the warm start's host-side increase-cone walk; structural, so
-        # metric churn never invalidates it (LRU like _dev)
-        self._warm_out: dict[int, tuple] = {}
-        # cross-rebuild MPLS RibMplsEntry cache: {slot_fingerprint:
-        # {(label, node, class_token, igp): RibMplsEntry}} — see the
-        # MPLS section of _assemble_routes. LRU over fingerprints; the
-        # cap covers one root by default, and compute_fleet_ribs raises
-        # it durably to its root count (reclaim via trim_caches())
-        self._mpls_cache: dict = {}
-        # cross-rebuild unicast RibEntry cache, same fingerprint scheme
-        # (see the plain-prefix section of _assemble_routes)
-        self._uni_cache: dict = {}
-        # class-level {label: RibMplsEntry} sub-dicts (MPLS section)
-        self._mpls_cls_cache: dict = {}
-        # cross-rebuild LFA backups (_lfa_backups), same fingerprint
-        # scheme: {"rows": {backup metric row bytes: next hops},
-        # "routes": {prefix: (route, next hops, route with them)}}, so
-        # an unchanged route keeps its backups and its identity
-        self._lfa_cache: dict = {}
-        self._mpls_fingerprint_cap = 8
         # nexthop-group intern table (types/routes.NexthopIntern): one
         # shared NexthopGroup object per distinct ECMP set across every
         # route this solver assembles — the million-prefix RIB carries
         # a few thousand of these, and diff/FIB equality collapses to
         # pointer compares on them
         self._nh_intern = NexthopIntern()
-        # multi-advertiser election: run the segmented reductions on
-        # device (ops/election.py) once the advertiser matrix has at
-        # least this many slots; below it the NumPy path wins on
-        # dispatch overhead. Byte-equal either way (integer algebra).
-        self.elect_device_min = 1 << 15
-        # device-resident advertiser matrix per election-view gen
-        # (small LRU — one live gen per PrefixState lineage)
-        self._elect_dev: dict = {}
         # observability: the last compute_routes call as six phases
         # that follow one another and cover it (prepare, solve, unpack,
         # election, assembly, mpls) — a view of the call's span record
         # (_PHASE_SPANS) — and election shape counts
         self.last_phase_ms: dict[str, float] = {}
-        self.elect_stats = {
-            "plain": 0, "multi": 0, "complex": 0, "device_elections": 0,
-        }
+        self.elect_stats = {"plain": 0, "multi": 0, "complex": 0}
         # per-device shard layout of the last sharded solve's output
         # (monitor/device.shard_rows — metadata only, no device sync);
         # empty until a mesh-sharded solve runs
@@ -469,10 +473,7 @@ class TpuSpfSolver:
                     "sets": {},
                     "host": {},
                 }
-        self._dev.pop(csr.base_version, None)  # refresh LRU position
-        self._dev[csr.base_version] = cache
-        while len(self._dev) > self._dev_lru_cap:
-            self._dev.pop(next(iter(self._dev)))
+            self._dev.put(csr.base_version, cache)
         got = cache["sets"].get(want)
         if got is not None:
             if not carried:
@@ -652,24 +653,45 @@ class TpuSpfSolver:
         self.dev_cache_stats["scatter_calls"] += 1
         return _scatter_set(arr, index, values)
 
+    def _structure_cell(self, csr) -> dict:
+        """The per-structure cache entry of `csr` (see __init__)."""
+        cell = self._structure.get(csr.structure_key)
+        if cell is None:
+            cell = self._structure.put(
+                csr.structure_key, {"nbr": {}, "labels": {}}
+            )
+        return cell
+
+    def _slot_cell(self, area: str, slot_cache) -> dict:
+        """The per-slot-fingerprint cache entry of the root whose
+        adjacency slots are `slot_cache` in `area` (see __init__)."""
+        slot_gen = (area, tuple(tuple(s) for s in slot_cache))
+        cell = self._slots.get(slot_gen)
+        if cell is None:
+            cell = self._slots.put(slot_gen, {
+                "uni": None,
+                "mpls": {},
+                "mpls_cls": {"groups": {}, "total": 0},
+                "lfa": {"rows": {}, "routes": {}},
+            })
+        return cell
+
+    def _base_host(self, csr) -> dict:
+        """The host part of `csr`'s base entry, or a throwaway dict
+        where the entry was evicted: what is stored there is computed
+        again rather than kept beside an entry that is gone."""
+        entry = self._dev.get(csr.base_version)
+        return {} if entry is None else entry["host"]
+
     def trim_caches(self, fingerprint_cap: int = 8) -> None:
         """Reclaim assembly-cache memory (e.g. after a fleet pass on a
-        shared solver): drop the MPLS fingerprint cap back down and
-        evict LRU fingerprints beyond it."""
-        self._mpls_fingerprint_cap = fingerprint_cap
-        while len(self._mpls_cache) > fingerprint_cap:
-            self._mpls_cache.pop(next(iter(self._mpls_cache)))
-        while len(self._uni_cache) > fingerprint_cap:
-            self._uni_cache.pop(next(iter(self._uni_cache)))
-        while len(self._mpls_cls_cache) > fingerprint_cap:
-            self._mpls_cls_cache.pop(next(iter(self._mpls_cls_cache)))
-        while len(self._lfa_cache) > fingerprint_cap:
-            self._lfa_cache.pop(next(iter(self._lfa_cache)))
-        # warm-start host index: cheap to rebuild (one argsort per
-        # topology base), so a trim drops it entirely
-        self._warm_out.clear()
-        # device-resident advertiser matrices: re-uploaded on demand
-        self._elect_dev.clear()
+        shared solver): drop the slot-fingerprint cap back down and
+        evict the least recent fingerprints beyond it, and drop each
+        base's warm-start cone index (one argsort to rebuild)."""
+        self._slots.cap = fingerprint_cap
+        self._slots.trim()
+        for entry in self._dev.values():
+            entry["host"].pop("warm_out", None)
 
     def solve_vp(self, csr) -> int:
         """Node-dimension size of the distance matrix `solve` returns
@@ -712,23 +734,19 @@ class TpuSpfSolver:
                 # per-shard span: dispatch wall only (the caller's
                 # materialization pays completion); the output's
                 # per-device shard layout is kept for ctrl/breeze
+                statics = {"has_overloads": has_over}
                 with profiling.annotate(
                     "spf:sharded_solve", counters=self.counters
                 ):
-                    out = sharded_sssp_split(
+                    args = (
                         dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
                         dev["ov_nbr"], dev["ov_wgt"], dev["over"],
                         jnp.asarray(roots), self.mesh,
-                        has_overloads=has_over,
                     )
+                    out = sharded_sssp_split(*args, **statics)
                 device_telemetry.observe(
                     "sharded_sssp_split",
-                    lambda: sharded_sssp_split.lower(
-                        dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                        dev["ov_nbr"], dev["ov_wgt"], dev["over"],
-                        jnp.asarray(roots), self.mesh,
-                        has_overloads=has_over,
-                    ),
+                    lambda: sharded_sssp_split.lower(*args, **statics),
                     span="spf:sharded_solve",
                     # dispatch-only span (async return)
                     span_complete=False,
@@ -743,22 +761,17 @@ class TpuSpfSolver:
                     "(use power-of-two axis sizes)",
                     dict(self.mesh.shape), dev["vp"], len(roots),
                 )
-        gs = self._pick_gs_and_count(dev)
-        out = batched_sssp_split(
-            dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-            dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"], dev["over"],
-            jnp.asarray(roots), has_overloads=has_over, gs_chunks=gs,
-        )
+        args = (*(dev[t] for t in _SPLIT_TABLES), jnp.asarray(roots))
+        statics = {
+            "has_overloads": has_over,
+            "gs_chunks": self._pick_gs_and_count(dev),
+        }
+        out = batched_sssp_split(*args, **statics)
         # no span: nothing times this dispatch (the caller's
         # materialization pays completion)
         device_telemetry.observe(
             "batched_sssp_split",
-            lambda: batched_sssp_split.lower(
-                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                dev["over"], jnp.asarray(roots),
-                has_overloads=has_over, gs_chunks=gs,
-            ),
+            lambda: batched_sssp_split.lower(*args, **statics),
         )
         return out
 
@@ -825,15 +838,12 @@ class TpuSpfSolver:
             csr.edge_src, csr.edge_dst, csr.edge_metric, csr.padded_nodes,
             csr.node_overloaded, return_slot_map=True,
         )
-        self._native_cache.pop(csr.base_version, None)
-        self._native_cache[csr.base_version] = {
+        self._native_cache.put(csr.base_version, {
             "oc": oc,
             "slot_map": slot_map,
             "version": csr.version,
             "journal_len": len(csr.patches),
-        }
-        while len(self._native_cache) > self._dev_lru_cap:
-            self._native_cache.pop(next(iter(self._native_cache)))
+        })
         return oc
 
     def solve(self, ls: LinkState, my_node: str):
@@ -860,15 +870,12 @@ class TpuSpfSolver:
             if my_id is None:
                 return None
             self.solve_count += 1
-            nbr_key = (csr.structure_key, my_id)
-            nbr_ids = self._nbr_cache.get(nbr_key)
+            nbrs = self._structure_cell(csr)["nbr"]
+            nbr_ids = nbrs.get(my_id)
             if nbr_ids is None:
-                nbr_ids = sorted(
+                nbr_ids = nbrs[my_id] = sorted(
                     d for (s, d) in csr.adj_details if s == my_id
                 )
-                self._nbr_cache[nbr_key] = nbr_ids
-                while len(self._nbr_cache) > 4 * self._dev_lru_cap:
-                    self._nbr_cache.pop(next(iter(self._nbr_cache)))
             n = len(nbr_ids)
             b = pad_batch(1 + n)
             nbr_metric_real = self._nbr_metrics(csr, my_id, nbr_ids)
@@ -901,18 +908,18 @@ class TpuSpfSolver:
         # instead of ~16 MB of device→host traffic per rebuild at
         # the 100k shape (see ops.spf_split.batched_sssp_split_rib)
         vp = dev["vp"]
-        gs = self._pick_gs_and_count(dev)
+        statics = {
+            "has_overloads": has_over,
+            "with_lfa": self.enable_lfa,
+            "gs_chunks": self._pick_gs_and_count(dev),
+        }
         with profiling.annotate("spf:batched_solve", counters=self.counters):
-            dist_dev, packed = batched_sssp_split_rib(
-                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                dev["over"], jnp.asarray(roots),
+            args = (
+                *(dev[t] for t in _SPLIT_TABLES), jnp.asarray(roots),
                 jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
                 jnp.asarray(nbr_over), jnp.int32(my_id),
-                has_overloads=has_over,
-                with_lfa=self.enable_lfa,
-                gs_chunks=gs,
             )
+            dist_dev, packed = batched_sssp_split_rib(*args, **statics)
             buf = np.asarray(packed)
             compile_ledger.record_transfer(buf.nbytes)
         # kernel cost ledger (docs/Monitor.md "Device telemetry"):
@@ -920,16 +927,7 @@ class TpuSpfSolver:
         # of this fn — a pure dict probe in steady state
         device_telemetry.observe(
             "batched_sssp_split_rib",
-            lambda: batched_sssp_split_rib.lower(
-                dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                dev["over"], jnp.asarray(roots),
-                jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
-                jnp.asarray(nbr_over), jnp.int32(my_id),
-                has_overloads=has_over,
-                with_lfa=self.enable_lfa,
-                gs_chunks=gs,
-            ),
+            lambda: batched_sssp_split_rib.lower(*args, **statics),
             span="spf:batched_solve",
         )
         with profiling.annotate("spf:unpack"):
@@ -1069,7 +1067,7 @@ class TpuSpfSolver:
         if lfa_jobs:
             self._lfa_backups(
                 csr, my_id, nbr_ids, fh, lfa, dist, slot_cache, ls.area,
-                lfa_jobs, out,
+                self._slot_cell(ls.area, slot_cache)["lfa"], lfa_jobs, out,
             )
         if ksp_jobs:
             self._ksp_batch(csr, ls, my_node, my_id, d_root, ksp_jobs, out)
@@ -1081,7 +1079,8 @@ class TpuSpfSolver:
         """Src-sorted live-edge permutation + row starts for the warm
         start's host-side increase-cone walk; structural per topology
         base, so metric churn never invalidates it."""
-        cached = self._warm_out.get(csr.base_version)
+        host = self._base_host(csr)
+        cached = host.get("warm_out")
         if cached is None:
             e = csr.num_edges
             src = csr.edge_src[:e].astype(np.int64)
@@ -1089,10 +1088,7 @@ class TpuSpfSolver:
             row_start = np.zeros(csr.padded_nodes + 1, np.int64)
             np.add.at(row_start, src + 1, 1)
             row_start = np.cumsum(row_start)
-            cached = (order, row_start)
-            self._warm_out[csr.base_version] = cached
-            while len(self._warm_out) > self._dev_lru_cap:
-                self._warm_out.pop(next(iter(self._warm_out)))
+            cached = host["warm_out"] = (order, row_start)
         return cached
 
     def _warm_cone(
@@ -1212,18 +1208,15 @@ class TpuSpfSolver:
         vp = dev["vp"]
         bb = pad_batch(1 + len(nbr_ids))
         has_over = bool(csr.node_overloaded.any())
-        gs = pick_gs_chunks(vp)
-        tables = (
-            "base_nbr", "base_wgt", "ov_ids", "ov_nbr", "ov_wgt",
-            "out_nbr", "over",
-        )
+        statics = {"has_overloads": has_over, "gs_chunks": pick_gs_chunks(vp)}
         # an area with a KSP prefix also holds the dense tables, which
         # every patch is scattered into as well, and re-runs its whole
         # KSP batch on every event
         dense = cache["sets"].get("dense")
         ksp = cache["host"].get("ksp") if dense is not None else None
         key = (
-            *(dev[t].shape for t in tables), bb, has_over, gs,
+            *(dev[t].shape for t in _SPLIT_TABLES), bb,
+            *statics.values(),
             None if dense is None else dense["wgt"].shape, ksp,
         )
         if key in self._prewarmed:
@@ -1261,21 +1254,17 @@ class TpuSpfSolver:
                 self._nbr_metrics(csr, my_id, nbr_ids), bb,
             )
             args = (
-                *(dev[t] for t in tables), jnp.asarray(roots),
+                *(dev[t] for t in _SPLIT_TABLES), jnp.asarray(roots),
                 jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
                 jnp.asarray(nbr_over), dist._dev,
                 jnp.asarray(np.zeros(vp, bool)),
             )
-            batched_sssp_split_warm_rib(
-                *args, has_overloads=has_over, gs_chunks=gs
-            )
+            batched_sssp_split_warm_rib(*args, **statics)
             # the kernel's cost row, which the first warm start would
             # otherwise lower and compile for (device.observe)
             device_telemetry.observe(
                 "batched_sssp_split_warm_rib",
-                lambda: batched_sssp_split_warm_rib.lower(
-                    *args, has_overloads=has_over, gs_chunks=gs
-                ),
+                lambda: batched_sssp_split_warm_rib.lower(*args, **statics),
                 span="spf:warm_solve",
             )
         self.spf_kernel_stats["prewarm_programs"] += programs
@@ -1414,30 +1403,23 @@ class TpuSpfSolver:
                             (rows[off : off + top], cols[off : off + top]),
                             INF_DIST,
                         )
-            gs = pick_gs_chunks(vp)
+            statics = {
+                "has_overloads": has_over, "gs_chunks": pick_gs_chunks(vp),
+            }
             with profiling.annotate("spf:warm_solve", counters=self.counters):
-                dist_dev2, packed = batched_sssp_split_warm_rib(
-                    dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                    dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                    dev["over"], jnp.asarray(roots),
+                args = (
+                    *(dev[t] for t in _SPLIT_TABLES), jnp.asarray(roots),
                     jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
-                    jnp.asarray(nbr_over),
-                    dist_dev, jnp.asarray(seed),
-                    has_overloads=has_over, gs_chunks=gs,
+                    jnp.asarray(nbr_over), dist_dev, jnp.asarray(seed),
+                )
+                dist_dev2, packed = batched_sssp_split_warm_rib(
+                    *args, **statics
                 )
                 buf = np.asarray(packed)
                 compile_ledger.record_transfer(buf.nbytes)
             device_telemetry.observe(
                 "batched_sssp_split_warm_rib",
-                lambda: batched_sssp_split_warm_rib.lower(
-                    dev["base_nbr"], dev["base_wgt"], dev["ov_ids"],
-                    dev["ov_nbr"], dev["ov_wgt"], dev["out_nbr"],
-                    dev["over"], jnp.asarray(roots),
-                    jnp.asarray(nbr_metric), jnp.asarray(nbr_ids_p),
-                    jnp.asarray(nbr_over),
-                    dist_dev, jnp.asarray(seed),
-                    has_overloads=has_over, gs_chunks=gs,
-                ),
+                lambda: batched_sssp_split_warm_rib.lower(*args, **statics),
                 span="spf:warm_solve",
             )
             with profiling.annotate("spf:warm_unpack"):
@@ -1593,15 +1575,16 @@ class TpuSpfSolver:
                 n_elect,
             )
             # multi-advertiser election: the masked argmax/argmin over the
-            # prefix→advertiser matrix (device-side segmented reductions
-            # past elect_device_min slots, NumPy below — byte-equal)
+            # prefix→advertiser matrix
             mel = None
             if multi is not None and len(multi.prefixes):
-                mel = self._elect_multi(multi, d_root, fh_any, my_id, view_gen)
-            # fingerprint for every cross-rebuild assembly cache: my own
-            # adjacency slot details (interface names, min-metric parallel
-            # links), which the fh column alone can't see
-            slot_gen = (ls.area, tuple(tuple(s) for s in slot_cache))
+                d_vec = np.asarray(d_root)
+                mel = elect_multi_np(
+                    multi, d_vec.astype(np.int64),
+                    (d_vec < INF_DIST) & fh_any, my_id,
+                )
+            # every cross-rebuild assembly cache of this root
+            slots = self._slot_cell(ls.area, slot_cache)
             if len(plain_p):
                 reach = (
                     (d_root[orig] < INF_DIST) & fh_any[orig] & (orig != my_id)
@@ -1628,7 +1611,7 @@ class TpuSpfSolver:
                 # RibEntry can be reused as-is — which also lets the
                 # Decision/Fib diffs skip field-by-field equality via
                 # identity. Three levels, all scoped to the slot fingerprint
-                # and the solver_view generation:
+                # and the election view's generation:
                 #   entries:    (view row, class token) → RibEntry
                 #   classdicts: (token, membership fp) → {prefix: RibEntry}
                 #   plain/multi: content signature → the WHOLE assembled
@@ -1636,14 +1619,13 @@ class TpuSpfSolver:
                 #                rebuild whose election outcome is
                 #                byte-identical re-lands the section as one
                 #                C-speed dict.update, no per-class loop
-                cell = self._uni_cache.pop(slot_gen, None)
-                if cell is None or cell.get("gen") != view_gen:
-                    cell = {"gen": view_gen, "entries": {}, "classdicts": {}}
+                cell = slots["uni"]
+                if cell is None or cell["gen"] != view_gen:
+                    cell = slots["uni"] = {
+                        "gen": view_gen, "entries": {}, "classdicts": {},
+                    }
                 elif csr.structure_key != csr.base_version:
                     self.spf_kernel_stats["assembly_carried"] += 1
-                self._uni_cache[slot_gen] = cell
-                while len(self._uni_cache) > self._mpls_fingerprint_cap:
-                    self._uni_cache.pop(next(iter(self._uni_cache)))
             if len(plain_p):
                 entries = cell["entries"]
                 classdicts = cell["classdicts"]
@@ -1771,7 +1753,7 @@ class TpuSpfSolver:
             if lfa_jobs:
                 self._lfa_backups(
                     csr, my_id, nbr_ids, fh, lfa, dist, slot_cache, ls.area,
-                    lfa_jobs, rdb.unicast_routes,
+                    slots["lfa"], lfa_jobs, rdb.unicast_routes,
                 )
             if ksp_jobs:
                 self._ksp_batch(
@@ -1785,17 +1767,9 @@ class TpuSpfSolver:
             # cross-rebuild cache: under churn most nodes keep the same
             # (first-hop set, igp), so the per-node SWAP/PHP NextHop
             # construction — the single hottest host loop in a steady-state
-            # rebuild — is skipped for every unchanged destination. Keyed by
-            # the shared `slot_gen` fingerprint computed above.
-            # re-insert to refresh the fingerprint's LRU position
-            mpls_cache = self._mpls_cache.pop(slot_gen, None) or {}
-            self._mpls_cache[slot_gen] = mpls_cache
-            # evict least-recently-used fingerprints (NOT a full wipe — the
-            # fleet path serves many roots per pass, each a fingerprint, and
-            # a wipe would defeat the cross-rebuild cache it relies on); the
-            # cap is raised by compute_fleet_ribs to cover its root count
-            while len(self._mpls_cache) > self._mpls_fingerprint_cap:
-                self._mpls_cache.pop(next(iter(self._mpls_cache)))
+            # rebuild — is skipped for every unchanged destination. Kept in
+            # the root's slot-fingerprint cell fetched above.
+            mpls_cache = slots["mpls"]
             if len(mpls_cache) > max(4096, 4 * len(csr.node_names)):
                 mpls_cache.clear()
             # vectorized per-destination eligibility; the expensive content
@@ -1819,12 +1793,7 @@ class TpuSpfSolver:
             # update. The structure key is in the key because rows are node
             # IDS: the name↔id interning changes with a built base, not
             # with a base an overload bit re-based (CsrGraph.structure_key).
-            mcell = self._mpls_cls_cache.pop(slot_gen, None) or {
-                "groups": {}, "total": 0
-            }
-            self._mpls_cls_cache[slot_gen] = mcell
-            while len(self._mpls_cls_cache) > self._mpls_fingerprint_cap:
-                self._mpls_cls_cache.pop(next(iter(self._mpls_cls_cache)))
+            mcell = slots["mpls_cls"]
             mcls = mcell["groups"]
             cls_sel = dest_cls[sel]
             for g in _class_groups(cls_sel):
@@ -1891,31 +1860,6 @@ class TpuSpfSolver:
                     )
         return rdb
 
-    def _elect_multi(self, multi, d_root, fh_any, my_id, view_gen):
-        """Multi-advertiser election dispatch: device-side segmented
-        reductions (ops/election.py) once the advertiser matrix is big
-        enough to amortize a dispatch, NumPy below. Integer algebra —
-        the two produce identical results (tested)."""
-        reach = (np.asarray(d_root) < INF_DIST) & fh_any
-        if len(multi.adv) >= self.elect_device_min:
-            from openr_tpu.ops.election import elect_multi_device
-
-            self.elect_stats["device_elections"] += 1
-            self._elect_dev.pop(view_gen, None)  # refresh LRU position
-            with profiling.annotate(
-                "spf:election", counters=self.counters
-            ):
-                out = elect_multi_device(
-                    multi, np.asarray(d_root), reach, my_id,
-                    dev_cache=self._elect_dev, gen=view_gen,
-                )
-            while len(self._elect_dev) > self._dev_lru_cap:
-                self._elect_dev.pop(next(iter(self._elect_dev)))
-            return out
-        return elect_multi_np(
-            multi, np.asarray(d_root).astype(np.int64), reach, my_id
-        )
-
     @staticmethod
     def _mpls_wrap(base, node: str, label: int) -> tuple[NextHop, ...]:
         """Wrap a node-segment target's base nexthops with the SWAP/PHP
@@ -1949,16 +1893,13 @@ class TpuSpfSolver:
         scan — measured 57 ms of a warm 100k rebuild (r5 profile) — runs
         once per built base. Shared by the full assembly and the
         topology-delta scoped MPLS reassembly."""
-        key = (ls.area, csr.structure_key)
-        labels_v = self._labels_cache.get(key)
+        labels = self._structure_cell(csr)["labels"]
+        labels_v = labels.get(ls.area)
         if labels_v is None:
-            labels_v = np.fromiter(
+            labels_v = labels[ls.area] = np.fromiter(
                 (ls.node_label(nm) for nm in csr.node_names), np.int64,
                 count=n_live,
             )
-            self._labels_cache[key] = labels_v
-            while len(self._labels_cache) > self._dev_lru_cap:
-                self._labels_cache.pop(next(iter(self._labels_cache)))
         return labels_v
 
     def _mk_nexthops_cached_factory(
@@ -2142,18 +2083,18 @@ class TpuSpfSolver:
             # BASELINE config 4's backbone has degree 2-4 with k=16: this
             # alone cuts the per-prefix solve count ~4x; the in-kernel
             # early exit (ops/ksp.py) handles the per-job dest bound.
-            # Neighbor counts are structural, so cache per topology base
-            # (LRU like _dev — one entry per area's topology). (src, dst)
-            # pairs are unique by construction (_build_csr collapses
-            # parallel links via edge_best), so plain bincounts ARE the
-            # distinct-neighbor counts. Paths LEAVE the root (out-neighbor
+            # Neighbor counts are structural, so they are kept with the
+            # base's entry. (src, dst) pairs are unique by construction
+            # (_build_csr collapses parallel links via edge_best), so
+            # plain bincounts ARE the distinct-neighbor counts. Paths LEAVE the root (out-neighbor
             # bound) and ENTER the dest (in-neighbor bound); the CSR can be
             # asymmetric (a hard-drained adjacency drops one direction), so
             # the two counts differ.
-            counts = self._ksp_nbr_counts.get(csr.base_version)
+            host = self._base_host(csr)
+            counts = host.get("ksp_counts")
             if counts is None:
                 valid = csr.edge_metric < INF_DIST
-                counts = (
+                counts = host["ksp_counts"] = (
                     np.bincount(
                         csr.edge_src[valid], minlength=csr.padded_nodes
                     ),
@@ -2161,11 +2102,6 @@ class TpuSpfSolver:
                         csr.edge_dst[valid], minlength=csr.padded_nodes
                     ),
                 )
-                self._ksp_nbr_counts[csr.base_version] = counts
-                while len(self._ksp_nbr_counts) > self._dev_lru_cap:
-                    self._ksp_nbr_counts.pop(
-                        next(iter(self._ksp_nbr_counts))
-                    )
             out_counts, in_counts = counts
             bound = int(
                 max(
@@ -2186,7 +2122,7 @@ class TpuSpfSolver:
             k_eff = min(self.ksp_k, 1 << (bound - 1).bit_length())
             # what names this batch's kernel programs, for
             # prewarm_flap_programs: the batch sizes its chunks pad to
-            self._dev[csr.base_version]["host"]["ksp"] = (
+            host["ksp"] = (
                 my_id, k_eff, max_hops,
                 tuple(sorted({
                     pad_batch(min(chunk, len(jobs) - start))
@@ -2281,6 +2217,7 @@ class TpuSpfSolver:
         dist,
         slot_cache: list[list[tuple[str, str]]],
         area: str,
+        cell: dict,
         jobs: list[tuple],
         out: dict,
     ) -> None:
@@ -2323,17 +2260,12 @@ class TpuSpfSolver:
                     backs = ok.any(axis=1) & ~fh[:n, t].any(axis=1)
                     rows[j] = np.where(backs, link + via.min(axis=1), -1)
             # a row's next hops depend on the row and the root's slots
-            # alone, so they are kept across rebuilds under the slots'
-            # fingerprint, and a route whose entry and backups are the
-            # objects of the last rebuild gets its last entry back: an
-            # event that moves few backups builds few objects
-            slot_gen = (area, tuple(tuple(sl) for sl in slot_cache))
-            cell = self._lfa_cache.pop(slot_gen, None) or {
-                "rows": {}, "routes": {},
-            }
-            self._lfa_cache[slot_gen] = cell
-            while len(self._lfa_cache) > self._mpls_fingerprint_cap:
-                self._lfa_cache.pop(next(iter(self._lfa_cache)))
+            # alone, so they are kept across rebuilds in the slots'
+            # fingerprint cell (`cell`, {"rows": {row bytes: next hops},
+            # "routes": {prefix: (route, next hops, route with them)}}),
+            # and a route whose entry and backups are the objects of the
+            # last rebuild gets its last entry back: an event that moves
+            # few backups builds few objects
             memo, held = cell["rows"], cell["routes"]
             for d in (memo, held):
                 if len(d) > 4 * max(len(jobs), 1024):

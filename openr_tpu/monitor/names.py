@@ -288,7 +288,6 @@ REBUILD_SPANS: tuple[str, ...] = (
     "spf:unpack",                #       packed buffer → d_root, fh, lfa
     "spf:rib_assembly",          #       election + assembly + mpls
     "spf:rib_election",          #         classes, advertiser election
-    "spf:election",              #           device election (big tables)
     "spf:rib_unicast",           #         unicast RibEntries
     "spf:unicast_general",       #           the scalar election (warm too)
     "spf:lfa_backups",           #           LFA backups, one pass a rebuild

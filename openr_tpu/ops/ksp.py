@@ -283,7 +283,11 @@ def ksp_edge_disjoint_dense(
         jnp.asarray(root, jnp.int32),
         jnp.asarray(dests, jnp.int32),
     )
-    d0 = None if dist0 is None else jnp.asarray(dist0, DIST_DTYPE)
+    kwargs = {
+        "k": k,
+        "max_hops": max_hops,
+        "dist0": None if dist0 is None else jnp.asarray(dist0, DIST_DTYPE),
+    }
     # kernel cost ledger (docs/Monitor.md "Device telemetry"): lowers +
     # AOT-compiles only when the compile ledger counted a fresh variant
     # of this fn; the call below then reuses that executable (jit cache
@@ -292,14 +296,10 @@ def ksp_edge_disjoint_dense(
     # jit-delegation shape (the orlint jit registry follows it).
     device_telemetry.observe(
         "_ksp_edge_disjoint_dense_jit",
-        lambda: _ksp_edge_disjoint_dense_jit.lower(
-            *args, k=k, max_hops=max_hops, dist0=d0
-        ),
+        lambda: _ksp_edge_disjoint_dense_jit.lower(*args, **kwargs),
         span="spf:ksp",
     )
-    return _ksp_edge_disjoint_dense_jit(
-        *args, k=k, max_hops=max_hops, dist0=d0
-    )
+    return _ksp_edge_disjoint_dense_jit(*args, **kwargs)
 
 
 # the undecorated kernel body, for tests that re-jit it under forced

@@ -26,6 +26,7 @@ import pytest
 from openr_tpu.decision.linkstate import LinkState, PrefixState
 from openr_tpu.decision.oracle import compute_routes as oracle_compute_routes
 from openr_tpu.decision.spf_backend import TpuSpfSolver
+from openr_tpu.ops.spf import INF_DIST
 from openr_tpu.types.topology import Adjacency, ForwardingAlgorithm
 from openr_tpu.utils import topogen
 
@@ -397,6 +398,88 @@ def test_a_rebased_base_keeps_no_view_of_its_parent_alive():
     assert child.rebased_from[0] != child.base_version
 
 
+def raised_link(st: Story) -> tuple[str, str]:
+    """Raise every parallel link of one edge that does not leave the
+    root to metric 20 (21 where it was 20); returns the edge's names."""
+    csr = st.ls.to_csr()
+    root = csr.name_to_id[ROOT]
+    u, v = next(k for k in sorted(csr.edge_index) if k[0] != root)
+    node, other = csr.node_names[u], csr.node_names[v]
+    db = st.dbs[node]
+    m = 21 if csr.edge_metric[csr.edge_index[(u, v)]] == 20 else 20
+    st.apply(dataclasses.replace(db, adjacencies=tuple(
+        dataclasses.replace(a, metric=m) if a.other_node_name == other
+        else a for a in db.adjacencies)))
+    after = st.ls.to_csr()
+    assert after.base_version == csr.base_version
+    assert after.edge_metric[after.edge_index[(u, v)]] == m
+    return node, other
+
+
+def test_the_cone_index_and_ksp_counts_live_and_die_with_their_base():
+    """The warm start's cone index and KSP's neighbor counts are kept in
+    their base's entry: a built base computes its own, an overload
+    re-base carries its parent's as the same objects, a metric flap on
+    the drained base warm-starts to the oracle's routes, and once the
+    entry is evicted the arrays are computed again and stored nowhere."""
+    st = Story(4, 9)
+    ls, ps = st.ls, prefixes(st, ("node-19",))
+    solver = device_solver()
+    got, art = solver.compute_routes(ls, ps, ROOT, return_artifact=True)
+    base = ls.to_csr()
+    host = solver._dev[base.base_version]["host"]
+    counts = host["ksp_counts"]
+    valid = base.edge_metric < INF_DIST
+    for arr, ends in zip(counts, (base.edge_src, base.edge_dst)):
+        np.testing.assert_array_equal(
+            arr, np.bincount(ends[valid], minlength=base.padded_nodes))
+    assert "warm_out" not in host  # a warm start makes it
+    pairs = {raised_link(st)}
+
+    def warm(art, got):
+        out = solver.warm_compute_routes(
+            art, ls, ps, ROOT, pairs, set(), got, 1.0)
+        assert out is not None
+        want = oracle_compute_routes(ls, ps, ROOT, vectorize=False)
+        assert out[0].unicast_routes == want.unicast_routes
+        assert out[0].mpls_routes == want.mpls_routes
+        return out
+
+    warm(art, got)
+    cone = host["warm_out"]
+    order, row_start = cone
+    assert len(order) == base.num_edges
+    assert row_start[-1] == base.num_edges
+    # a drain: the re-based base carries both, the same objects
+    st.flip(undrained(st))
+    got, art = solver.compute_routes(ls, ps, ROOT, return_artifact=True)
+    drained = ls.to_csr()
+    assert drained.rebased_from[0] == base.base_version
+    assert solver.dev_cache_stats["carried"] == 2  # split + dense
+    child = solver._dev[drained.base_version]["host"]
+    assert child is not host
+    assert child["ksp_counts"] is counts and child["warm_out"] is cone
+    # a metric flap on the drained base: a warm start on the carried index
+    pairs = {raised_link(st)}
+    warm(art, got)
+    assert solver.warm_solves == 2  # each walked a cone
+    assert child["warm_out"] is cone and child["ksp_counts"] is counts
+    # four more drains evict both entries: the index of an evicted base
+    # is computed again and kept nowhere
+    for _ in range(4):
+        st.flip(undrained(st))
+        assert_routes(solver, ls, ps, ROOT)
+    assert base.base_version not in solver._dev
+    assert drained.base_version not in solver._dev
+    again = solver._warm_out_index(base)
+    assert again is not cone and base.base_version not in solver._dev
+    for a, b in zip(again, cone):
+        np.testing.assert_array_equal(a, b)
+    assert all(
+        e["host"].get("warm_out") is not again
+        for e in solver._dev.values())
+
+
 def test_graphs_built_with_no_structure_version_key_on_their_base():
     """A hand-built CsrGraph (the benchmark's er100k cell builds one)
     leaves `structure_version` at 0, so `structure_key` is its
@@ -432,8 +515,10 @@ def test_graphs_built_with_no_structure_version_key_on_their_base():
         view = ps.election_view(csr.name_to_id, csr.structure_key)
         assert view.gen[2] == csr.base_version
     bases = {a.base_version, b.base_version}
-    assert {key[1] for key in solver._labels_cache} == bases
+    assert {
+        key for key, cell in solver._structure.items() if cell["labels"]
+    } == bases
     groups = {
-        gkey[0] for cell in solver._mpls_cls_cache.values()
-        for gkey in cell["groups"]}
+        gkey[0] for cell in solver._slots.values()
+        for gkey in cell["mpls_cls"]["groups"]}
     assert groups == bases

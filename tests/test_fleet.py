@@ -98,24 +98,26 @@ def test_fleet_empty_and_all_unknown_targets():
 
 
 def test_fleet_mpls_cache_reuse_and_trim():
-    """The fleet pass durably raises the MPLS fingerprint cap so a
+    """The fleet pass durably raises the slot-fingerprint cap so a
     SECOND pass reuses the cached entries; trim_caches() reclaims the
     footprint on demand."""
     adj_dbs, prefix_dbs = topogen.grid(4, 4)
     ls, ps = _state(adj_dbs, prefix_dbs)
     solver = TpuSpfSolver(native_rib="off")
     f1 = compute_fleet_ribs(ls, ps, solver=solver)
-    n_fp = len(solver._mpls_cache)
+    n_fp = len(solver._slots)
     assert n_fp >= len(f1)  # one fingerprint per root retained
+    held = {fp: cell["mpls"] for fp, cell in solver._slots.items()}
     f2 = compute_fleet_ribs(ls, ps, solver=solver)
     # second pass: identical results served from the retained caches
     assert all(
         f1[n].mpls_routes == f2[n].mpls_routes for n in f1
     )
-    assert len(solver._mpls_cache) == n_fp  # no thrash between passes
+    assert len(solver._slots) == n_fp  # no thrash between passes
+    assert all(solver._slots[fp]["mpls"] is m for fp, m in held.items())
     solver.trim_caches()
-    assert len(solver._mpls_cache) <= 8
-    assert solver._mpls_fingerprint_cap == 8
+    assert len(solver._slots) <= 8
+    assert solver._slots.cap == 8
 
 
 def test_fleet_with_mesh_solver_equals_single_device():

@@ -411,7 +411,7 @@ def test_randomized_churn_cache_equivalence_property():
                 dataclasses.replace(db, adjacencies=tuple(adjs))
             )
         elif op < 7:
-            # prefix withdraw or re-add (solver_view gen transitions)
+            # prefix withdraw or re-add (election view gen transitions)
             i = int(rng.integers(0, len(names)))
             pfx = IpPrefix(prefix=f"10.9.{i}.0/24")
             if rng.integers(0, 2):

@@ -2,8 +2,8 @@
 nexthop-group interning, delta-native FIB programming, range
 origination.
 
-The load-bearing contract: the batched election (decision/election.py,
-device or NumPy) + grouped assembly must be BYTE-EQUAL to the
+The load-bearing contract: the batched election (decision/election.py)
++ grouped assembly must be BYTE-EQUAL to the
 per-prefix scalar path (`oracle.compute_routes(vectorize=False)`) on
 both engines, under randomized churn covering anycast ECMP ties,
 drained links, node overloads, and the MPLS label tables — the
@@ -229,70 +229,44 @@ def test_randomized_election_churn_parity(backend):
     run(body())
 
 
-def test_elect_device_matches_numpy():
-    """The jitted segmented-election kernel (ops/election.py) is
-    integer-exact against elect_multi_np on randomized tables."""
-    from openr_tpu.common.constants import DIST_INF
-    from openr_tpu.ops.election import elect_multi_device
-
-    rng = np.random.default_rng(3)
-    for trial in range(5):
-        m = int(rng.integers(1, 40))
-        counts = rng.integers(1, 6, m)
-        indptr = np.concatenate(([0], np.cumsum(counts))).astype(np.int64)
-        s = int(indptr[-1])
-        t = election.MultiTable(
-            prefixes=[f"p{i}" for i in range(m)],
-            indptr=indptr,
-            seg=np.repeat(np.arange(m, dtype=np.int64), counts),
-            adv=rng.integers(0, 30, s).astype(np.int64),
-            known=rng.random(s) < 0.9,
-            rank=rng.integers(0, 8, s).astype(np.int64),
-            entries=[None] * s,
-            names=[f"n{i}" for i in range(s)],
-        )
-        d_vec = np.where(
-            rng.random(32) < 0.8, rng.integers(1, 100, 32), DIST_INF
-        ).astype(np.int64)
-        reach = (d_vec < DIST_INF) & (rng.random(32) < 0.9)
-        my_id = int(rng.integers(0, 30))
-        a = election.elect_multi_np(t, d_vec, reach, my_id)
-        b = elect_multi_device(
-            t, d_vec, reach, my_id, dev_cache={}, gen=("t", trial)
-        )
-        for f in ("survive", "local", "is_best", "chosen"):
-            assert (getattr(a, f) == getattr(b, f)).all(), (trial, f)
-        sel = a.survive
-        assert (a.min_igp[sel] == b.min_igp[sel]).all(), trial
-
-
-def test_solver_device_election_threshold():
-    """A TPU solver with elect_device_min=1 routes the multi election
-    through the device kernel and stays byte-equal to the scalar
-    oracle."""
-    adj_dbs, prefix_dbs = topogen.grid(3, 3)
+def test_solver_elects_an_anycast_table_past_32k_slots_as_the_oracle():
+    """An advertiser matrix of more than 32,768 slots, over twice the largest
+    a benchmark cell holds, is elected by the solver's NumPy path to the
+    scalar oracle's routes: preference splits, distance splits, ECMP
+    ties among the best, and prefixes the root advertises itself."""
     from openr_tpu.decision.linkstate import LinkState, PrefixState
     from openr_tpu.decision.spf_backend import TpuSpfSolver
 
+    adj_dbs, _ = topogen.grid(3, 3)
     ls, ps = LinkState(), PrefixState()
     for db in adj_dbs:
         ls.update_adjacency_db(db)
-    for db in prefix_dbs:
-        ps.update_prefix_db(db)
     names = [db.this_node_name for db in adj_dbs]
-    for k in range(6):
-        e = anycast_entry(f"10.50.{k}.0/24", dist=k % 2)
-        for a in (names[(k + 1) % 9], names[(k + 3) % 9]):
-            ps.update_prefix_db(
-                PrefixDatabase(this_node_name=a, prefix_entries=(e,))
-            )
+    rng = np.random.default_rng(5)
+    per_node: dict[str, list] = {n: [] for n in names}
+    n_prefixes = 4500
+    for k in range(n_prefixes):
+        pstr = f"10.{60 + k // 256}.{k % 256}.0/24"
+        for a in rng.choice(len(names), int(rng.integers(6, 10)), False):
+            per_node[names[a]].append(anycast_entry(
+                pstr, pp=int(rng.choice((500, 1000))),
+                dist=int(rng.integers(0, 2)),
+            ))
+    for n, entries in per_node.items():
+        ps.update_prefix_db(
+            PrefixDatabase(this_node_name=n, prefix_entries=tuple(entries))
+        )
+    csr = ls.to_csr()
+    view = ps.election_view(csr.name_to_id, csr.structure_key)
+    assert len(view.multi.adv) > 1 << 15
+    assert len(view.multi.prefixes) == n_prefixes
     solver = TpuSpfSolver(native_rib="off")
-    solver.elect_device_min = 1
-    got = solver.compute_routes(ls, ps, "node-0")
-    ref = oracle_compute_routes(ls, ps, "node-0", vectorize=False)
+    got = solver.compute_routes(ls, ps, "node-4")
+    ref = oracle_compute_routes(ls, ps, "node-4", vectorize=False)
+    assert solver.elect_stats["multi"] == n_prefixes
+    assert 0 < len(ref.unicast_routes) < n_prefixes  # some local
     assert got.unicast_routes == ref.unicast_routes
     assert got.mpls_routes == ref.mpls_routes
-    assert solver.elect_stats["device_elections"] > 0
 
 
 def test_multi_sig_cache_sees_fh_change():

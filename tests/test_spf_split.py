@@ -362,7 +362,7 @@ def test_uni_cache_not_fooled_by_parallel_prefix_states():
     """Two independent PrefixState instances can reach the same _rev with
     different prefix contents; a shared solver's cross-rebuild unicast
     cache must not serve one state's RibEntrys for the other (lineage id
-    in the solver_view gen)."""
+    in the election view's gen)."""
     from openr_tpu.decision.linkstate import PrefixState
     from openr_tpu.decision.spf_backend import TpuSpfSolver
     from openr_tpu.types.topology import PrefixDatabase, PrefixEntry
@@ -392,6 +392,65 @@ def test_uni_cache_not_fooled_by_parallel_prefix_states():
     assert all(str(p).startswith("10.1.") for p in ra.unicast_routes)
     assert all(str(p).startswith("10.2.") for p in rb.unicast_routes)
     assert len(ra.unicast_routes) == len(rb.unicast_routes) > 0
+
+
+def test_slot_fingerprint_cells_are_evicted_whole_and_trimmed_to_n():
+    """A solver that serves more root slot fingerprints than its cap (8)
+    evicts the least recent cell with all four of its parts (unicast,
+    MPLS entries, MPLS class groups, LFA backups), a root served again
+    after its eviction gets the oracle's routes from a new cell, and
+    trim_caches(n) leaves exactly the n most recent cells."""
+    from openr_tpu.decision.linkstate import LinkState, PrefixState
+    from openr_tpu.decision.oracle import compute_routes as oracle_routes
+    from openr_tpu.decision.spf_backend import TpuSpfSolver
+
+    adj_dbs, prefix_dbs = topogen.grid(4, 4)
+    ls, ps = LinkState(), PrefixState()
+    for db in adj_dbs:
+        ls.update_adjacency_db(db)
+    for db in prefix_dbs:
+        ps.update_prefix_db(db)
+    roots = [db.this_node_name for db in adj_dbs][:11]
+    solver = TpuSpfSolver(native_rib="off", enable_lfa=True)
+
+    def serve(root):
+        got = solver.compute_routes(ls, ps, root)
+        want = oracle_routes(ls, ps, root, enable_lfa=True)
+        assert got.unicast_routes == want.unicast_routes
+        assert got.mpls_routes == want.mpls_routes
+        key = next(reversed(solver._slots))  # the cell just served
+        assert key[0] == ls.area
+        return key, solver._slots[key]
+
+    cells = dict(serve(r) for r in roots)
+    assert len(cells) == len(roots)  # one fingerprint a root
+    assert solver._slots.cap == 8 and len(solver._slots) == 8
+    keys = list(cells)
+    assert list(solver._slots) == keys[-8:]
+    parts = ("uni", "mpls", "mpls_cls", "lfa")
+    for key in keys:
+        cell = cells[key]
+        assert cell["uni"]["entries"] and cell["mpls"]
+        assert cell["mpls_cls"]["groups"] and cell["lfa"]["rows"]
+        if key in keys[:-8]:
+            # evicted: none of its four parts is held by any cell
+            assert key not in solver._slots
+            assert not any(
+                c[p] is cell[p] for c in solver._slots.values()
+                for p in parts)
+        else:
+            assert all(solver._slots[key][p] is cell[p] for p in parts)
+    # the first root again: a new cell, the oracle's routes, and the
+    # least recent cell out
+    again = serve(roots[0])
+    assert again[0] == keys[0]
+    assert all(again[1][p] is not cells[keys[0]][p] for p in parts)
+    assert list(solver._slots) == [*keys[-7:], keys[0]]
+    solver.trim_caches(3)
+    assert solver._slots.cap == 3
+    assert list(solver._slots) == [*keys[-2:], keys[0]]
+    assert all(
+        "warm_out" not in e["host"] for e in solver._dev.values())
 
 
 def test_pick_gs_chunks_never_silently_disables():
