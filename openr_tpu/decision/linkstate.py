@@ -20,6 +20,7 @@ import numpy as np
 
 from openr_tpu.common.constants import DEFAULT_AREA, DIST_INF, METRIC_MAX
 from openr_tpu.common.util import pad_bucket  # noqa: F401  (re-export)
+from openr_tpu.monitor import profiling
 from openr_tpu.types.topology import (
     Adjacency,
     AdjacencyDatabase,
@@ -298,8 +299,11 @@ class LinkState:
         self._pending: list[tuple[str, Adjacency]] = []
         # how to_csr served (decision.csr.*): `builds` ran _build_csr,
         # `overload_rebases` derived a base from an overload-only
-        # change. Shared with snapshots, which do the rebuilds' work.
-        self.csr_stats = {"builds": 0, "overload_rebases": 0}
+        # change, `adjacencies_walked` is what the builds' walks read.
+        # Shared with snapshots, which do the rebuilds' work.
+        self.csr_stats = {
+            "builds": 0, "overload_rebases": 0, "adjacencies_walked": 0,
+        }
 
     # ---- mutation ---------------------------------------------------------
 
@@ -570,6 +574,8 @@ class LinkState:
         )
 
     def _build_csr(self) -> CsrGraph:
+        """The CSR of every adjacency database, built anew: five passes,
+        each a child span of `spf:to_csr` (docs/Monitor.md "Spans")."""
         names = sorted(self._adj_dbs)  # deterministic interning
         name_to_id = {n: i for i, n in enumerate(names)}
         v = len(names)
@@ -581,75 +587,86 @@ class LinkState:
         # from the far side as (advertiser, advertiser's if_name) ==
         # our (other_node_name, other_if_name). Parallel links between
         # the same pair drain independently.
-        has_reverse: set[tuple[str, str]] = set()
-        drained: set[tuple[str, str]] = set()
-        for node, db in self._adj_dbs.items():
-            for adj in db.adjacencies:
-                has_reverse.add((node, adj.other_node_name))
-                if adj.is_overloaded:
-                    drained.add((node, adj.if_name))
+        with profiling.annotate("spf:csr_index"):
+            has_reverse: set[tuple[str, str]] = set()
+            drained: set[tuple[str, str]] = set()
+            for node, db in self._adj_dbs.items():
+                for adj in db.adjacencies:
+                    has_reverse.add((node, adj.other_node_name))
+                    if adj.is_overloaded:
+                        drained.add((node, adj.if_name))
 
-        srcs: list[int] = []
-        dsts: list[int] = []
-        metrics: list[int] = []
-        adj_details: dict[tuple[int, int], list] = {}
-        for node in names:
-            db = self._adj_dbs[node]
-            u = name_to_id[node]
-            for adj in db.adjacencies:
-                if adj.other_node_name not in name_to_id:
-                    continue  # neighbor's adj db not yet received
-                if (adj.other_node_name, node) not in has_reverse:
-                    continue  # bidirectional check failed
-                if adj.is_overloaded or (
-                    adj.other_node_name, adj.other_if_name
-                ) in drained:
-                    continue  # drained link (either side, both dirs)
-                w = name_to_id[adj.other_node_name]
-                key = (u, w)
-                detail = (
-                    adj.if_name,
-                    int(adj.metric),
-                    int(adj.weight),
-                    int(adj.adj_label),
-                    adj.other_if_name,
-                )
-                # parallel links: SPF uses the min metric; all parallel
-                # interfaces at min metric become ECMP nexthops
-                adj_details.setdefault(key, []).append(detail)
-                srcs.append(u)
-                dsts.append(w)
-                metrics.append(int(adj.metric))
+        with profiling.annotate("spf:csr_walk"):
+            srcs: list[int] = []
+            dsts: list[int] = []
+            metrics: list[int] = []
+            adj_details: dict[tuple[int, int], list] = {}
+            walked = 0
+            for node in names:
+                db = self._adj_dbs[node]
+                u = name_to_id[node]
+                walked += len(db.adjacencies)
+                for adj in db.adjacencies:
+                    if adj.other_node_name not in name_to_id:
+                        continue  # neighbor's adj db not yet received
+                    if (adj.other_node_name, node) not in has_reverse:
+                        continue  # bidirectional check failed
+                    if adj.is_overloaded or (
+                        adj.other_node_name, adj.other_if_name
+                    ) in drained:
+                        continue  # drained link (either side, both dirs)
+                    w = name_to_id[adj.other_node_name]
+                    key = (u, w)
+                    detail = (
+                        adj.if_name,
+                        int(adj.metric),
+                        int(adj.weight),
+                        int(adj.adj_label),
+                        adj.other_if_name,
+                    )
+                    # parallel links: SPF uses the min metric; all
+                    # parallel interfaces at min metric become ECMP
+                    # nexthops
+                    adj_details.setdefault(key, []).append(detail)
+                    srcs.append(u)
+                    dsts.append(w)
+                    metrics.append(int(adj.metric))
+            self.csr_stats["adjacencies_walked"] += walked
 
         # Collapse parallel edges to min-metric (solver-side); details kept.
-        edge_best: dict[tuple[int, int], int] = {}
-        for s, d, m in zip(srcs, dsts, metrics):
-            key = (s, d)
-            if key not in edge_best or m < edge_best[key]:
-                edge_best[key] = m
-        e = len(edge_best)
+        with profiling.annotate("spf:csr_collapse"):
+            edge_best: dict[tuple[int, int], int] = {}
+            for s, d, m in zip(srcs, dsts, metrics):
+                key = (s, d)
+                if key not in edge_best or m < edge_best[key]:
+                    edge_best[key] = m
+            e = len(edge_best)
 
-        vp = pad_bucket(max(v, 1) + 1)  # +1 dead slot for padding edges
-        ep = pad_bucket(max(e, 1), minimum=128)
+        with profiling.annotate("spf:csr_sort"):
+            vp = pad_bucket(max(v, 1) + 1)  # +1 dead slot for padding edges
+            ep = pad_bucket(max(e, 1), minimum=128)
 
-        edge_src = np.zeros(ep, dtype=np.int32)
-        edge_dst = np.full(ep, vp - 1, dtype=np.int32)  # dead slot
-        edge_metric = np.full(ep, INF_METRIC, dtype=np.int32)
+            edge_src = np.zeros(ep, dtype=np.int32)
+            edge_dst = np.full(ep, vp - 1, dtype=np.int32)  # dead slot
+            edge_metric = np.full(ep, INF_METRIC, dtype=np.int32)
 
-        # Sort by destination for contiguous segment reduction.
-        items = sorted(edge_best.items(), key=lambda kv: (kv[0][1], kv[0][0]))
-        edge_index: dict[tuple[int, int], int] = {}
-        for i, ((s, d), m) in enumerate(items):
-            edge_src[i] = s
-            edge_dst[i] = d
-            edge_metric[i] = min(m, METRIC_MAX)
-            edge_index[(s, d)] = i
+            # Sort by destination for contiguous segment reduction.
+            items = sorted(
+                edge_best.items(), key=lambda kv: (kv[0][1], kv[0][0])
+            )
+            edge_index: dict[tuple[int, int], int] = {}
+            for i, ((s, d), m) in enumerate(items):
+                edge_src[i] = s
+                edge_dst[i] = d
+                edge_metric[i] = min(m, METRIC_MAX)
+                edge_index[(s, d)] = i
 
-        node_overloaded = np.zeros(vp, dtype=bool)
-        node_mask = np.zeros(vp, dtype=bool)
-        for n, i in name_to_id.items():
-            node_mask[i] = True
-            node_overloaded[i] = self._adj_dbs[n].is_overloaded
+        with profiling.annotate("spf:csr_masks"):
+            node_overloaded = np.zeros(vp, dtype=bool)
+            node_mask = np.zeros(vp, dtype=bool)
+            for n, i in name_to_id.items():
+                node_mask[i] = True
+                node_overloaded[i] = self._adj_dbs[n].is_overloaded
 
         ver = next(_csr_version)
         return CsrGraph(

@@ -56,6 +56,8 @@ COUNTERS: frozenset[str] = frozenset(
         # derived from an overload-only change without one
         "decision.csr.builds",
         "decision.csr.overload_rebases",
+        # adjacencies the builds' walks read (every one of the area's)
+        "decision.csr.adjacencies_walked",
         # rebuilds published with an empty update: Fib is told nothing
         "decision.rebuild.no_change",
         # a rebuild's PrefixState snapshot: handed out again (no prefix
@@ -277,6 +279,11 @@ REBUILD_SPANS: tuple[str, ...] = (
     "decision:thread_start",     #     to_thread → the thread runs (record)
     "decision:compute_rib",      #     per-area compute + merge
     "spf:to_csr",                #       LinkState → CSR snapshot
+    "spf:csr_index",             #         build: the bidirectional index
+    "spf:csr_walk",              #         build: the walk over adjacencies
+    "spf:csr_collapse",          #         build: parallel edges to min
+    "spf:csr_sort",              #         build: dst sort, arrays, index
+    "spf:csr_masks",             #         build: node masks
     "spf:prepare",               #       cold: to_csr + pads + dispatch
     "spf:dispatch",              #       device tables: hit, patch, build
     "spf:patch_scatter",         #         journal suffix → compiled scatters

@@ -70,6 +70,11 @@ def built(dbs: dict):
     return fresh._build_csr()
 
 
+def walked(dbs: dict) -> int:
+    """What a build's walk reads: every adjacency of the LSDB."""
+    return sum(len(db.adjacencies) for db in dbs.values())
+
+
 def assert_same_csr(got, want):
     for name in ARRAYS:
         a, b = getattr(got, name), getattr(want, name)
@@ -148,7 +153,8 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
     st = Story(k, seed)
     ls, stats = st.ls, st.ls.csr_stats
     assert_same_csr(ls.to_csr(), built(st.dbs))
-    assert stats == {"builds": 1, "overload_rebases": 0}
+    assert stats == {"builds": 1, "overload_rebases": 0,
+                     "adjacencies_walked": walked(st.dbs)}
     bases = {ls.to_csr().base_version}
     structure = ls.to_csr().structure_version
     kinds = set()
@@ -196,16 +202,19 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
         assert got.base_version == base.base_version
         assert got.structure_version == base.structure_version
         if kind in ("fallback", "fallback_flip"):
-            assert grew == {"builds": 1, "overload_rebases": 0}, kind
+            assert grew == {"builds": 1, "overload_rebases": 0,
+                            "adjacencies_walked": walked(st.dbs)}, kind
             # a build is a structure of its own
             assert base.structure_version == base.base_version
             assert base.rebased_from is None
         elif kind == "metric":
-            assert grew == {"builds": 0, "overload_rebases": 0}, kind
+            assert grew == {"builds": 0, "overload_rebases": 0,
+                            "adjacencies_walked": 0}, kind
         else:
             # one re-base however many flips: a new base, an empty journal,
             # the structure of the base before it
-            assert grew == {"builds": 0, "overload_rebases": 1}, kind
+            assert grew == {"builds": 0, "overload_rebases": 1,
+                            "adjacencies_walked": 0}, kind
             assert base.version == base.base_version
             assert base.patches == ()
             assert base.base_version not in bases
@@ -239,7 +248,8 @@ def test_a_snapshot_before_a_flip_keeps_the_old_mask():
                 last.base_version}) == 3
     assert last.edge_metric is base.edge_metric
     assert last.adj_details is base.adj_details
-    assert ls.csr_stats == {"builds": 1, "overload_rebases": 2}
+    assert ls.csr_stats == {"builds": 1, "overload_rebases": 2,
+                            "adjacencies_walked": walked(st.dbs)}
     # through the solver: each view solves with its own mask, the first
     # by a build, the two re-based ones carried down the chain, each
     # placing its own mask
