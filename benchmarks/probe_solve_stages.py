@@ -52,7 +52,7 @@ for it in range(ITERS):
     t0 = time.perf_counter()
     csr = ls.to_csr()
     my_id = csr.name_to_id["node-0"]
-    nbr_ids = sorted(d for (s, d) in csr.adj_details if s == my_id)
+    nbr_ids = csr.neighbor_ids(my_id)
     n = len(nbr_ids)
     b = pad_batch(1 + n)
     nbr_metric_real = np.empty(n, dtype=np.int32)

@@ -33,7 +33,7 @@ print(f"# vp={vp} W={dev['base_wgt'].shape[1]} Go={dev['ov_nbr'].shape[0]} "
       f"Wo={dev['ov_nbr'].shape[1]} Wout={dev['out_nbr'].shape[1]}")
 
 my_id = csr.name_to_id["node-0"]
-nbr_ids = sorted(d for (s, d) in csr.adj_details if s == my_id)
+nbr_ids = csr.neighbor_ids(my_id)
 b = 32
 roots_h = np.full(b, my_id, dtype=np.int32)
 roots_h[1 : 1 + len(nbr_ids)] = nbr_ids[: b - 1]

@@ -50,10 +50,11 @@ def compute_fleet_ribs(
     if n == 0:
         return {}
 
-    # per-node out-adjacency (min metric per neighbor), from the keys
-    # the CSR already carries for nexthop construction
+    # per-node out-adjacency (min metric per neighbor), from the edges
+    # the CSR carries details of for nexthop construction
     nbrs_of: dict[int, list[int]] = {}
-    for (s, d) in csr.adj_details:
+    src, dst = csr.detail_pairs()
+    for s, d in zip(src.tolist(), dst.tolist()):
         nbrs_of.setdefault(s, []).append(d)
 
     targets = [

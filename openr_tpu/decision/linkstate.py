@@ -14,6 +14,8 @@ power-of-two buckets; invalid slots are masked with `INF_METRIC`.
 from __future__ import annotations
 
 import itertools
+import operator
+from collections.abc import Mapping
 from dataclasses import dataclass, field, replace
 
 import numpy as np
@@ -56,6 +58,115 @@ class MetricPatch:
     metric: int
 
 
+class EdgeIndex(Mapping):
+    """`edge_index` of a built CsrGraph: (src, dst) -> edge slot, answered
+    by a binary search over the edges' sorted `dst·V + src` keys (the
+    dst-sorted edge order) instead of a dict of every edge. Iterates in
+    slot order."""
+
+    __slots__ = ("_keys", "_v")
+
+    def __init__(self, keys: np.ndarray, v: int):
+        self._keys = keys  # int64 [num_edges], ascending
+        self._v = v
+
+    def slot(self, key) -> int:
+        """The slot of edge `key`, -1 where the graph has no such edge
+        (a key that is not a pair of node ids included)."""
+        try:
+            u, w = key
+            u, w = operator.index(u), operator.index(w)
+        except (TypeError, ValueError):
+            return -1
+        v = self._v
+        if not (0 <= u < v and 0 <= w < v):
+            return -1
+        k = w * v + u
+        i = int(self._keys.searchsorted(k))
+        return i if i < len(self._keys) and self._keys[i] == k else -1
+
+    def src(self, i: int) -> int:
+        return int(self._keys[i]) % self._v
+
+    def __getitem__(self, key) -> int:
+        i = self.slot(key)
+        if i < 0:
+            raise KeyError(key)
+        return i
+
+    def get(self, key, default=None):
+        i = self.slot(key)
+        return default if i < 0 else i
+
+    def __contains__(self, key) -> bool:
+        return self.slot(key) >= 0
+
+    def __len__(self) -> int:
+        return len(self._keys)
+
+    def __iter__(self):
+        if not len(self._keys):
+            return iter(())
+        dst, src = np.divmod(self._keys, self._v)
+        return zip(src.tolist(), dst.tolist())
+
+
+class EdgeDetails(Mapping):
+    """`adj_details` of a built CsrGraph: (src, dst) -> the edge's
+    [(if_name, metric, weight, adj_label, other_if_name)], one a parallel
+    adjacency that survived the build, in the source database's order.
+    Edge i's adjacencies are rows `rows[starts[i]:starts[i + 1]]` of the
+    build's concatenated columns; a row is position `row - row_off[src]`
+    of `dbs[src].adjacencies`. A list is made on the first read of its
+    edge and kept in the mapping, which `replace()` copies and re-based
+    bases share, so later reads are a dict hit."""
+
+    __slots__ = ("_index", "_rows", "_starts", "_dbs", "_row_off", "_memo")
+
+    def __init__(self, index: EdgeIndex, rows: np.ndarray,
+                 starts: np.ndarray, dbs: list, row_off: list[int]):
+        self._index = index
+        self._rows = rows
+        self._starts = starts  # [num_edges + 1]
+        self._dbs = dbs
+        self._row_off = row_off
+        self._memo: dict = {}
+
+    def _make(self, i: int) -> list:
+        u = self._index.src(i)
+        adjs, off = self._dbs[u].adjacencies, self._row_off[u]
+        rows = self._rows[self._starts[i]:self._starts[i + 1]].tolist()
+        return [
+            (a.if_name, int(a.metric), int(a.weight), int(a.adj_label),
+             a.other_if_name)
+            for a in (adjs[r - off] for r in rows)
+        ]
+
+    def get(self, key, default=None):
+        got = self._memo.get(key)
+        if got is None:
+            i = self._index.slot(key)
+            if i < 0:
+                return default
+            got = self._memo[key] = self._make(i)
+        return got
+
+    def __getitem__(self, key) -> list:
+        got = self.get(key)
+        if got is None:
+            raise KeyError(key)
+        return got
+
+    def __contains__(self, key) -> bool:
+        return key in self._memo or key in self._index
+
+    def __len__(self) -> int:
+        return len(self._index)
+
+    def __iter__(self):
+        return iter(self._index)
+
+
 @dataclass
 class CsrGraph:
     """Padded, device-ready edge-list view of the LSDB.
@@ -82,8 +193,10 @@ class CsrGraph:
     node_mask: np.ndarray
     node_names: list[str]
     # host-side maps for building NextHops from solver output:
-    # (src_id, dst_id) -> list[(if_name, metric, weight, adj_label, other_if)]
-    adj_details: dict[tuple[int, int], list[tuple[str, int, int, int, str]]]
+    # (src_id, dst_id) -> list[(if_name, metric, weight, adj_label, other_if)].
+    # A built graph's is an `EdgeDetails` over the build's arrays; a
+    # hand-built graph passes a plain dict (docs/Decision.md)
+    adj_details: Mapping[tuple[int, int], list[tuple[str, int, int, int, str]]]
     name_to_id: dict[str, int]
     # metric-patched entries overriding adj_details (shared base stays
     # untouched; the override dict holds only churned edges, so a 50-flap
@@ -94,8 +207,9 @@ class CsrGraph:
     _dense_width: int | None = None
     _row_start: np.ndarray | None = None
     # --- incremental-churn support ------------------------------------
-    # (src_id, dst_id) -> edge-array slot (built once per base)
-    edge_index: dict[tuple[int, int], int] = field(default_factory=dict)
+    # (src_id, dst_id) -> edge-array slot (built once per base; an
+    # `EdgeIndex` on a built graph, a dict where hand-built)
+    edge_index: Mapping[tuple[int, int], int] = field(default_factory=dict)
     # unique id of this materialization; patched copies keep the base's
     # id in `base_version` plus the cumulative journal that produced them,
     # so the TPU backend can scatter-update device-resident arrays
@@ -123,6 +237,22 @@ class CsrGraph:
         if got is not None:
             return got
         return self.adj_details.get((u, v), default)
+
+    def detail_pairs(self) -> tuple[np.ndarray, np.ndarray]:
+        """(src, dst) id arrays of the edges `adj_details` holds: on a
+        built graph its live edges, read off the arrays without making a
+        key; on a hand-built graph the dict's keys."""
+        if isinstance(self.adj_details, EdgeDetails):
+            e = self.num_edges
+            return self.edge_src[:e], self.edge_dst[:e]
+        keys = np.array(list(self.adj_details), dtype=np.int64)
+        keys = keys.reshape(-1, 2)
+        return keys[:, 0], keys[:, 1]
+
+    def neighbor_ids(self, u: int) -> list[int]:
+        """Sorted ids of the nodes `u` has an edge with details toward."""
+        src, dst = self.detail_pairs()
+        return np.sort(dst[src == u]).tolist()
 
     @property
     def structure_key(self) -> int:
@@ -299,11 +429,18 @@ class LinkState:
         self._pending: list[tuple[str, Adjacency]] = []
         # how to_csr served (decision.csr.*): `builds` ran _build_csr,
         # `overload_rebases` derived a base from an overload-only
-        # change, `adjacencies_walked` is what the builds' walks read.
-        # Shared with snapshots, which do the rebuilds' work.
+        # change, `adjacencies_walked` is what the builds' walks read,
+        # `databases_converted` the databases whose build columns were
+        # made anew (`_gather_columns`). Shared with snapshots, which do
+        # the rebuilds' work.
         self.csr_stats = {
             "builds": 0, "overload_rebases": 0, "adjacencies_walked": 0,
+            "databases_converted": 0,
         }
+        # the build's per-database columns, [(interning, {node: (db,
+        # columns)})]: shared with snapshots like csr_stats, so a build
+        # converts only the databases that changed since the last one
+        self._columns: list = [([], {})]
 
     # ---- mutation ---------------------------------------------------------
 
@@ -392,6 +529,7 @@ class LinkState:
         # in the shared cell
         snap._pending = self._pending
         snap.csr_stats = self.csr_stats
+        snap._columns = self._columns
         return snap
 
     # ---- queries ----------------------------------------------------------
@@ -574,73 +712,67 @@ class LinkState:
         )
 
     def _build_csr(self) -> CsrGraph:
-        """The CSR of every adjacency database, built anew: five passes,
-        each a child span of `spf:to_csr` (docs/Monitor.md "Spans")."""
+        """The CSR of every adjacency database, built anew from the
+        databases' columns (`_gather_columns`): five passes over whole
+        arrays, each a child span of `spf:to_csr` (docs/Monitor.md
+        "Spans"). A row is an adjacency; rows run in name order, each
+        database's in its own order, and the edges and their details
+        keep that order within a (dst, src) pair."""
         names = sorted(self._adj_dbs)  # deterministic interning
         name_to_id = {n: i for i, n in enumerate(names)}
         v = len(names)
+        dbs = [self._adj_dbs[n] for n in names]
 
-        # Directed adjacency index for the bidirectional check, plus
-        # the drained-link endpoints: an overloaded adjacency drains
-        # BOTH directions of that one link (reference:
-        # setInterfaceOverload † — maintenance soft-drain), identified
-        # from the far side as (advertiser, advertiser's if_name) ==
-        # our (other_node_name, other_if_name). Parallel links between
-        # the same pair drain independently.
+        # The rows with a known neighbor in (dst, src) order, and the
+        # bidirectional check: a row u->w is usable only if some row
+        # w->u exists. A stable sort by destination keeps each
+        # destination's rows in row order, which is source order, so
+        # the rows' reverse keys w·V + u come out ascending and are
+        # looked up in the sorted forward keys u·V + w in one pass.
         with profiling.annotate("spf:csr_index"):
-            has_reverse: set[tuple[str, str]] = set()
-            drained: set[tuple[str, str]] = set()
-            for node, db in self._adj_dbs.items():
-                for adj in db.adjacencies:
-                    has_reverse.add((node, adj.other_node_name))
-                    if adj.is_overloaded:
-                        drained.add((node, adj.if_name))
+            nbr32, metric, over, made = _gather_columns(
+                self._columns, names, name_to_id, dbs)
+            self.csr_stats["databases_converted"] += made
+            lens = np.fromiter(
+                (len(db.adjacencies) for db in dbs), np.int64, v)
+            row_off = np.zeros(v + 1, dtype=np.int64)
+            np.cumsum(lens, out=row_off[1:])
+            src = np.repeat(np.arange(v, dtype=np.int64), lens)
+            nbr = nbr32.astype(np.int64)
+            known = np.flatnonzero(nbr >= 0)  # -1: no adj db received yet
+            rows = known[np.argsort(nbr32[known], kind="stable")]
+            keys = (nbr * v + src)[rows]
+            fwd = np.sort((src * v + nbr)[known])
+            if np.array_equal(fwd, keys):
+                # the same pairs both ways round: every row has its reverse
+                keep = np.ones(len(keys), dtype=bool)
+            else:
+                at = np.minimum(np.searchsorted(fwd, keys), len(fwd) - 1)
+                keep = fwd[at] == keys
 
+        # The drained links: an overloaded adjacency drains BOTH
+        # directions of that one link (reference: setInterfaceOverload †
+        # — maintenance soft-drain), identified from the far side as
+        # (advertiser, advertiser's if_name) == our (other_node_name,
+        # other_if_name). Parallel links between the same pair drain
+        # independently.
         with profiling.annotate("spf:csr_walk"):
-            srcs: list[int] = []
-            dsts: list[int] = []
-            metrics: list[int] = []
-            adj_details: dict[tuple[int, int], list] = {}
-            walked = 0
-            for node in names:
-                db = self._adj_dbs[node]
-                u = name_to_id[node]
-                walked += len(db.adjacencies)
-                for adj in db.adjacencies:
-                    if adj.other_node_name not in name_to_id:
-                        continue  # neighbor's adj db not yet received
-                    if (adj.other_node_name, node) not in has_reverse:
-                        continue  # bidirectional check failed
-                    if adj.is_overloaded or (
-                        adj.other_node_name, adj.other_if_name
-                    ) in drained:
-                        continue  # drained link (either side, both dirs)
-                    w = name_to_id[adj.other_node_name]
-                    key = (u, w)
-                    detail = (
-                        adj.if_name,
-                        int(adj.metric),
-                        int(adj.weight),
-                        int(adj.adj_label),
-                        adj.other_if_name,
-                    )
-                    # parallel links: SPF uses the min metric; all
-                    # parallel interfaces at min metric become ECMP
-                    # nexthops
-                    adj_details.setdefault(key, []).append(detail)
-                    srcs.append(u)
-                    dsts.append(w)
-                    metrics.append(int(adj.metric))
-            self.csr_stats["adjacencies_walked"] += walked
+            if over.any():  # `over` (the gather's own copy) takes them
+                over[_drained_rows(
+                    np.unique(src[over]).tolist(), names, name_to_id, dbs,
+                    row_off)] = True
+                keep &= ~over[rows]
+            rows, keys = rows[keep], keys[keep]
+            self.csr_stats["adjacencies_walked"] += len(nbr)
 
-        # Collapse parallel edges to min-metric (solver-side); details kept.
+        # Collapse parallel edges to min-metric (solver-side); details
+        # kept: a pair's rows are one run of equal keys.
         with profiling.annotate("spf:csr_collapse"):
-            edge_best: dict[tuple[int, int], int] = {}
-            for s, d, m in zip(srcs, dsts, metrics):
-                key = (s, d)
-                if key not in edge_best or m < edge_best[key]:
-                    edge_best[key] = m
-            e = len(edge_best)
+            e_rows = len(rows)
+            first = np.flatnonzero(np.concatenate(
+                ([True], keys[1:] != keys[:-1]))) if e_rows else rows
+            e = len(first)
+            best = np.minimum.reduceat(metric[rows], first) if e else first
 
         with profiling.annotate("spf:csr_sort"):
             vp = pad_bucket(max(v, 1) + 1)  # +1 dead slot for padding edges
@@ -649,24 +781,20 @@ class LinkState:
             edge_src = np.zeros(ep, dtype=np.int32)
             edge_dst = np.full(ep, vp - 1, dtype=np.int32)  # dead slot
             edge_metric = np.full(ep, INF_METRIC, dtype=np.int32)
-
-            # Sort by destination for contiguous segment reduction.
-            items = sorted(
-                edge_best.items(), key=lambda kv: (kv[0][1], kv[0][0])
+            ekeys = keys[first]
+            edge_dst[:e], edge_src[:e] = np.divmod(ekeys, max(v, 1))
+            edge_metric[:e] = np.minimum(best, METRIC_MAX)
+            edge_index = EdgeIndex(ekeys, v)
+            adj_details = EdgeDetails(
+                edge_index, rows, np.append(first, e_rows), dbs,
+                row_off.tolist(),
             )
-            edge_index: dict[tuple[int, int], int] = {}
-            for i, ((s, d), m) in enumerate(items):
-                edge_src[i] = s
-                edge_dst[i] = d
-                edge_metric[i] = min(m, METRIC_MAX)
-                edge_index[(s, d)] = i
 
         with profiling.annotate("spf:csr_masks"):
             node_overloaded = np.zeros(vp, dtype=bool)
             node_mask = np.zeros(vp, dtype=bool)
-            for n, i in name_to_id.items():
-                node_mask[i] = True
-                node_overloaded[i] = self._adj_dbs[n].is_overloaded
+            node_mask[:v] = True
+            node_overloaded[:v] = [db.is_overloaded for db in dbs]
 
         ver = next(_csr_version)
         return CsrGraph(
@@ -685,6 +813,82 @@ class LinkState:
             base_version=ver,
             structure_version=ver,
         )
+
+
+def _db_columns(
+    db: AdjacencyDatabase, name_to_id: dict[str, int]
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One database's build columns, a row an adjacency in its order: the
+    neighbor's id under `name_to_id` (-1 for a neighbor with no
+    database), the metric, the adjacency's `is_overloaded`."""
+    adjs = db.adjacencies
+    n = len(adjs)
+    return (
+        np.fromiter(
+            (name_to_id.get(a.other_node_name, -1) for a in adjs),
+            np.int32, n),
+        np.fromiter((a.metric for a in adjs), np.int64, n),
+        np.fromiter((a.is_overloaded for a in adjs), bool, n),
+    )
+
+
+def _gather_columns(
+    cell: list, names: list[str], name_to_id: dict[str, int], dbs: list
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """The build's columns of `dbs` (in `names` order), concatenated, and
+    how many databases had theirs made anew. `cell` holds (interning,
+    {node: (database, its columns)}) and is shared with snapshots: a
+    database's columns are kept while the node set, so the interning,
+    stands and the node's database is the same object; a new node set
+    starts a fresh store, swapped in whole, so a store's columns all
+    hold ids of its own interning."""
+    interning, store = cell[0]
+    if interning != names:
+        store = {}
+        cell[0] = (names, store)
+    made = 0
+    cols = []
+    for name, db in zip(names, dbs):
+        got = store.get(name)
+        if got is None or got[0] is not db:
+            got = store[name] = (db, *_db_columns(db, name_to_id))
+            made += 1
+        cols.append(got)
+    if not cols:
+        return (np.zeros(0, dtype=np.int32), np.zeros(0, dtype=np.int64),
+                np.zeros(0, dtype=bool), made)
+    return (
+        np.concatenate([c[1] for c in cols]),
+        np.concatenate([c[2] for c in cols]),
+        np.concatenate([c[3] for c in cols]),
+        made,
+    )
+
+
+def _drained_rows(
+    drainers: list[int], names: list[str], name_to_id: dict[str, int],
+    dbs: list, row_off: np.ndarray,
+) -> list[int]:
+    """Rows whose link the far side drained: row `a` of node u where
+    (a.other_node_name, a.other_if_name) is an overloaded adjacency's
+    (advertiser, if_name). Only the `drainers` (nodes holding an
+    overloaded adjacency) and their neighbors are read: a row toward a
+    drainer from a node the drainer lists no adjacency to fails the
+    bidirectional check anyway."""
+    out: list[int] = []
+    for x in drainers:
+        name = names[x]
+        ifs = {a.if_name for a in dbs[x].adjacencies if a.is_overloaded}
+        near = {name_to_id.get(a.other_node_name)
+                for a in dbs[x].adjacencies}
+        near.discard(None)
+        for y in near:
+            off = int(row_off[y])
+            out.extend(
+                off + pos for pos, a in enumerate(dbs[y].adjacencies)
+                if a.other_node_name == name and a.other_if_name in ifs
+            )
+    return out
 
 
 class PrefixState:

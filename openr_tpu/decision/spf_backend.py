@@ -324,8 +324,9 @@ class TpuSpfSolver:
         # Per topology structure (csr.structure_key, which pins the ids,
         # names and details, and which a re-based base shares with its
         # parent): {"nbr": {node id: sorted neighbor ids}, "labels":
-        # {area: int64 node-label vector}}, so the O(E) adj_details scan
-        # and the O(V) label scan run once per built topology
+        # {area: int64 node-label vector}}, so the O(E) neighbor scan
+        # (`CsrGraph.neighbor_ids`) and the O(V) label scan run once per
+        # built topology
         self._structure = _Lru(4)
         # Per slot fingerprint (`_slot_cell`: the area and the root's
         # adjacency slots, which the first-hop columns alone can't see):
@@ -873,9 +874,7 @@ class TpuSpfSolver:
             nbrs = self._structure_cell(csr)["nbr"]
             nbr_ids = nbrs.get(my_id)
             if nbr_ids is None:
-                nbr_ids = nbrs[my_id] = sorted(
-                    d for (s, d) in csr.adj_details if s == my_id
-                )
+                nbr_ids = nbrs[my_id] = csr.neighbor_ids(my_id)
             n = len(nbr_ids)
             b = pad_batch(1 + n)
             nbr_metric_real = self._nbr_metrics(csr, my_id, nbr_ids)

@@ -58,6 +58,8 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.csr.overload_rebases",
         # adjacencies the builds' walks read (every one of the area's)
         "decision.csr.adjacencies_walked",
+        # databases whose build columns a build made anew
+        "decision.csr.databases_converted",
         # rebuilds published with an empty update: Fib is told nothing
         "decision.rebuild.no_change",
         # a rebuild's PrefixState snapshot: handed out again (no prefix

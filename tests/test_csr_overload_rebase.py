@@ -154,7 +154,9 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
     ls, stats = st.ls, st.ls.csr_stats
     assert_same_csr(ls.to_csr(), built(st.dbs))
     assert stats == {"builds": 1, "overload_rebases": 0,
-                     "adjacencies_walked": walked(st.dbs)}
+                     "adjacencies_walked": walked(st.dbs),
+                     "databases_converted": len(st.dbs)}
+    last_built = dict(st.dbs)  # the databases the last build read
     bases = {ls.to_csr().base_version}
     structure = ls.to_csr().structure_version
     kinds = set()
@@ -198,23 +200,33 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
         assert_same_csr(got, built(st.dbs))
         assert ls.to_csr() is got
         grew = {key: stats[key] - before[key] for key in stats}
+        converted = grew.pop("databases_converted")
         base = ls._csr_cell[0]
         assert got.base_version == base.base_version
         assert got.structure_version == base.structure_version
         if kind in ("fallback", "fallback_flip"):
             assert grew == {"builds": 1, "overload_rebases": 0,
                             "adjacencies_walked": walked(st.dbs)}, kind
+            # the databases that changed since the last build, or every
+            # one where the node set moved (the ids are interned anew)
+            assert converted == (
+                len(st.dbs) if set(st.dbs) != set(last_built)
+                else sum(st.dbs[n] is not last_built[n] for n in st.dbs)
+            ), kind
+            last_built = dict(st.dbs)
             # a build is a structure of its own
             assert base.structure_version == base.base_version
             assert base.rebased_from is None
         elif kind == "metric":
             assert grew == {"builds": 0, "overload_rebases": 0,
                             "adjacencies_walked": 0}, kind
+            assert converted == 0, kind
         else:
             # one re-base however many flips: a new base, an empty journal,
             # the structure of the base before it
             assert grew == {"builds": 0, "overload_rebases": 1,
                             "adjacencies_walked": 0}, kind
+            assert converted == 0, kind
             assert base.version == base.base_version
             assert base.patches == ()
             assert base.base_version not in bases
@@ -249,7 +261,8 @@ def test_a_snapshot_before_a_flip_keeps_the_old_mask():
     assert last.edge_metric is base.edge_metric
     assert last.adj_details is base.adj_details
     assert ls.csr_stats == {"builds": 1, "overload_rebases": 2,
-                            "adjacencies_walked": walked(st.dbs)}
+                            "adjacencies_walked": walked(st.dbs),
+                            "databases_converted": len(st.dbs)}
     # through the solver: each view solves with its own mask, the first
     # by a build, the two re-based ones carried down the chain, each
     # placing its own mask
