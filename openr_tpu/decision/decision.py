@@ -1548,9 +1548,9 @@ class Decision(OpenrModule):
                 for tier, n in self.decode_stats.items():
                     self.counters.set(f"decision.decode.{tier}", n)
             # how each area's CSR was served: built, or re-based by an
-            # overload bit, what the builds walked and the databases
-            # whose columns they made anew (LinkState.csr_stats, shared
-            # with snapshots)
+            # overload bit, what the builds walked, the databases whose
+            # columns they made anew and the builds that kept the node
+            # interning (LinkState.csr_stats, shared with snapshots)
             csr = [ls.csr_stats for ls in self._link_states.values()]
             self.counters.set(
                 "decision.csr.builds", sum(c["builds"] for c in csr))
@@ -1565,6 +1565,10 @@ class Decision(OpenrModule):
             self.counters.set(
                 "decision.csr.databases_converted",
                 sum(c["databases_converted"] for c in csr),
+            )
+            self.counters.set(
+                "decision.csr.interning_kept",
+                sum(c["interning_kept"] for c in csr),
             )
             if self._tpu is not None:
                 for k, n in self._tpu.dev_cache_stats.items():

@@ -93,7 +93,7 @@ class ElectView:
     multi: MultiTable | None
     complex_items: list  # [(prefix, {node: entry})] scalar fallback
     complex_table: ComplexTable  # complex_items' advertisers, columnar
-    gen: tuple  # generation token (lineage, rev, structure_key)
+    gen: tuple  # generation token (lineage, rev, interning_key)
 
 
 @dataclass
@@ -121,7 +121,7 @@ def build_elect_view(entries: dict, name_to_id: dict, gen) -> ElectView:
     """Classify a PrefixState's entries into the election view.
 
     ``entries`` is the prefix → {node: PrefixEntry} map; the walk is
-    O(prefixes) and runs once per (prefix revision, topology structure) —
+    O(prefixes) and runs once per (prefix revision, node interning) —
     the result is cached by PrefixState's shared view cell."""
     plain_p: list = []
     plain_n: list = []

@@ -221,6 +221,12 @@ class CsrGraph:
     # version, and a base `LinkState._rebase` derived keeps its
     # parent's, so caches of structure alone survive an overload bit
     structure_version: int = 0
+    # the structure_version of the build that interned this graph's node
+    # set: a later build of the same node set keeps that build's
+    # `node_names` and `name_to_id` (the same objects) and its number,
+    # so caches of the ids and names alone survive a changed adjacency
+    # set (`interning_key`)
+    interning_version: int = 0
     # on a base `_rebase` derived: (parent base_version, parent view's
     # version, its journal), what brings the parent's device tables to
     # the view this base was derived from. Numbers, never the parent
@@ -256,11 +262,22 @@ class CsrGraph:
 
     @property
     def structure_key(self) -> int:
-        """What caches of the ids, names and details alone key on:
-        `structure_version`, or `base_version` where the constructor
-        left it at 0 (no build is numbered 0), so a re-based base keys
-        as its parent and a hand-built graph as its own base."""
+        """What caches of the edges and the databases' contents key on
+        (the neighbour list, the node-label vector): `structure_version`,
+        or `base_version` where the constructor left it at 0 (no build is
+        numbered 0), so a re-based base keys as its parent and a
+        hand-built graph as its own base. Every build has its own."""
         return self.structure_version or self.base_version
+
+    @property
+    def interning_key(self) -> int:
+        """What caches of the ids and names alone key on (the election
+        view, and through its `gen` the unicast route cell; the MPLS
+        class groups): `interning_version`, which a build of an unchanged
+        node set keeps, so a link down or up keeps it where
+        `structure_key` moves; or `structure_key` where the constructor
+        left it at 0, so a hand-built graph keys as its own base."""
+        return self.interning_version or self.structure_key
 
     @property
     def padded_nodes(self) -> int:
@@ -431,16 +448,19 @@ class LinkState:
         # `overload_rebases` derived a base from an overload-only
         # change, `adjacencies_walked` is what the builds' walks read,
         # `databases_converted` the databases whose build columns were
-        # made anew (`_gather_columns`). Shared with snapshots, which do
-        # the rebuilds' work.
+        # made anew (`_gather_columns`), `interning_kept` the builds that
+        # kept the last build's interning. Shared with snapshots, which
+        # do the rebuilds' work.
         self.csr_stats = {
             "builds": 0, "overload_rebases": 0, "adjacencies_walked": 0,
-            "databases_converted": 0,
+            "databases_converted": 0, "interning_kept": 0,
         }
-        # the build's per-database columns, [(interning, {node: (db,
-        # columns)})]: shared with snapshots like csr_stats, so a build
-        # converts only the databases that changed since the last one
-        self._columns: list = [([], {})]
+        # the last build's interning and its per-database columns,
+        # [(names, name_to_id, its number, {node: (db, columns)})]: shared
+        # with snapshots like csr_stats, so a build of the same node set
+        # keeps the ids (`interning_kept`, `CsrGraph.interning_version`)
+        # and converts only the databases that changed since the last one
+        self._columns: list = [(None, {}, 0, {})]
 
     # ---- mutation ---------------------------------------------------------
 
@@ -718,8 +738,17 @@ class LinkState:
         "Spans"). A row is an adjacency; rows run in name order, each
         database's in its own order, and the edges and their details
         keep that order within a (dst, src) pair."""
+        ver = next(_csr_version)
         names = sorted(self._adj_dbs)  # deterministic interning
-        name_to_id = {n: i for i, n in enumerate(names)}
+        interning = self._columns[0]
+        if interning[0] == names:
+            # the node set stands, so do the ids: the last build's list,
+            # map and number, the same objects
+            self.csr_stats["interning_kept"] += 1
+        else:
+            interning = self._columns[0] = (
+                names, {n: i for i, n in enumerate(names)}, ver, {})
+        names, name_to_id, interned, store = interning
         v = len(names)
         dbs = [self._adj_dbs[n] for n in names]
 
@@ -731,7 +760,7 @@ class LinkState:
         # looked up in the sorted forward keys u·V + w in one pass.
         with profiling.annotate("spf:csr_index"):
             nbr32, metric, over, made = _gather_columns(
-                self._columns, names, name_to_id, dbs)
+                store, names, name_to_id, dbs)
             self.csr_stats["databases_converted"] += made
             lens = np.fromiter(
                 (len(db.adjacencies) for db in dbs), np.int64, v)
@@ -796,7 +825,6 @@ class LinkState:
             node_mask[:v] = True
             node_overloaded[:v] = [db.is_overloaded for db in dbs]
 
-        ver = next(_csr_version)
         return CsrGraph(
             num_nodes=v,
             num_edges=e,
@@ -812,6 +840,7 @@ class LinkState:
             version=ver,
             base_version=ver,
             structure_version=ver,
+            interning_version=interned,
         )
 
 
@@ -833,19 +862,15 @@ def _db_columns(
 
 
 def _gather_columns(
-    cell: list, names: list[str], name_to_id: dict[str, int], dbs: list
+    store: dict, names: list[str], name_to_id: dict[str, int], dbs: list
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
     """The build's columns of `dbs` (in `names` order), concatenated, and
-    how many databases had theirs made anew. `cell` holds (interning,
-    {node: (database, its columns)}) and is shared with snapshots: a
-    database's columns are kept while the node set, so the interning,
-    stands and the node's database is the same object; a new node set
-    starts a fresh store, swapped in whole, so a store's columns all
-    hold ids of its own interning."""
-    interning, store = cell[0]
-    if interning != names:
-        store = {}
-        cell[0] = (names, store)
+    how many databases had theirs made anew. `store` is the interning's
+    {node: (database, its columns)} (`LinkState._columns`): a database's
+    columns are kept while the node set, so the interning, stands and
+    the node's database is the same object; a new node set comes with a
+    fresh store, so a store's columns all hold ids of its own
+    interning."""
     made = 0
     cols = []
     for name, db in zip(names, dbs):
@@ -967,7 +992,7 @@ class PrefixState:
         (nothing changed since) rather than build one."""
         return self._snap is not None and self._snap._rev == self._rev
 
-    def election_view(self, name_to_id: dict, structure_key: int):
+    def election_view(self, name_to_id: dict, interning_key: int):
         """Cached columnar election classification for RIB assembly
         (:class:`openr_tpu.decision.election.ElectView`).
 
@@ -976,19 +1001,20 @@ class PrefixState:
         originator-id arrays, and "multi" (anycast ECMP: 2+ advertisers,
         all plain-shaped) as the prefix→advertiser matrix the batched
         election consumes — and everything else, which keeps the scalar
-        general path. Cached on (prefix rev, topology structure), the
-        structure being `CsrGraph.structure_key`, which pins
-        `name_to_id`: under metric-only churn and an overload bit
-        neither changes, so those rebuilds skip the O(P)
-        classification entirely.
+        general path. Cached on (prefix rev, node interning), the
+        interning being `CsrGraph.interning_key`, which pins
+        `name_to_id` and nothing of the edges: metric-only churn, an
+        overload bit and a link down or up (a build of the same node
+        set) change neither, so those rebuilds skip the O(P)
+        classification entirely; a node added or removed moves it.
 
         ``gen`` is a generation token unique to (instance lineage,
-        prefix rev, topology structure): within one PrefixState lineage
-        it changes iff the view could, and it can never collide across
+        prefix rev, node interning): within one PrefixState lineage it
+        changes iff the view could, and it can never collide across
         independent instances (the lineage id), so cross-rebuild caches
         may key row indices into the view arrays on it.
         """
-        key = (self._lineage, self._rev, structure_key)
+        key = (self._lineage, self._rev, interning_key)
         cached = self._view_cell[0]
         if cached is not None and cached[0] == key:
             return cached[1]

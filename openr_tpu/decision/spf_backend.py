@@ -326,7 +326,10 @@ class TpuSpfSolver:
         # parent): {"nbr": {node id: sorted neighbor ids}, "labels":
         # {area: int64 node-label vector}}, so the O(E) neighbor scan
         # (`CsrGraph.neighbor_ids`) and the O(V) label scan run once per
-        # built topology
+        # built topology. Both read edges or databases, so neither keys
+        # on the node interning (`csr.interning_key`), which a build of
+        # the same node set keeps: the root's own link can go down, and
+        # a label changes in a build
         self._structure = _Lru(4)
         # Per slot fingerprint (`_slot_cell`: the area and the root's
         # adjacency slots, which the first-hop columns alone can't see):
@@ -392,10 +395,10 @@ class TpuSpfSolver:
         # gc_pause_ms / gc_full_collections: the garbage collector's
         # process totals as they stood when the last solver call ended
         # (_note_gc).
-        # assembly_carried: _assemble_routes calls on a base an overload
-        # bit re-based (structure_key != base_version) that kept the
-        # last RIB's unicast cell: the election view, keyed on the
-        # structure, survived the new base, and so did its RibEntrys.
+        # assembly_carried: _assemble_routes calls on a new base, built
+        # or re-based by an overload bit, that kept the last RIB's
+        # unicast cell: the election view, keyed on the node interning,
+        # survived the new base, and so did its RibEntrys.
         self.spf_kernel_stats = {
             "gs_active": 0, "gs_disabled": 0, "uniform_metric": 0,
             "engine_device": 0, "engine_native": 0,
@@ -1446,7 +1449,7 @@ class TpuSpfSolver:
                 n_live = len(csr.node_names)
                 changed_mask = np.zeros(csr.padded_nodes, bool)
                 changed_mask[changed_ids] = True
-                view = ps.election_view(csr.name_to_id, csr.structure_key)
+                view = ps.election_view(csr.name_to_id, csr.interning_key)
                 touched = set(prefix_dirt)
                 if len(view.plain_p):
                     for i in np.nonzero(changed_mask[view.orig])[0]:
@@ -1548,7 +1551,7 @@ class TpuSpfSolver:
             # a handful of classes. The general per-prefix loop below keeps
             # every other case (anycast, UCMP, KSP, min_nexthop); LFA backups
             # are one pass over the routes of all three (`_lfa_backups`).
-            view = ps.election_view(csr.name_to_id, csr.structure_key)
+            view = ps.election_view(csr.name_to_id, csr.interning_key)
             plain_p, plain_n = view.plain_p, view.plain_n
             plain_e, orig = view.plain_e, view.orig
             complex_items, view_gen = view.complex_items, view.gen
@@ -1621,9 +1624,11 @@ class TpuSpfSolver:
                 cell = slots["uni"]
                 if cell is None or cell["gen"] != view_gen:
                     cell = slots["uni"] = {
-                        "gen": view_gen, "entries": {}, "classdicts": {},
+                        "gen": view_gen, "base": csr.base_version,
+                        "entries": {}, "classdicts": {},
                     }
-                elif csr.structure_key != csr.base_version:
+                elif cell["base"] != csr.base_version:
+                    cell["base"] = csr.base_version
                     self.spf_kernel_stats["assembly_carried"] += 1
             if len(plain_p):
                 entries = cell["entries"]
@@ -1789,9 +1794,10 @@ class TpuSpfSolver:
             # class-level sub-dict reuse, mirroring the unicast path: a
             # destination class whose membership, labels, and (fh, igp)
             # token are unchanged since a previous rebuild is ONE dict
-            # update. The structure key is in the key because rows are node
-            # IDS: the name↔id interning changes with a built base, not
-            # with a base an overload bit re-based (CsrGraph.structure_key).
+            # update. The interning key is in the key because rows are node
+            # IDS: the name↔id interning changes with the node set, not
+            # with a link down or up or an overload bit
+            # (CsrGraph.interning_key); the labels are in it as bytes.
             mcell = slots["mpls_cls"]
             mcls = mcell["groups"]
             cls_sel = dest_cls[sel]
@@ -1801,7 +1807,7 @@ class TpuSpfSolver:
                 lab = labels_v[rows]
                 # bytes, not hashes, for the same reason as the unicast path
                 gkey = (
-                    csr.structure_key, token, rows.tobytes(), lab.tobytes()
+                    csr.interning_key, token, rows.tobytes(), lab.tobytes()
                 )
                 sub = mcls.get(gkey)
                 if sub is None:
@@ -1890,8 +1896,9 @@ class TpuSpfSolver:
         build → new structure_key; an overload bit re-bases only where
         the label stayed, `_overload_only`), so the O(V) python label
         scan — measured 57 ms of a warm 100k rebuild (r5 profile) — runs
-        once per built base. Shared by the full assembly and the
-        topology-delta scoped MPLS reassembly."""
+        once per built base. Not on the interning: a build that keeps
+        the node set may carry a new label. Shared by the full assembly
+        and the topology-delta scoped MPLS reassembly."""
         labels = self._structure_cell(csr)["labels"]
         labels_v = labels.get(ls.area)
         if labels_v is None:

@@ -60,6 +60,8 @@ COUNTERS: frozenset[str] = frozenset(
         "decision.csr.adjacencies_walked",
         # databases whose build columns a build made anew
         "decision.csr.databases_converted",
+        # builds that kept the last build's node interning (same node set)
+        "decision.csr.interning_kept",
         # rebuilds published with an empty update: Fib is told nothing
         "decision.rebuild.no_change",
         # a rebuild's PrefixState snapshot: handed out again (no prefix

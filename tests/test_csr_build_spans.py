@@ -49,7 +49,8 @@ def test_a_build_opens_the_five_spans_in_order_under_to_csr():
                      ("spf:to_csr", None)]
     assert ls.csr_stats == {"builds": 1, "overload_rebases": 0,
                             "adjacencies_walked": 64,
-                            "databases_converted": 20}
+                            "databases_converted": 20,
+                            "interning_kept": 0}
 
 
 def test_the_walk_counts_what_it_reads_a_one_sided_adjacency_included():
@@ -87,4 +88,5 @@ def test_a_re_base_or_a_patch_opens_no_build_span_and_walks_nothing(change):
     assert spans == [("spf:to_csr", None)]
     grew = {k: ls.csr_stats[k] - walked[k] for k in walked}
     assert grew == {"builds": 0, "overload_rebases": int(change == "drain"),
-                    "adjacencies_walked": 0, "databases_converted": 0}
+                    "adjacencies_walked": 0, "databases_converted": 0,
+                    "interning_kept": 0}

@@ -78,7 +78,7 @@ def test_a_link_down_and_up_converts_the_two_databases_and_walks_all(which):
         lsdb = sum(len(ls.adjacency_db(n).adjacencies) for n in ls.nodes)
         assert grew == {"builds": 1, "overload_rebases": 0,
                         "adjacencies_walked": lsdb,
-                        "databases_converted": 2}
+                        "databases_converted": 2, "interning_kept": 1}
         assert csr.num_edges == lsdb
     assert lsdb == 64
 

@@ -12,8 +12,9 @@ removed); snapshots are taken before and after flips.
 The solver keys on the same lineage: a re-based base carries its
 parent's device tables (`TpuSpfSolver._carry`) with its own overload mask
 placed anew, and equal routes hold whether it carries or builds. The
-assembly's caches key on `CsrGraph.structure_key`, which falls back to the
-base where a graph was built by hand with no `structure_version`."""
+assembly's caches key on `CsrGraph.interning_key` and `structure_key`,
+both of which a re-based base shares with its parent and which fall back
+to the base where a graph was built by hand with no `structure_version`."""
 
 import dataclasses
 import gc
@@ -155,7 +156,8 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
     assert_same_csr(ls.to_csr(), built(st.dbs))
     assert stats == {"builds": 1, "overload_rebases": 0,
                      "adjacencies_walked": walked(st.dbs),
-                     "databases_converted": len(st.dbs)}
+                     "databases_converted": len(st.dbs),
+                     "interning_kept": 0}
     last_built = dict(st.dbs)  # the databases the last build read
     bases = {ls.to_csr().base_version}
     structure = ls.to_csr().structure_version
@@ -201,6 +203,7 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
         assert ls.to_csr() is got
         grew = {key: stats[key] - before[key] for key in stats}
         converted = grew.pop("databases_converted")
+        kept = grew.pop("interning_kept")
         base = ls._csr_cell[0]
         assert got.base_version == base.base_version
         assert got.structure_version == base.structure_version
@@ -213,6 +216,8 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
                 len(st.dbs) if set(st.dbs) != set(last_built)
                 else sum(st.dbs[n] is not last_built[n] for n in st.dbs)
             ), kind
+            # and the ids stand where the node set did
+            assert kept == (set(st.dbs) == set(last_built)), kind
             last_built = dict(st.dbs)
             # a build is a structure of its own
             assert base.structure_version == base.base_version
@@ -220,13 +225,13 @@ def test_the_derived_csr_is_the_build_of_the_same_lsdb(k, seed):
         elif kind == "metric":
             assert grew == {"builds": 0, "overload_rebases": 0,
                             "adjacencies_walked": 0}, kind
-            assert converted == 0, kind
+            assert converted == kept == 0, kind
         else:
             # one re-base however many flips: a new base, an empty journal,
             # the structure of the base before it
             assert grew == {"builds": 0, "overload_rebases": 1,
                             "adjacencies_walked": 0}, kind
-            assert converted == 0, kind
+            assert converted == kept == 0, kind
             assert base.version == base.base_version
             assert base.patches == ()
             assert base.base_version not in bases
@@ -262,7 +267,8 @@ def test_a_snapshot_before_a_flip_keeps_the_old_mask():
     assert last.adj_details is base.adj_details
     assert ls.csr_stats == {"builds": 1, "overload_rebases": 2,
                             "adjacencies_walked": walked(st.dbs),
-                            "databases_converted": len(st.dbs)}
+                            "databases_converted": len(st.dbs),
+                            "interning_kept": 0}
     # through the solver: each view solves with its own mask, the first
     # by a build, the two re-based ones carried down the chain, each
     # placing its own mask

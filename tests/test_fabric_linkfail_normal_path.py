@@ -40,7 +40,8 @@ FABRICS = {4: (1, 1, 2147483659), 8: (3, 3, 5)}
 SEQUENCE = 14
 COUNTED = ("decision.rebuild.structural", "decision.csr.builds",
            "decision.csr.overload_rebases", "decision.csr.adjacencies_walked",
-           "decision.spf.warm_starts", "decision.spf.engine_native")
+           "decision.spf.warm_starts", "decision.spf.engine_native",
+           "decision.csr.interning_kept", "decision.spf.assembly_carried")
 
 
 def link(u: int, v: int) -> tuple[int, int]:
@@ -286,6 +287,19 @@ def test_every_event_builds_the_csr_and_walks_the_whole_lsdb(story):
     assert story["cold"]["lsdb_adjacencies"] == full - 2 * standing
     for s in story["steps"]:
         assert s["lsdb_adjacencies"] == full - 2 * len(s["down"]), s["label"]
+
+
+def test_every_event_keeps_the_interning_and_the_unicast_cell(story):
+    """A link down or up leaves the node set as it was: each event's build
+    keeps the last build's interning, and the cold assembly on the new
+    base keeps the last RIB's unicast cell, one of each an event after the
+    first RIB (the first RIB interns and fills the cell, and reads 0)."""
+    assert story["cold"]["grew"]["decision.csr.interning_kept"] == 0
+    assert story["cold"]["grew"]["decision.spf.assembly_carried"] == 0
+    for s in [*story["steps"], story["one_end"], story["other_end"],
+              story["back_up"], story["flap_down"], story["flap_up"]]:
+        assert s["grew"]["decision.csr.interning_kept"] == 1, s["label"]
+        assert s["grew"]["decision.spf.assembly_carried"] == 1, s["label"]
 
 
 def test_a_link_down_at_one_end_only_is_dropped_both_ways(story):
